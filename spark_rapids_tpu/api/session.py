@@ -468,11 +468,19 @@ class TpuSession:
         device_budget = self.conf.get(rc.DEVICE_MEMORY_LIMIT)
         if not device_budget:
             import jax
-            try:
-                stats = jax.devices()[0].memory_stats() or {}
-                hbm = stats.get("bytes_limit", 16 << 30)
-            except Exception:
-                hbm = 16 << 30
+            # this process's own device: in a multi-controller fleet
+            # devices()[0] belongs to process 0 and answers only there
+            dev = jax.local_devices()[0]
+            if dev.platform == "tpu":
+                # the chip reports its HBM; a missing figure is a broken
+                # backend, not something to guess around
+                hbm = dev.memory_stats()["bytes_limit"]
+            else:
+                # the CPU backend reports no limit: size the pool as if
+                # for 16 GiB so tests spill where a chip would
+                cpu_assumed_bytes = 16 << 30
+                hbm = (dev.memory_stats() or {}).get(
+                    "bytes_limit", cpu_assumed_bytes)
             # GpuDeviceManager.scala:170-245 sizing contract: subtract
             # the runtime reserve, apply alloc fraction, clamp to the
             # max fraction, and fail fast below the min fraction
@@ -739,7 +747,8 @@ class TpuSession:
         return FleetRunner(self)
 
     # --------------------------------------------------------------- planning --
-    def plan(self, logical: L.LogicalPlan, overrides=None):
+    def plan(self, logical: L.LogicalPlan, overrides=None,
+             pushdown: bool = True):
         from spark_rapids_tpu.config import rapids_conf as rc
         # a caller may plan through a one-off TpuOverrides (the recovery
         # driver's split-batch rung scales batch sizes this way) without
@@ -750,7 +759,7 @@ class TpuSession:
             # the whole query to the CPU fallback chain instead of
             # failing it (RapidsConf.scala suppressPlanningFailure)
             try:
-                exec_plan = ov.apply(logical)
+                exec_plan = ov.apply(logical, pushdown=pushdown)
             except Exception as exc:
                 import warnings
                 # surface the root cause: the CPU chain may itself lack
@@ -764,7 +773,7 @@ class TpuSession:
                 self.last_planning_error = exc
                 exec_plan = self.plan_cpu_only(logical)
         else:
-            exec_plan = ov.apply(logical)
+            exec_plan = ov.apply(logical, pushdown=pushdown)
         if self.conf.get(rc.PROFILE_TRACE):
             def mark(node):
                 node.trace_ops = True

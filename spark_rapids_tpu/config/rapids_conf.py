@@ -370,12 +370,13 @@ SHUFFLE_TRANSPORT_ENABLED = conf(
 
 SHUFFLE_PACKED_ENABLED = conf(
     "spark.rapids.tpu.shuffle.packed.enabled", True,
-    "Fused packed shuffle wire format: byte-reinterpret all fixed-width "
-    "columns of an exchange into width-homogeneous lane payloads (uint32 "
-    "lanes for 4/8-byte columns, uint8 lanes for bool/small ints, "
-    "validity masks bit-packed eight to a lane) and move each payload "
-    "with ONE all_to_all — O(distinct widths) <= 2 collectives per "
-    "exchange instead of O(columns + masks). False restores per-column "
+    "Fused packed shuffle wire format: gather all fixed-width columns of "
+    "an exchange into width-homogeneous lane payloads (uint32 lanes for "
+    "4-byte columns and int64, uint8 lanes for bool/small ints, validity "
+    "masks bit-packed eight to a lane, float64 columns as themselves in "
+    "an f64 group — the TPU compiler refuses to bit-cast a double) and "
+    "move each payload with ONE all_to_all — O(distinct widths) <= 3 "
+    "collectives per exchange instead of O(columns + masks). False restores per-column "
     "collectives (the A/B baseline, and the automatic fallback for "
     "exchanges carrying unpackable columns). See docs/performance.md "
     "\"Shuffle wire format\".", _to_bool)
@@ -871,7 +872,7 @@ SERVING_SYNC_BUDGET = conf(
     "(utils/hostsync.py). A query that exceeds it is rejected with a "
     "typed BudgetExhaustedFault at the offending sync — a runaway "
     "sync loop in one query must not serialize the whole session's "
-    "tunnel. 0 disables.", _to_int,
+    "dispatch queue. 0 disables.", _to_int,
     lambda v: None if v >= 0 else "must be >= 0")
 
 SERVING_DEADLINE_BUDGET_MS = conf(

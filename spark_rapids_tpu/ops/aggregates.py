@@ -26,6 +26,7 @@ import numpy as np
 
 from spark_rapids_tpu.columnar import dtypes as dts
 from spark_rapids_tpu.columnar.dtypes import DataType
+from spark_rapids_tpu.ops import selection
 from spark_rapids_tpu.ops.expressions import ColVal, Expression, combine_validity
 
 
@@ -41,11 +42,11 @@ def _row_mask(nrows, capacity: int, row_mask=None):
 def _sortable_keys(keys: Sequence[ColVal], valid_rows, capacity: int,
                    descending: Optional[Sequence[bool]] = None,
                    nulls_first: Optional[Sequence[bool]] = None):
-    """Build jnp.lexsort key list (least-significant first) from key columns.
-
-    Dead rows (padding or filtered) always sort last.  Floats are normalized
-    so NaN sorts largest and -0.0 == 0.0 (Spark ordering).
-    """
+    """Build the lexsort key list (least-significant first) from key
+    columns, and the dead-row flag that sorts last whatever the keys say
+    (padding or filtered rows; ``selection.lexsort_i32``'s ``dead``).
+    Floats are normalized so NaN sorts largest and -0.0 == 0.0 (Spark
+    ordering)."""
     n = len(keys)
     descending = descending or [False] * n
     nulls_first = nulls_first or [not d for d in descending]
@@ -68,8 +69,7 @@ def _sortable_keys(keys: Sequence[ColVal], valid_rows, capacity: int,
         if c.validity is not None:
             null_key = jnp.logical_not(c.validity).astype(jnp.int8)
             lex.append(-null_key if nf else null_key)
-    lex.append(pad.astype(jnp.int8))  # most significant: dead rows last
-    return lex
+    return lex, pad
 
 
 def _order_keys(v, desc: bool) -> List:
@@ -110,8 +110,9 @@ def widen_colval(c: ColVal, capacity: int) -> ColVal:
 def sort_permutation(keys: Sequence[ColVal], valid_rows, capacity: int,
                      descending: Optional[Sequence[bool]] = None,
                      nulls_first: Optional[Sequence[bool]] = None):
-    lex = _sortable_keys(keys, valid_rows, capacity, descending, nulls_first)
-    return jnp.lexsort(lex).astype(jnp.int32)
+    lex, dead = _sortable_keys(keys, valid_rows, capacity, descending,
+                               nulls_first)
+    return selection.lexsort_i32(lex, dead=dead)
 
 
 def _keys_equal_prev(sorted_keys: Sequence[ColVal], capacity: int):
@@ -546,7 +547,6 @@ def groupby_aggregate(keys: Sequence[ColVal],
     ``nrows`` prefix.  Returns (out_keys, out_buffers, num_groups); output
     rows beyond num_groups are padding.
     """
-    from spark_rapids_tpu.ops import selection
 
     keys = [widen_colval(c, capacity) for c in keys]
     buffer_inputs = [(k, widen_colval(c, capacity))
@@ -1031,27 +1031,6 @@ def reduce_aggregate(buffer_inputs: Sequence[Tuple[str, ColVal]],
     tree reduction on the VPU (orders of magnitude faster at multi-million
     row capacities)."""
     valid_rows = _row_mask(nrows, capacity, row_mask)
-    # all-float all-sum shape (count buffers are int sums handled below):
-    # fuse every column into one HBM pass on TPU via the pallas kernel
-    from spark_rapids_tpu.ops import pallas_kernels as pk
-    import os
-    float_sums = [(k, c) for k, c in buffer_inputs
-                  if k == "sum" and jnp.issubdtype(c.values.dtype,
-                                                  jnp.floating)]
-    # opt-in until f64-in-pallas is validated on the target chip
-    # (interpret-mode tests pass; hardware lowering of f64 is the risk)
-    if pk.use_pallas() and \
-            os.environ.get("SPARK_RAPIDS_TPU_PALLAS_REDUCE") and \
-            len(float_sums) == len(buffer_inputs) and buffer_inputs:
-        vals = [c.values for _, c in buffer_inputs]
-        valids = [jnp.ones(capacity, dtype=jnp.bool_)
-                  if c.validity is None else c.validity
-                  for _, c in buffer_inputs]
-        sums, cnts = pk.masked_multi_reduce(vals, valids, valid_rows,
-                                            interpret=False)
-        return [ColVal(c.dtype, sums[i:i + 1].astype(c.values.dtype),
-                       (cnts[i:i + 1] > 0))
-                for i, (_, c) in enumerate(buffer_inputs)]
     # ONE multi-operand lax.reduce: every buffer's reduction plus the
     # contribution counts ride a single pass over the input — XLA fuses
     # the predicate/projection producers into the reduce loop, so a
@@ -1191,7 +1170,6 @@ def groupby_collect(keys: Sequence[ColVal], collect_inputs, nrows,
     Returns (out_keys, out_buffers, collect_arrays, num_groups) where
     each collect array is a ColVal with offsets (ARRAY layout).
     """
-    from spark_rapids_tpu.ops import selection
 
     live = _row_mask(nrows, capacity, row_mask)
     n_live = live.sum().astype(jnp.int32)
@@ -1223,9 +1201,10 @@ def groupby_collect(keys: Sequence[ColVal], collect_inputs, nrows,
             null_flag = jnp.zeros(capacity, dtype=jnp.int8) \
                 if child.validity is None else \
                 jnp.logical_not(child.validity).astype(jnp.int8)
-            perm2 = jnp.lexsort(
-                _order_keys(child.values, False) + [null_flag] +
-                _sortable_keys(keys, live, capacity))
+            lex, dead = _sortable_keys(keys, live, capacity)
+            perm2 = selection.lexsort_i32(
+                _order_keys(child.values, False) + [null_flag] + lex,
+                dead=dead)
             sc = selection.gather([child] + list(keys), perm2, n_live)
             schild, skeys2 = sc[0], sc[1:]
             same2 = _keys_equal_prev(skeys2, capacity)

@@ -74,12 +74,11 @@ def join_match(build_keys: Sequence[ColVal], probe_keys: Sequence[ColVal],
             live = live & c.validity
         norm_keys.append(_norm_key(c.values))
 
-    # sort: dead rows last, then by keys, then build before probe
-    lex = [side]
-    for k in reversed(norm_keys):
-        lex.append(k)
-    lex.append(jnp.logical_not(live).astype(jnp.int8))
-    perm = jnp.lexsort(lex).astype(jnp.int32)
+    # sort: dead rows last, then by keys, then build before probe —
+    # the stable sort's own tie-break, build rows being the lower
+    # positions, so ``side`` is no operand of the sorting network
+    perm = selection.lexsort_i32(list(reversed(norm_keys)),
+                                 dead=jnp.logical_not(live))
     n_live = live.sum().astype(jnp.int32)
 
     s_keys = [k[perm] for k in norm_keys]
@@ -192,7 +191,7 @@ def hash_join_match(build_keys: Sequence[ColVal],
     slot_b = slot_b.astype(jnp.int32)  # T for dead/overflowed rows
 
     # build rows grouped by slot, ORIGINAL order within a slot (stable)
-    sorted_to_build = jnp.lexsort([slot_b]).astype(jnp.int32)
+    sorted_to_build = selection.lexsort_i32([slot_b])
     counts = jnp.bincount(slot_b, length=T + 1)[:T].astype(jnp.int32)
     starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
 

@@ -1,32 +1,26 @@
 """Pallas TPU kernels for the engine's hot data-parallel primitives.
 
-Two kernels where hand-scheduling beats what XLA emits for the generic
-lowering (see /opt/skills/guides/pallas_guide.md):
-
 - ``partition_histogram``: per-row partition-id counts.  XLA lowers
   ``segment_sum`` / one-hot scatter to a serialized scatter on TPU; here
   each grid step one-hot-expands a row block in VMEM and accumulates a
-  (1, num_parts) running sum — the TPU grid is sequential, so the
+  (num_parts, 1) running sum — the TPU grid is sequential, so the
   accumulate-into-output pattern is race-free.  Feeds shuffle partition
   sizing and AQE statistics (the reference gets these numbers from cudf's
-  ``contiguousSplit`` metadata, GpuPartitioning.scala:50).
+  ``contiguousSplit`` metadata, GpuPartitioning.scala:50).  Compiles for
+  the chip (tests/test_chip_compile.py).
 
-- ``masked_multi_reduce``: one pass over N value columns + a shared row
-  mask producing per-column (sum, count).  The keyless aggregation path
-  (grand totals, TPC-H q6 shape) otherwise reads each column twice (sum
-  pass + count pass) from HBM; fusing halves the bandwidth on the
-  bandwidth-bound side of the roofline.
+- ``hash_insert`` / ``hash_probe``: open-addressing hash table, behind
+  the default-off ``spark.rapids.tpu.pallas.hash.enabled``.  Interpret
+  mode only so far: the chip's compiler refuses the scalar stores.
 
-Both kernels run under ``interpret=True`` off-TPU so the CPU-mesh test
-suite exercises the same code path the chip runs.  ``use_pallas()`` gates
-dispatch: real TPU backends only (the interpreter is for tests — the XLA
-fallback is faster on CPU).
+Tests run the kernels under ``interpret=True`` on the CPU mesh.
+``use_pallas()`` gates dispatch: real TPU backends only (the interpreter
+is for tests — the XLA formulation is faster on CPU).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,10 +30,7 @@ _BLOCK_ROWS = 1024
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(maxsize=1)
@@ -79,23 +70,25 @@ def hash_dispatch_conf(conf=None):
 
 # ---------------------------------------------------------------- histogram --
 
-def _hist_kernel(pid_ref, mask_ref, out_ref, *, num_parts: int):
+def _hist_kernel(key_ref, out_ref, *, num_parts: int):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    pids = pid_ref[...]            # (1, BLOCK)
-    mask = mask_ref[...]           # (1, BLOCK)
-    # one-hot (BLOCK, num_parts) via broadcast compare; masked rows
-    # contribute nothing.  The sum over the row axis is a dense reduction
-    # the VPU handles natively — no scatter.
-    cols = jax.lax.broadcasted_iota(jnp.int32, (pids.shape[1], num_parts), 1)
-    onehot = (pids.reshape(-1, 1) == cols) & mask.reshape(-1, 1)
+    keys = key_ref[...]            # (1, BLOCK) int32, masked rows are -1
+    # one-hot (num_parts, BLOCK): partitions down the sublanes, rows
+    # along the lanes, so the (1, BLOCK) key row broadcasts down the
+    # sublanes as loaded — Mosaic refuses to reshape a vector across
+    # the lane/sublane axes.  The row-axis sum is a dense reduction the
+    # VPU/XLU handle natively — no scatter.
+    parts = jax.lax.broadcasted_iota(
+        jnp.int32, (num_parts, keys.shape[1]), 0)
+    onehot = jnp.where(keys == parts, jnp.int32(1), jnp.int32(0))
     # dtype= pins the accumulator: under x64 an int32 sum promotes to
     # int64 and the store into the int32 out ref refuses
-    out_ref[...] += onehot.sum(axis=0, keepdims=True, dtype=jnp.int32)
+    out_ref[...] += onehot.sum(axis=1, keepdims=True, dtype=jnp.int32)
 
 
 def partition_histogram(pids: jnp.ndarray, mask: jnp.ndarray,
@@ -112,25 +105,25 @@ def partition_histogram(pids: jnp.ndarray, mask: jnp.ndarray,
     if capacity == 0:
         # grid would be 0: the step-0 output init never runs
         return jnp.zeros(num_parts, dtype=jnp.int32)
+    # the mask folds into the key outside the kernel (XLA fuses it into
+    # the producer): one int32 operand, no i1 vector inside the kernel
+    keys = jnp.where(mask, pids.astype(jnp.int32), jnp.int32(-1))
     padded = ((capacity + _BLOCK_ROWS - 1) // _BLOCK_ROWS) * _BLOCK_ROWS
     if padded != capacity:
-        pids = jnp.pad(pids, (0, padded - capacity))
-        mask = jnp.pad(mask, (0, padded - capacity))
-    pids2 = pids.reshape(1, padded).astype(jnp.int32)
-    mask2 = mask.reshape(1, padded)
-    grid = padded // _BLOCK_ROWS
+        keys = jnp.pad(keys, (0, padded - capacity), constant_values=-1)
     out = pl.pallas_call(
         functools.partial(_hist_kernel, num_parts=num_parts),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((1, _BLOCK_ROWS), lambda i: (0, i)),
-            pl.BlockSpec((1, _BLOCK_ROWS), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, num_parts), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, num_parts), jnp.int32),
+        grid=(padded // _BLOCK_ROWS,),
+        # int32 zeros: under x64 a Python 0 in an index map is an i64,
+        # which Mosaic refuses
+        in_specs=[pl.BlockSpec((1, _BLOCK_ROWS),
+                               lambda i: (jnp.int32(0), i))],
+        out_specs=pl.BlockSpec((num_parts, 1),
+                               lambda i: (jnp.int32(0), jnp.int32(0))),
+        out_shape=jax.ShapeDtypeStruct((num_parts, 1), jnp.int32),
         interpret=interpret,
-    )(pids2, mask2)
-    return out[0]
+    )(keys.reshape(1, padded))
+    return out[:, 0]
 
 
 def partition_histogram_xla(pids, mask, num_parts):
@@ -150,82 +143,6 @@ def histogram(pids, mask, num_parts):
     return jax.ops.segment_sum(
         jnp.ones_like(pids, dtype=jnp.int32), key,
         num_segments=num_parts + 1)[:num_parts]
-
-
-# ---------------------------------------------------- fused masked reduce --
-
-def _multi_reduce_kernel(mask_ref, *refs, num_cols: int):
-    # refs = (val_ref_0..val_ref_{n-1}, valid_ref_0.., sum_out, cnt_out)
-    val_refs = refs[:num_cols]
-    valid_refs = refs[num_cols:2 * num_cols]
-    sum_ref = refs[2 * num_cols]
-    cnt_ref = refs[2 * num_cols + 1]
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
-    def _init():
-        sum_ref[...] = jnp.zeros_like(sum_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    mask = mask_ref[...]  # (1, BLOCK) bool
-    for c in range(num_cols):
-        v = val_refs[c][...]
-        ok = mask & valid_refs[c][...]
-        contrib = jnp.where(ok, v, 0.0).sum(axis=1, dtype=sum_ref.dtype)
-        cnt = ok.sum(axis=1, dtype=jnp.int32)
-        sum_ref[0, c] += contrib[0]
-        cnt_ref[0, c] += cnt[0]
-
-
-def masked_multi_reduce(values: Sequence[jnp.ndarray],
-                        validities: Sequence[jnp.ndarray],
-                        mask: jnp.ndarray,
-                        interpret: bool | None = None
-                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One HBM pass: per column c, (sum of values[c] where mask &
-    validity[c], count of those rows).  Values are float64 accumulated in
-    float64 (emulated on TPU but still single-pass)."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    n = len(values)
-    capacity = values[0].shape[0]
-    if capacity == 0:
-        return (jnp.zeros(n, dtype=jnp.float64),
-                jnp.zeros(n, dtype=jnp.int32))
-    padded = ((capacity + _BLOCK_ROWS - 1) // _BLOCK_ROWS) * _BLOCK_ROWS
-    vals2, valid2 = [], []
-    for v, ok in zip(values, validities):
-        v = v.astype(jnp.float64)
-        if padded != capacity:
-            v = jnp.pad(v, (0, padded - capacity))
-            ok = jnp.pad(ok, (0, padded - capacity))
-        vals2.append(v.reshape(1, padded))
-        valid2.append(ok.reshape(1, padded))
-    m = mask
-    if padded != capacity:
-        m = jnp.pad(m, (0, padded - capacity))
-    m2 = m.reshape(1, padded)
-    grid = padded // _BLOCK_ROWS
-    block = pl.BlockSpec((1, _BLOCK_ROWS), lambda i: (0, i))
-    sums, cnts = pl.pallas_call(
-        functools.partial(_multi_reduce_kernel, num_cols=n),
-        grid=(grid,),
-        in_specs=[block] * (2 * n + 1),
-        out_specs=[pl.BlockSpec((1, n), lambda i: (0, 0))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((1, n), jnp.float64),
-                   jax.ShapeDtypeStruct((1, n), jnp.int32)],
-        interpret=interpret,
-    )(m2, *vals2, *valid2)
-    return sums[0], cnts[0]
-
-
-def masked_multi_reduce_xla(values, validities, mask):
-    sums, cnts = [], []
-    for v, ok in zip(values, validities):
-        live = jnp.logical_and(mask, ok)
-        sums.append(jnp.where(live, v.astype(jnp.float64), 0.0).sum())
-        cnts.append(live.astype(jnp.int32).sum())
-    return jnp.stack(sums), jnp.stack(cnts)
 
 
 # ------------------------------------------------- hash table insert/probe --

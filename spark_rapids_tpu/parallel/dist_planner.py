@@ -1088,7 +1088,8 @@ class DistPlanner:
                 sub.pushed_filters = list(plan.pushed_filters)
                 sub.required_columns = plan.required_columns
                 sub.file_meta = set(plan.file_meta)
-                batches = list(self.session.plan(sub).execute())
+                batches = list(self.session.plan(
+                    sub, pushdown=False).execute())
                 rows = sum(b.nrows for b in batches)
             else:
                 batches, rows = [], 0
@@ -2043,6 +2044,11 @@ def try_distributed(session, plan: L.LogicalPlan, resume: bool = False):
         session.last_dist_explain = "distributed disabled by conf"
         return None
     planner = DistPlanner(session, mesh, resume=resume)
+    # this query's column pruning and filters onto its scans: the
+    # FileRelation of a view is shared, and would otherwise carry
+    # whatever the last single-process plan left on it
+    from spark_rapids_tpu.plan.overrides import _pushdown_pass
+    _pushdown_pass(plan, session.cache_manager)
     session.last_scan_stats = None  # per-query: no stale sharded stats
     session.last_fusion_stats = None  # per-query fusion attribution
     from spark_rapids_tpu.parallel import exchange_async as _xa

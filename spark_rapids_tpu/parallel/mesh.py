@@ -43,30 +43,14 @@ DATA_AXIS = "data"
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma=False):
-    """Version portability wrapper for ``jax.shard_map``: older jax
-    releases ship it as ``jax.experimental.shard_map.shard_map`` with
-    the ``check_vma`` knob still named ``check_rep``.  Every SPMD
-    module routes through here so the engine runs on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with the engine's default ``check_vma=False``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(n_devices: Optional[int] = None,
               axis_name: str = DATA_AXIS) -> Mesh:
     devices = jax.devices()
-    if n_devices is not None and len(devices) < n_devices:
-        # single-TPU dev boxes: fall back to the virtual CPU mesh (the
-        # xla_force_host_platform_device_count path used by dry runs/tests)
-        try:
-            cpu = jax.devices("cpu")
-            if len(cpu) >= n_devices:
-                devices = cpu
-        except RuntimeError:
-            pass
     if n_devices is not None:
         if len(devices) < n_devices:
             raise ValueError(

@@ -155,7 +155,7 @@ def layout_by_partition(cols: Sequence[ColVal], pids: jnp.ndarray,
     capacity = pids.shape[0]
     row_mask = jnp.arange(capacity, dtype=jnp.int32) < nrows
     sort_key = jnp.where(row_mask, pids, num_parts)
-    perm = jnp.argsort(sort_key, stable=True).astype(jnp.int32)
+    perm = selection.lexsort_i32([sort_key])
     sorted_cols = selection.gather(cols, perm, nrows)
     # per-destination counts: pallas one-hot accumulation on TPU (XLA's
     # segment_sum lowers to a serialized scatter there), one-hot matmul

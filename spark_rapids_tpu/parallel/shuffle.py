@@ -9,19 +9,23 @@ connection management, and retry logic disappear — the collective is
 compiled into the XLA program.
 
 Wire format (the fused data path): all fixed-width columns of a batch are
-byte-reinterpreted (``jax.lax.bitcast_convert_type`` — always to *narrower*
-lanes, because the TPU X64 rewriter cannot lower 64<->64 float/int
-bitcasts) into width-homogeneous lane groups:
+gathered into width-homogeneous lane groups:
 
-* **u32 group** — 4-byte columns contribute one uint32 lane, 8-byte
-  columns two; payload shape ``[num_parts, slot, lanes32]``.
+* **u32 group** — 4-byte columns and int64 are byte-reinterpreted
+  (``jax.lax.bitcast_convert_type``) to one / two uint32 lanes; payload
+  shape ``[num_parts, slot, lanes32]``.
 * **u8 group** — bool/int8 columns contribute one uint8 lane, int16 two,
   and every validity mask is bit-packed eight-to-a-lane at the tail;
   payload shape ``[num_parts, slot, lanes8]``.
+* **f64 group** — float64 columns ride as themselves, one lane each,
+  payload shape ``[num_parts, slot, lanes64]``: the TPU X64 rewriter
+  refuses every ``bitcast-convert`` whose operand is an f64 (to u32, to
+  u8 and to int64 alike), so a double is never reinterpreted on the
+  send side.
 
 Each group moves with ONE ``all_to_all`` and the slice→dense compaction
 index map is computed once per exchange and shared by every lane — an
-exchange costs O(distinct widths) ≤ 2 collectives plus the counts vector,
+exchange costs O(distinct widths) ≤ 3 collectives plus the counts vector,
 instead of O(columns + validity masks).  ``packed.enabled=false`` (or an
 unpackable column) falls back to the per-column collectives, which still
 reuse the shared compaction indices.
@@ -308,6 +312,9 @@ def packed_enabled(conf=None) -> bool:
 
 _U32 = "u32"
 _U8 = "u8"
+_F64 = "f64"
+# wire bytes per lane of each group
+_LANE_BYTES = {_U32: 4, _U8: 1, _F64: 8}
 
 
 class _PackPlan:
@@ -322,10 +329,13 @@ class _PackPlan:
         self.col_dtype = [c.values.dtype for c in cols]
         self.valid_bit: List[Optional[int]] = []
         import numpy as np
-        n32 = n8 = nbits = 0
+        n32 = n8 = n64 = nbits = 0
         for c in cols:
             w = np.dtype(c.values.dtype).itemsize
-            if w in (4, 8):
+            if c.values.dtype == jnp.float64:
+                grp, lanes, n64 = _F64, 1, n64 + 1
+                self.col_start.append(n64 - 1)
+            elif w in (4, 8):
                 grp, lanes, n32 = _U32, w // 4, n32 + w // 4
                 self.col_start.append(n32 - w // 4)
             elif w in (1, 2):
@@ -343,11 +353,18 @@ class _PackPlan:
         self.n32 = n32
         self.n8_data = n8
         self.n8 = n8 + (nbits + 7) // 8
+        self.n64 = n64
+
+    @property
+    def lanes(self) -> Dict[str, int]:
+        """Lane count of every group this plan ships."""
+        return {g: n for g, n in ((_U32, self.n32), (_U8, self.n8),
+                                  (_F64, self.n64)) if n}
 
     @property
     def collectives(self) -> int:
         """Data collectives this plan launches (counts vector excluded)."""
-        return (1 if self.n32 else 0) + (1 if self.n8 else 0)
+        return len(self.lanes)
 
 
 class _Unpackable(Exception):
@@ -355,7 +372,7 @@ class _Unpackable(Exception):
 
 
 # site -> trace-time lane report ({"collectives", "row_bytes",
-# "row_bytes32", "row_bytes8"}): the EXACT wire cost of the program a
+# "group_row_bytes"}): the EXACT wire cost of the program a
 # consumer site compiled, recorded by the exchange body itself (it
 # alone sees runtime dtypes/nullability).  Keyed by the consumer's jit
 # signature, so it persists across consumer reconstruction exactly as
@@ -388,8 +405,9 @@ def _record_wire_report(site, cols, plan, surplus_rounds: int = 0,
         # a ragged plan adds one collective-permute per surplus round
         # per width group on top of the base all_to_alls
         collectives = 1 + plan.collectives * (1 + surplus_rounds)
-        row_bytes = 4 * plan.n32 + plan.n8
-        rb32, rb8 = 4 * plan.n32, plan.n8
+        group_row_bytes = {g: _LANE_BYTES[g] * n
+                           for g, n in plan.lanes.items()}
+        row_bytes = sum(group_row_bytes.values())
     else:
         # per-column wire: one collective per column + mask; validity
         # rides as full bool lanes (1 byte/row), not bit-packed
@@ -397,14 +415,14 @@ def _record_wire_report(site, cols, plan, surplus_rounds: int = 0,
         row_bytes = sum(
             max(np.dtype(c.values.dtype).itemsize, 1) for c in cols) \
             + nullable
-        rb32, rb8 = 0, 0
+        group_row_bytes = {}
     # saved_per_row: bytes/row the wire-encoding narrow transform shaved
     # BEFORE packing — cols already hold the narrowed dtypes, so
     # row_bytes above is the true (post-encoding) wire cost and this
     # field attributes the delta (encodedBytesSaved)
     _WIRE_REPORTS[site] = {"collectives": collectives,
                            "row_bytes": row_bytes,
-                           "row_bytes32": rb32, "row_bytes8": rb8,
+                           "group_row_bytes": group_row_bytes,
                            "row_bytes_saved": saved_per_row,
                            "fallback": fallback}
 
@@ -455,21 +473,25 @@ def _plan_pack(cols: Sequence[ColVal]) -> Optional[_PackPlan]:
 
 
 def _pack_payloads(cols: Sequence[ColVal], plan: _PackPlan, sel=None):
-    """Build the (u32, u8) lane payloads.  ``sel`` is an optional gather
-    index array (the padded-slot send layout); lanes inherit its shape
-    with one trailing lane axis."""
+    """Build the lane payloads, ``{group: [..., lanes]}`` for every group
+    the plan ships.  ``sel`` is an optional gather index array (the
+    padded-slot send layout); lanes inherit its shape with one trailing
+    lane axis."""
 
     def take(a):
         return a if sel is None else a[sel]
 
     lanes32: List[jnp.ndarray] = [None] * plan.n32
     lanes8: List[jnp.ndarray] = [None] * plan.n8
+    lanes64: List[jnp.ndarray] = [None] * plan.n64
     shape = None
     for c, grp, start, nlanes in zip(cols, plan.col_group, plan.col_start,
                                      plan.col_lanes):
         send = take(c.values)
         shape = send.shape
-        if grp == _U32:
+        if grp == _F64:
+            lanes64[start] = send
+        elif grp == _U32:
             if nlanes == 1:
                 lanes32[start] = jax.lax.bitcast_convert_type(
                     send, jnp.uint32)
@@ -494,20 +516,24 @@ def _pack_payloads(cols: Sequence[ColVal], plan: _PackPlan, sel=None):
         lane = plan.n8_data + bit // 8
         lanes8[lane] = lanes8[lane] | jnp.left_shift(
             take(c.validity).astype(jnp.uint8), jnp.uint8(bit % 8))
-    p32 = jnp.stack(lanes32, axis=-1) if lanes32 else None
-    p8 = jnp.stack(lanes8, axis=-1) if lanes8 else None
-    return p32, p8
+    return {g: jnp.stack(lanes, axis=-1)
+            for g, lanes in ((_U32, lanes32), (_U8, lanes8),
+                             (_F64, lanes64)) if lanes}
 
 
 def _unpack_payloads(cols: Sequence[ColVal], plan: _PackPlan,
-                     flat32, flat8, in_range) -> List[ColVal]:
+                     flat: Dict[str, jnp.ndarray],
+                     in_range) -> List[ColVal]:
     """Invert :func:`_pack_payloads` on already index-compacted lane
-    matrices (``flat32``: [cap, lanes32], ``flat8``: [cap, lanes8])."""
+    matrices (``flat[group]``: [cap, lanes])."""
+    flat32, flat8 = flat.get(_U32), flat.get(_U8)
     out: List[ColVal] = []
     for c, grp, start, nlanes, bit in zip(
             cols, plan.col_group, plan.col_start, plan.col_lanes,
             plan.valid_bit):
-        if grp == _U32:
+        if grp == _F64:
+            vals = flat[_F64][:, start]
+        elif grp == _U32:
             sub = flat32[:, start:start + nlanes]
             vals = jax.lax.bitcast_convert_type(
                 sub[:, 0] if nlanes == 1 else sub, c.values.dtype)
@@ -534,24 +560,23 @@ class WirePayload:
     :func:`pack_for_wire` inside the SAME traced program as the compute
     that fed it: partition-sorted columns, narrowed code columns, the
     per-destination counts, and (when the lane packer accepts the
-    columns) the (u32, u8) lane payloads in the padded-slot send
+    columns) the per-group lane payloads in the padded-slot send
     layout.  ``exchange`` composes this with the all_to_all and the
     receive-side unpack; a fused distributed stage emits it without any
     intermediate dispatch boundary."""
 
     __slots__ = ("cols", "narrowed", "counts", "starts", "src",
-                 "plan", "p32", "p8")
+                 "plan", "payloads")
 
     def __init__(self, cols, narrowed, counts, starts, src, plan,
-                 p32, p8):
+                 payloads):
         self.cols = cols
         self.narrowed = narrowed
         self.counts = counts
         self.starts = starts
         self.src = src
         self.plan = plan
-        self.p32 = p32
-        self.p8 = p8
+        self.payloads = payloads
 
 
 def pack_for_wire(cols: Sequence[ColVal], pids: jnp.ndarray, nrows,
@@ -573,11 +598,11 @@ def pack_for_wire(cols: Sequence[ColVal], pids: jnp.ndarray, nrows,
     j = jnp.arange(slot, dtype=jnp.int32)[None, :]
     src = jnp.clip(starts[:, None] + j, 0, capacity - 1)
     plan = _plan_pack(sorted_cols) if packed else None
-    p32 = p8 = None
+    payloads = None
     if plan is not None:
-        p32, p8 = _pack_payloads(sorted_cols, plan, sel=src)
+        payloads = _pack_payloads(sorted_cols, plan, sel=src)
     return WirePayload(sorted_cols, narrowed, counts, starts, src,
-                       plan, p32, p8)
+                       plan, payloads)
 
 
 def _compaction_indices(recv_counts, total, num_parts: int, slot: int):
@@ -689,18 +714,10 @@ def exchange(cols: Sequence[ColVal], pids: jnp.ndarray, nrows,
         # per launch; a nonzero count is the signal, not a launch tally.
         metrics_for_session().record_fallback()
     if plan is not None:
-        p32, p8 = pay.p32, pay.p8
-        flat32 = flat8 = None
-        if p32 is not None:
-            r32 = jax.lax.all_to_all(p32, axis_name, split_axis=0,
-                                     concat_axis=0)
-            flat32 = r32[part, offset]
-        if p8 is not None:
-            r8 = jax.lax.all_to_all(p8, axis_name, split_axis=0,
-                                    concat_axis=0)
-            flat8 = r8[part, offset]
-        out_cols = _unpack_payloads(sorted_cols, plan, flat32, flat8,
-                                    in_range)
+        flat = {g: jax.lax.all_to_all(p, axis_name, split_axis=0,
+                                      concat_axis=0)[part, offset]
+                for g, p in pay.payloads.items()}
+        out_cols = _unpack_payloads(sorted_cols, plan, flat, in_range)
     else:
         out_cols = []
         for c in sorted_cols:
@@ -746,26 +763,22 @@ def _exchange_ragged(sorted_cols, plan, counts, recv_counts, starts,
     # base payloads: the uniform wire at the COLD slot
     j = jnp.arange(base, dtype=jnp.int32)[None, :]
     src = jnp.clip(starts[:, None] + j, 0, capacity - 1)
-    p32, p8 = _pack_payloads(sorted_cols, plan, sel=src)
-    r32 = jax.lax.all_to_all(p32, axis_name, split_axis=0,
-                             concat_axis=0) if p32 is not None else None
-    r8 = jax.lax.all_to_all(p8, axis_name, split_axis=0,
-                            concat_axis=0) if p8 is not None else None
+    rbase = {g: jax.lax.all_to_all(p, axis_name, split_axis=0,
+                                   concat_axis=0)
+             for g, p in _pack_payloads(sorted_cols, plan,
+                                        sel=src).items()}
 
     # surplus rounds: each round is a partial permutation; a shard not
     # in the round still traces the (garbage) buffer but the
     # collective-permute transmits only the named links
-    s32_rounds, s8_rounds = [], []
+    sur_rounds = {g: [] for g in rbase}
     jj = jnp.arange(sur, dtype=jnp.int32)
     for r, rnd in enumerate(rp.rounds):
         my_dst = jnp.asarray(rp.round_dst_by_src[r])[me]
         sel = jnp.clip(starts[my_dst] + base + jj, 0, capacity - 1)
-        q32, q8 = _pack_payloads(sorted_cols, plan, sel=sel)
         perm = [tuple(p) for p in rnd]
-        if q32 is not None:
-            s32_rounds.append(jax.lax.ppermute(q32, axis_name, perm=perm))
-        if q8 is not None:
-            s8_rounds.append(jax.lax.ppermute(q8, axis_name, perm=perm))
+        for g, q in _pack_payloads(sorted_cols, plan, sel=sel).items():
+            sur_rounds[g].append(jax.lax.ppermute(q, axis_name, perm=perm))
 
     # receive: offset < base reads the all_to_all slice; beyond it, the
     # surplus buffer of the (src -> me) pair via the static round table
@@ -774,8 +787,6 @@ def _exchange_ragged(sorted_cols, plan, counts, recv_counts, starts,
     so = jnp.clip(offset - base, 0, sur - 1)
 
     def combine(rbase, rounds_list):
-        if rbase is None:
-            return None
         if rounds_list:
             stacked = jnp.stack(rounds_list)          # [rounds, sur, l]
         else:
@@ -785,10 +796,8 @@ def _exchange_ragged(sorted_cols, plan, counts, recv_counts, starts,
         pick = (offset < base)
         return jnp.where(pick[:, None], base_v, sur_v)
 
-    flat32 = combine(r32, s32_rounds)
-    flat8 = combine(r8, s8_rounds)
-    out_cols = _unpack_payloads(sorted_cols, plan, flat32, flat8,
-                                in_range)
+    flat = {g: combine(r, sur_rounds[g]) for g, r in rbase.items()}
+    out_cols = _unpack_payloads(sorted_cols, plan, flat, in_range)
     if with_overflow:
         limits = jnp.asarray(rp.limits)[me]           # [n_dst]
         return out_cols, total, jnp.any(counts > limits)
@@ -862,14 +871,10 @@ def all_gather_cols(cols: Sequence[ColVal], nrows, axis_name: str,
     if packed and plan is None and cols:
         metrics_for_session().record_fallback()  # see exchange()
     if plan is not None:
-        p32, p8 = _pack_payloads(cols, plan)
-        flat32 = flat8 = None
-        if p32 is not None:
-            flat32 = jax.lax.all_gather(p32, axis_name)[part, offset]
-        if p8 is not None:
-            flat8 = jax.lax.all_gather(p8, axis_name)[part, offset]
+        flat = {g: jax.lax.all_gather(p, axis_name)[part, offset]
+                for g, p in _pack_payloads(cols, plan).items()}
         return _widen_wire_cols(
-            _unpack_payloads(cols, plan, flat32, flat8, in_range),
+            _unpack_payloads(cols, plan, flat, in_range),
             narrowed), total
     out_cols: List[ColVal] = []
     for c in cols:
@@ -1344,10 +1349,12 @@ def estimate_collectives(dtypes, packed: bool,
     n = len(dtypes) if nullable is None else nullable
     if not packed:
         return 1 + len(dtypes) + n
-    widths = [np.dtype(dt.storage).itemsize for dt in dtypes]
-    has32 = any(w in (4, 8) for w in widths)
-    has8 = any(w in (1, 2) for w in widths) or n > 0
-    return 1 + int(has32) + int(has8)
+    storages = [np.dtype(dt.storage) for dt in dtypes]
+    has64 = any(st == np.float64 for st in storages)
+    has32 = any(st.itemsize in (4, 8) and st != np.float64
+                for st in storages)
+    has8 = any(st.itemsize in (1, 2) for st in storages) or n > 0
+    return 1 + int(has32) + int(has8) + int(has64)
 
 
 def record_exchange_metrics(metrics: ShuffleWireMetrics, *, dtypes,
@@ -1397,7 +1404,7 @@ def record_exchange_metrics(metrics: ShuffleWireMetrics, *, dtypes,
     if rep is not None:
         collectives = rep["collectives"]
         row_bytes = rep["row_bytes"]
-        rb32, rb8 = rep.get("row_bytes32", 0), rep.get("row_bytes8", 0)
+        group_row_bytes = rep.get("group_row_bytes", {})
         saved_pr = rep.get("row_bytes_saved", 0)
     else:
         collectives = estimate_collectives(dtypes, packed, nullable)
@@ -1405,10 +1412,10 @@ def record_exchange_metrics(metrics: ShuffleWireMetrics, *, dtypes,
         # one i32 lane instead of two
         saved_pr = 4 * int(wire_encode_cols)
         row_bytes = max(wire_row_bytes(dtypes, nullable) - saved_pr, 0)
-        rb32 = rb8 = 0
-    if rb32 or rb8:
+        group_row_bytes = {}
+    if group_row_bytes:
         group_bytes = {g: rows_moved * rb
-                       for g, rb in (("u32", rb32), ("u8", rb8)) if rb}
+                       for g, rb in group_row_bytes.items()}
     else:
         group_bytes = {"percol": rows_moved * row_bytes}
     per_dest = None

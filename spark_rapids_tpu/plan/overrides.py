@@ -859,7 +859,8 @@ def _conv_map_in_pandas(node: L.MapInPandas, children, conf):
 def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
     """Column pruning + predicate pushdown into FileRelations.
 
-    Pruned columns are only those dropped by a Project/Aggregate above, so
+    Pruned columns are only those dropped by a Project/Aggregate above
+    (the requirement passes through Filters and Joins by name), so
     BoundReference ordinals stay valid (the scan emits null placeholders
     for unread columns, which by construction nothing references).
     Filters push down until a Project renames the namespace.
@@ -872,6 +873,7 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
     the shared FileRelation nodes).
     """
     barrier_entered: set = set()
+    scanned: set = set()  # FileRelations this pass has already visited
 
     def visit(node, required, filters):
         if cache_manager is not None and id(node) not in barrier_entered \
@@ -880,8 +882,18 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
             visit(node, None, [])
             return
         if isinstance(node, L.FileRelation):
-            if required is not None:
-                node.required_columns = set(required)
+            # a view's FileRelation is shared: across queries, so both
+            # fields are overwritten on the pass's first visit (None =
+            # "all"); and within one query that scans the view twice,
+            # so a second visit reads what either scan needs and pushes
+            # no filter only one of them has
+            req = None if required is None else set(required)
+            if id(node) in scanned:
+                prev = node.required_columns
+                req = None if req is None or prev is None else req | prev
+                filters = []
+            scanned.add(id(node))
+            node.required_columns = req
             node.pushed_filters = list(filters)
             return
         if isinstance(node, L.Filter):
@@ -892,6 +904,14 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
         if isinstance(node, L.Project):
             refs = set()
             for e in node.exprs:
+                # a column passed through or renamed that nothing above
+                # reads (the SQL resolver's join-deduplication renames)
+                # asks nothing of the scan; a computed expression always
+                # does — it still runs, and must see real values
+                bare = e.child if isinstance(e, Alias) else e
+                if required is not None and e.name not in required \
+                        and isinstance(bare, BoundReference):
+                    continue
                 refs.update(e.references())
             visit(node.child, refs, [])
             return
@@ -900,6 +920,29 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
             for e in list(node.group_exprs) + list(node.agg_exprs):
                 refs.update(e.references())
             visit(node.child, refs, [])
+            return
+        if isinstance(node, L.Sort) and required is not None:
+            req = set(required)
+            for e, _, _ in node.orders:
+                req.update(e.references())
+            visit(node.child, req, [])
+            return
+        if isinstance(node, L.Limit):
+            visit(node.child, required, [])
+            return
+        if isinstance(node, L.Join) and required is not None:
+            # each side reads its join keys plus whatever of it the
+            # condition and the operators above reference; a name both
+            # sides carry is kept on both
+            above = set(required)
+            if node.condition is not None:
+                above.update(node.condition.references())
+            for child, keys in ((node.left, node.left_keys),
+                                (node.right, node.right_keys)):
+                need = {n for n, _ in child.schema if n in above}
+                for k in keys:
+                    need.update(k.references())
+                visit(child, need, [])
             return
         for c in node.children:
             visit(c, None, [])
@@ -962,10 +1005,14 @@ class TpuOverrides:
         return self._chain_nodes_by_ident.setdefault(self._ident(),
                                                      set())
 
-    def apply(self, plan: L.LogicalPlan):
+    def apply(self, plan: L.LogicalPlan, pushdown: bool = True):
+        """``pushdown=False`` plans a scan its owner already annotated
+        (the distributed planner's per-shard reads of one query's
+        FileRelation); the pass would reset what it copied."""
         global _planning_passes
         _planning_passes += 1
-        _pushdown_pass(plan, self.cache_manager)
+        if pushdown:
+            _pushdown_pass(plan, self.cache_manager)
         meta = PlanMeta(plan, self.conf)
         meta.tag()
         ident = self._ident()

@@ -44,6 +44,8 @@ in-flight query id), feeding ``tools/profiling`` health checks.
 from __future__ import annotations
 
 import itertools
+import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -275,6 +277,23 @@ def _ensure_monitor() -> None:
         _monitor.start()
 
 
+# every XLA compilation runs under a frame of this JAX module
+_JAX_COMPILER_FILE = os.path.join("jax", "_src", "compiler.py")
+
+
+def _compiling(ident: int) -> bool:
+    """Is thread ``ident`` inside JAX's compiler right now?  Read off
+    the thread's own stack: any jit path compiles there (``cached_jit``
+    entries, bare ``@jax.jit`` kernels and eager ops alike), and JAX
+    announces a compilation only once it is over."""
+    frame = sys._current_frames().get(ident)
+    while frame is not None:
+        if frame.f_code.co_filename.endswith(_JAX_COMPILER_FILE):
+            return True
+        frame = frame.f_back
+    return False
+
+
 def _monitor_loop() -> None:
     global _any_pending
     while True:
@@ -285,6 +304,14 @@ def _monitor_loop() -> None:
         for s in active:
             if s.tripped:
                 continue
+            if now >= s.deadline_at and _compiling(s.opener):
+                # XLA compilation is work, not a hang: on a TPU one
+                # 64-bit sort program compiles for minutes, and a cold
+                # join -> aggregate -> sort pipeline emits no batch
+                # until several have.  Re-arm as a heartbeat would; a
+                # compiler that truly never returns is beyond
+                # cooperative cancellation anyway.
+                s.beat()
             if now >= s.deadline_at:
                 s.tripped = True
                 elapsed_ms = (now - s.started) * 1e3

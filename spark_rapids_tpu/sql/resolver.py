@@ -100,19 +100,25 @@ class Resolver:
     def _select(self, stmt: A.SelectStmt):
         F = self.F
         scope = Scope()
+        # WHERE conjuncts over one source of an inner-join chain filter
+        # that source BEFORE the joins (Spark's PushPredicateThroughJoin)
+        pushed = self._single_source_conjuncts(stmt)
+        pushed_ids = {id(c) for cs in pushed.values() for c in cs}
         if stmt.from_ is None:
             df = self.session.range(1)
             scope.add(None, ["id"])
         else:
-            df = self._from_item(stmt.from_, scope)
+            df = self._from_item(stmt.from_, scope, pushed)
         for j in stmt.joins:
-            df = self._join(df, j, scope)
+            df = self._join(df, j, scope, pushed)
         if stmt.where is not None:
             # top-level conjuncts that are IN (subquery) become
             # semi/anti joins (Spark's RewritePredicateSubquery); the
             # rest filter normally
             residual = None
             for conj in self._split_conjuncts(stmt.where):
+                if id(conj) in pushed_ids:
+                    continue
                 if isinstance(conj, A.InSubquery):
                     df = self._in_subquery_join(df, conj, scope)
                     continue
@@ -318,6 +324,64 @@ class Resolver:
         else:
             yield node
 
+    def _single_source_conjuncts(self, stmt: A.SelectStmt) -> Dict:
+        """alias -> the top-level WHERE conjuncts that reference columns
+        of that FROM/JOIN source only.  Only for a chain of inner joins
+        (a filter on one side commutes with an inner join; an outer
+        join's null-extended side does not), and only where every
+        column reference is attributable without building the sources:
+        qualified by a known alias, or a bare name that exactly one
+        table has.  Everything else stays above the joins."""
+        if stmt.where is None or not stmt.joins or \
+                any(j.how != "inner" for j in stmt.joins):
+            return {}
+        items = [stmt.from_] + [j.right for j in stmt.joins]
+        aliases = [getattr(i, "alias", None) or getattr(i, "name", None)
+                   for i in items]
+        if len(set(aliases)) != len(aliases):
+            return {}
+        # a derived table's columns are unknown until it is built, so
+        # with one in the FROM clause only qualified names attribute
+        all_tables = all(isinstance(i, A.TableRef) for i in items)
+        columns = {a: {n for n, _ in self.session.table(i.name).schema}
+                   for a, i in zip(aliases, items)
+                   if isinstance(i, A.TableRef)}
+
+        def source_of(ref: A.ColRef):
+            if len(ref.parts) >= 2 and ref.parts[0] in aliases:
+                return ref.parts[0]
+            if not all_tables:
+                return None
+            has = [a for a in aliases if ref.parts[0] in columns[a]]
+            return has[0] if len(has) == 1 else None
+
+        def sources(node, out) -> bool:
+            """Collect the sources ``node`` references into ``out``;
+            False when it cannot be pushed (subquery, window, or an
+            unattributable name)."""
+            if isinstance(node, A.ColRef):
+                out.add(source_of(node))
+                return None not in out
+            if isinstance(node, (A.ScalarSubquery, A.InSubquery)) or \
+                    getattr(node, "window", None) is not None:
+                return False
+            for f in getattr(node, "__dataclass_fields__", {}):
+                v = getattr(node, f)
+                for x in (v if isinstance(v, list) else [v]):
+                    if hasattr(x, "__dataclass_fields__") and \
+                            not sources(x, out):
+                        return False
+            return True
+
+        pushed: Dict[str, list] = {}
+        for conj in self._split_conjuncts(stmt.where):
+            # x IN (uncorrelated subquery): only x names a source
+            probe = conj.child if isinstance(conj, A.InSubquery) else conj
+            found: set = set()
+            if sources(probe, found) and len(found) == 1:
+                pushed.setdefault(found.pop(), []).append(conj)
+        return pushed
+
     def _in_subquery_join(self, df, node: A.InSubquery, scope: Scope):
         """x IN (SELECT k FROM ...) -> semi join; NOT IN -> null-aware
         anti (SQL three-valued semantics: a NULL anywhere in the
@@ -349,19 +413,28 @@ class Resolver:
         return df.join(sub, on=key == F.col(rname), how="semi")
 
     # ------------------------------------------------------------- from --
-    def _from_item(self, item, scope: Scope):
+    def _from_item(self, item, scope: Scope, pushed=None):
         if isinstance(item, A.SubqueryRef):
-            sub = self._select(item.query)
-            scope.add(item.alias, [n for n, _ in sub.schema])
-            return sub
-        df = self.session.table(item.name)
+            df = self._select(item.query)
+            alias = item.alias
+        else:
+            df = self.session.table(item.name)
+            alias = item.alias or item.name
         cols = [n for n, _ in df.schema]
-        scope.add(item.alias or item.name, cols)
+        scope.add(alias, cols)
+        # this source's own WHERE conjuncts (_single_source_conjuncts)
+        own = Scope()
+        own.add(alias, cols)
+        for conj in (pushed or {}).get(alias, ()):
+            if isinstance(conj, A.InSubquery):
+                df = self._in_subquery_join(df, conj, own)
+            else:
+                df = df.filter(self._expr(conj, own))
         return df
 
-    def _join(self, left, j: A.JoinClause, scope: Scope):
+    def _join(self, left, j: A.JoinClause, scope: Scope, pushed=None):
         right_scope = Scope()
-        right = self._from_item(j.right, right_scope)
+        right = self._from_item(j.right, right_scope, pushed)
         ralias, rmap = right_scope.sources[0]
         rcols = list(rmap)
         if j.how == "cross":

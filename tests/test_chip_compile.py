@@ -1,0 +1,160 @@
+"""What the chip's compiler says about the main path, asked without a chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is described, not attached (``v5e:2x2``).  Nothing runs here: these tests
+only prove the programs COMPILE for the chip — a kernel Mosaic refuses or
+an op the X64 rewriter cannot lower fails here, at no chip time.  The
+topology is described inside the module-scoped fixture only (never at
+import): one process at a time may load the TPU library, and under
+pytest-xdist every worker imports this file.
+
+No 64-bit sort program is compiled here: each takes the chip's compiler
+one to two minutes.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from spark_rapids_tpu.columnar import dtypes as dts
+from spark_rapids_tpu.ops import pallas_kernels as pk
+from spark_rapids_tpu.ops.expressions import ColVal
+from spark_rapids_tpu.parallel import shuffle
+from spark_rapids_tpu.parallel.mesh import shard_map
+
+ROWS = 1 << 22
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("parts", [4, 8, 16])
+def test_partition_histogram_compiles(one_chip, parts):
+    compiled = jax.jit(
+        lambda pids, mask: pk.partition_histogram(
+            pids, mask, parts, interpret=False)
+    ).lower(_spec((ROWS,), jnp.int32, one_chip),
+            _spec((ROWS,), jnp.bool_, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# an int64 key, a double, a flag and a date, the first two nullable
+WIRE_DTYPES = [dts.INT64, dts.FLOAT64, dts.BOOL, dts.INT32]
+WIRE_NULLABLE = [True, True, False, False]
+
+
+def _wire_cols(values, masks):
+    return [ColVal(dt, v, m if nullable else None)
+            for dt, v, m, nullable
+            in zip(WIRE_DTYPES, values, masks, WIRE_NULLABLE)]
+
+
+def _wire_specs(rows, sharding):
+    values = tuple(_spec((rows,), dt.storage, sharding)
+                   for dt in WIRE_DTYPES)
+    masks = tuple(_spec((rows,), jnp.bool_, sharding)
+                  for _ in WIRE_DTYPES)
+    return values, masks
+
+
+def test_shuffle_pack_and_unpack_compile(one_chip):
+    """The packed wire's send and receive sides, each its own program so
+    the compiler cannot cancel a cast against its inverse.  A double
+    rides the f64 lane group as itself: the X64 rewriter refuses every
+    ``bitcast-convert`` of an f64 operand."""
+    values, masks = _wire_specs(ROWS, one_chip)
+    plan = shuffle._plan_pack(_wire_cols(values, masks))
+    assert plan.lanes == {"u32": 3, "u8": 2, "f64": 1}
+
+    def pack(values, masks):
+        return shuffle._pack_payloads(_wire_cols(values, masks), plan)
+
+    packed = jax.jit(pack).lower(values, masks).compile()
+    payloads = {g: _spec(s.shape, s.dtype, one_chip) for g, s
+                in jax.eval_shape(pack, values, masks).items()}
+
+    def unpack(flat, in_range):
+        cols = shuffle._unpack_payloads(
+            _wire_cols(values, masks), plan, flat, in_range)
+        return [(c.values, c.validity) for c in cols]
+
+    unpacked = jax.jit(unpack).lower(
+        payloads, _spec((ROWS,), jnp.bool_, one_chip)).compile()
+    for compiled in (packed, unpacked):
+        assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_fused_q6_stage_compiles(one_chip, monkeypatch):
+    """The flagship fused filter+project+reduce stage at 2^23 rows."""
+    import __graft_entry__ as g
+    from spark_rapids_tpu.utils import compile_cache
+    # tests keep no compile cache: a program compiled for a described
+    # chip is written to it but cannot be read back without one
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
+    fn, example = g.entry()
+    rows = 1 << 23
+    args = [_spec((rows,), a.dtype, one_chip) for a in example[:-1]]
+    args.append(_spec((), example[-1].dtype, one_chip))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_four_chip_exchange_compiles(topo, monkeypatch):
+    """The shard_map exchange around ``lax.all_to_all`` on a 4-device
+    mesh, as the chip runs it: packed wire, Pallas histogram."""
+    # use_pallas() asks jax.default_backend(), which is the CPU here:
+    # steer it from the test so the program is the chip's
+    on_chip = lambda: True  # noqa: E731
+    on_chip.cache_clear = lambda: None
+    monkeypatch.setattr(pk, "use_pallas", on_chip)
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+    cap, slot = 1 << 21, 1 << 20     # SF1 lineitem is 1.5M rows a shard
+    values, masks = _wire_specs(4 * cap, sharded)
+
+    def step(values, masks, pids, nrows):
+        out, total, overflow = shuffle.exchange(
+            _wire_cols(values, masks), pids, nrows[0], "data", 4,
+            slot=slot, packed=True, with_overflow=True)
+        return ([(c.values, c.validity) for c in out],
+                total.reshape(1), overflow.reshape(1))
+
+    fn = shard_map(step, mesh=mesh,
+                   in_specs=(P("data"),) * 4, out_specs=P("data"))
+    compiled = jax.jit(fn).lower(
+        values, masks, _spec((4 * cap,), jnp.int32, sharded),
+        _spec((4,), jnp.int32, sharded)).compile()
+    text = compiled.as_text()
+    assert "all-to-all" in text
+    assert "tpu_custom_call" in text
+    assert _device_bytes(compiled) < HBM_BYTES

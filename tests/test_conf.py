@@ -193,7 +193,7 @@ def test_suppress_planning_failure():
     plan = df.orderBy("x").plan
 
     class Boom:
-        def apply(self, logical):
+        def apply(self, logical, pushdown=True):
             raise RuntimeError("planner bug")
     real = s.overrides
     s.overrides = Boom()

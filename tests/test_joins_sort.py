@@ -204,3 +204,30 @@ def test_sort_strings_runs_native(session):
     assert "CpuFallbackExec" not in tree
     assert "TpuSortExec" in tree
     assert q.to_pandas()["s"].tolist() == ["a", "b", "c"]
+
+
+def test_lexsort_i32_is_jnp_lexsort_with_an_int32_index(rng):
+    """The engine's sort permutation: same order as ``jnp.lexsort``
+    (last key primary, stable), with the row index carried as int32 —
+    on a TPU the int64 index jnp carries under x64 is pure compile
+    time."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.selection import lexsort_i32
+    n = 5000
+    keys = [jnp.asarray(rng.integers(0, 7, n)),                  # int64
+            jnp.asarray(rng.normal(size=n).round(1)),            # f64, ties
+            jnp.asarray(rng.integers(0, 2, n).astype(np.int8))]  # flag
+    got = lexsort_i32(keys)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jnp.lexsort(keys)))
+    np.testing.assert_array_equal(
+        np.asarray(lexsort_i32(keys[:1])),
+        np.asarray(jnp.argsort(keys[0], stable=True)))
+    # dead rows last, by a stable partition after the sort: the same
+    # permutation as one more (most significant) sort key
+    dead = jnp.asarray(rng.random(n) < 0.3)
+    for ks in (keys, []):
+        np.testing.assert_array_equal(
+            np.asarray(lexsort_i32(ks, dead=dead)),
+            np.asarray(jnp.lexsort(ks + [dead.astype(jnp.int8)])))

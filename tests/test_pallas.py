@@ -29,25 +29,6 @@ def test_histogram_empty_mask(rng):
     assert np.asarray(got).sum() == 0
 
 
-@pytest.mark.parametrize("n,ncols", [(100, 1), (3000, 3), (1024, 2)])
-def test_masked_multi_reduce_matches_xla(rng, n, ncols):
-    vals = [jnp.asarray(rng.uniform(-10, 10, n)) for _ in range(ncols)]
-    valids = [jnp.asarray(rng.random(n) < 0.9) for _ in range(ncols)]
-    mask = jnp.asarray(rng.random(n) < 0.6)
-    s, c = pk.masked_multi_reduce(vals, valids, mask, interpret=True)
-    ws, wc = pk.masked_multi_reduce_xla(vals, valids, mask)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(ws), rtol=1e-12)
-    np.testing.assert_array_equal(np.asarray(c), np.asarray(wc))
-
-
-def test_masked_multi_reduce_all_masked(rng):
-    vals = [jnp.asarray(rng.uniform(size=256))]
-    valids = [jnp.ones(256, dtype=bool)]
-    mask = jnp.zeros(256, dtype=bool)
-    s, c = pk.masked_multi_reduce(vals, valids, mask, interpret=True)
-    assert float(s[0]) == 0.0 and int(c[0]) == 0
-
-
 def test_use_pallas_off_on_cpu():
     # conftest pins the cpu backend; dispatch must choose the XLA path
     assert not pk.use_pallas()

@@ -101,6 +101,48 @@ def test_left_join_and_semi(session):
     assert set(semi["c_id"]) == set(o["cust"].unique())
 
 
+def _filters_sit_below_joins(plan) -> bool:
+    from spark_rapids_tpu.plan import logical as L
+    if isinstance(plan, L.Filter) and \
+            any(isinstance(c, L.Join) for c in plan.children):
+        return False
+    return all(_filters_sit_below_joins(c) for c in plan.children)
+
+
+def test_where_filters_its_source_before_an_inner_join(session):
+    # qualified, and bare names that exactly one table has; the IN
+    # subquery's semi join lands on its own source too
+    df = session.sql(
+        "SELECT o.o_id, c.name FROM orders o "
+        "JOIN customers c ON o.cust = c.c_id "
+        "WHERE o.amount > 300 AND region = 1 "
+        "AND o.cust IN (SELECT c_id FROM customers WHERE c_id < 9) "
+        "ORDER BY o_id")
+    assert _filters_sit_below_joins(df.plan)
+    o, c = session._test_orders, session._test_cust
+    want = o[(o.amount > 300) & (o.cust < 9)] \
+        .merge(c[c.region == 1], left_on="cust", right_on="c_id") \
+        .sort_values("o_id")
+    assert len(want) > 0
+    got = df.to_pandas()
+    assert got["o_id"].tolist() == want["o_id"].tolist()
+    assert got["name"].tolist() == want["name"].tolist()
+
+
+def test_where_stays_above_an_outer_join(session):
+    # the anti-join idiom: a filter on the null-extended side of a LEFT
+    # JOIN does not commute with it
+    df = session.sql(
+        "SELECT c.c_id FROM customers c LEFT JOIN "
+        "(SELECT cust, amount FROM orders WHERE amount > 480) o "
+        "ON c.c_id = o.cust WHERE o.amount IS NULL ORDER BY c_id")
+    o, c = session._test_orders, session._test_cust
+    want = sorted(set(c.c_id) - set(o[o.amount > 480].cust))
+    assert 0 < len(want) < len(c)
+    assert df.to_pandas()["c_id"].tolist() == want
+    assert not _filters_sit_below_joins(df.plan)
+
+
 def test_using_join(session):
     session.sql("SELECT cust AS c_id, amount FROM orders") \
         .createOrReplaceTempView("o2")

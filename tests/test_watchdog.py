@@ -98,6 +98,28 @@ def test_heartbeat_extends_deadline():
     W.checkpoint()
 
 
+def test_compilation_is_not_silence():
+    # a section whose thread sits in XLA's compiler past the deadline
+    # does not trip (on a TPU one 64-bit sort compiles for minutes);
+    # the same wall time spent asleep does (the first test above)
+    import jax
+    import jax.numpy as jnp
+
+    def long_chain(x):
+        for i in range(1500):   # seconds of XLA:CPU compile, no loop
+            x = jnp.sin(x) * 1.0001 + i
+        return x
+
+    lowered = jax.jit(long_chain).lower(jnp.ones(8))  # traced out here
+    with W.section("pipeline.worker", deadline_ms=100) as s:
+        t0 = time.monotonic()
+        lowered.compile()
+        assert time.monotonic() - t0 > 0.3, "compile too quick to prove it"
+        assert not s.tripped
+    W.checkpoint()  # nothing parked
+    assert not W.watchdog_metrics.snapshot()["trips"]
+
+
 def test_delay_rule_wedges_until_disarmed_or_deadline():
     # a tripped deadline aborts the wedge cooperatively (the delay
     # loop is itself a checkpoint)
