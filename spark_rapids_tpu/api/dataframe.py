@@ -1043,9 +1043,7 @@ class DataFrame:
         finally:
             self.session.last_pipeline_stats = stats
 
-    def _run_single_process(self, mode,
-                            overrides=None) -> List[ColumnarBatch]:
-        import time as _time
+    def _plan_physical(self, mode, overrides=None):
         template = getattr(self, "_template", None)
         logical = template.plan if template is not None else self.plan
         prep = getattr(self, "_prepared", None)
@@ -1065,8 +1063,19 @@ class DataFrame:
         else:
             exec_plan = self.session.plan(logical,
                                           overrides=overrides)
-        self._last_exec = exec_plan
+        return exec_plan
+
+    def _run_single_process(self, mode,
+                            overrides=None) -> List[ColumnarBatch]:
+        import time as _time
         from spark_rapids_tpu.utils import tracing
+        # the envelope's wall starts BEFORE planning and planning is a
+        # span of its own, so the rollup's unattributedMs means what
+        # its name says
+        t0 = _time.perf_counter()
+        with tracing.span("plan.physical"):
+            exec_plan = self._plan_physical(mode, overrides)
+        self._last_exec = exec_plan
         events = getattr(self.session, "events", None)
         if events is None or not events.enabled:
             from spark_rapids_tpu.exec.fusion import (
@@ -1075,7 +1084,6 @@ class DataFrame:
             self.session._current_qid = None
             p0 = persistent_info()
             fm0 = fusion_metrics.snapshot()
-            t0 = _time.perf_counter()
             status = "success"
             try:
                 return self._drive(exec_plan)
@@ -1123,7 +1131,6 @@ class DataFrame:
         jit0 = cache_info()
         pjit0 = persistent_info()
         fm0 = fusion_metrics.snapshot()
-        t0 = _time.perf_counter()
         status = "success"
         try:
             return self._drive(exec_plan)

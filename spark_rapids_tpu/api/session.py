@@ -369,7 +369,8 @@ class TpuSession:
             trace_dir=trace_dir,
             max_events=self.conf.get(rc.TRACE_MAX_EVENTS),
             obs_dir=(self.conf.get(rc.JIT_CACHE_DIR) or trace_dir
-                     or None))
+                     or None),
+            profile=bool(self.conf.get(rc.PROFILE_TRACE)))
         # self-tuning cost-based planner (plan/costmodel.py): one
         # evidence-fed decision authority over every tuning knob,
         # default-off — None keeps every consumption site a single
@@ -774,12 +775,6 @@ class TpuSession:
                 exec_plan = self.plan_cpu_only(logical)
         else:
             exec_plan = ov.apply(logical, pushdown=pushdown)
-        if self.conf.get(rc.PROFILE_TRACE):
-            def mark(node):
-                node.trace_ops = True
-                for c in node.children:
-                    mark(c)
-            mark(exec_plan)
         return exec_plan
 
     def plan_cpu_only(self, logical: L.LogicalPlan):

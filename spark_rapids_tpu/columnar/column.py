@@ -170,15 +170,10 @@ class Column:
 
     # -------------------------------------------------------- buffer access --
     def _upload(self, np_buf):
-        """Host->device materialization (once per buffer).  Timed into
-        the pipeline's upload-overlap accounting when this thread is a
-        pipeline worker (utils/hostsync.watch_uploads)."""
-        import time
+        """Host->device materialization (once per buffer), counted
+        where it happens (utils/hostsync.upload)."""
         from spark_rapids_tpu.utils import hostsync
-        t0 = time.perf_counter_ns()
-        out = jnp.asarray(np_buf)
-        hostsync.note_upload(time.perf_counter_ns() - t0)
-        return out
+        return hostsync.upload(np_buf)
 
     @property
     def data(self):

@@ -150,9 +150,13 @@ TRACE_MAX_EVENTS = conf(
 
 PROFILE_TRACE = conf(
     "spark.rapids.tpu.profile.trace", False,
-    "Wrap each operator's execution in a jax.profiler TraceAnnotation so "
-    "per-op ranges appear in XPlane/perfetto captures (the NVTX-range "
-    "analog, NvtxWithMetrics.scala).", _to_bool)
+    "Enter a jax.profiler annotation for the lifetime of every "
+    "engine span (utils/tracing.py), named by the span's operator where "
+    "it has one (TpuFileScanExec) and by its point otherwise "
+    "(io.reader, hostsync.fetch, ...), so the engine's spans lie on the "
+    "profiler's clock beside the device's programs in XPlane/perfetto "
+    "captures (the NVTX-range analog, NvtxWithMetrics.scala).  Works "
+    "with or without spark.rapids.tpu.trace.enabled.", _to_bool)
 
 BATCH_SIZE_BYTES = conf(
     "spark.rapids.sql.batchSizeBytes", 1 << 31,

@@ -77,7 +77,7 @@ def _grouped_kernel(kinds: Tuple[str, ...], nkeys: int):
     """Group-by over pre-evaluated fixed-width (values, validity) columns."""
 
     @jax.jit
-    def run(keys_flat, bufs_flat, nrows):
+    def grouped_agg(keys_flat, bufs_flat, nrows):
         capacity = keys_flat[0][0].shape[0]
         keys = [ColVal(None, v, val) for v, val in keys_flat]
         buf_inputs = [(k, ColVal(None, v, val))
@@ -87,7 +87,7 @@ def _grouped_kernel(kinds: Tuple[str, ...], nkeys: int):
         return ([(k.values, k.validity) for k in out_keys],
                 [(b.values, b.validity) for b in out_bufs], n)
 
-    return run
+    return grouped_agg
 
 
 @functools.lru_cache(maxsize=None)
@@ -96,14 +96,14 @@ def _keyless_kernel(kinds: Tuple[str, ...]):
     staged path's keyless case, e.g. SELECT min(s))."""
 
     @jax.jit
-    def run(bufs_flat, nrows):
+    def keyless_agg(bufs_flat, nrows):
         capacity = bufs_flat[0][0].shape[0]
         buf_inputs = [(k, ColVal(None, v, val))
                       for k, (v, val) in zip(kinds, bufs_flat)]
         outs = agg.reduce_aggregate(buf_inputs, nrows, capacity)
         return [(o.values, o.validity) for o in outs]
 
-    return run
+    return keyless_agg
 
 
 @functools.lru_cache(maxsize=None)
@@ -114,7 +114,7 @@ def _coded_kernel(kinds: Tuple[str, ...], k_bucket: int):
     addressing + segment reduce."""
 
     @jax.jit
-    def run(keys_flat, bufs_flat, mins, slot_ranges, mask):
+    def coded_agg(keys_flat, bufs_flat, mins, slot_ranges, mask):
         capacity = keys_flat[0][0].shape[0]
         keys = [ColVal(None, v, val) for v, val in keys_flat]
         buf_inputs = [(k, ColVal(None, v, val))
@@ -125,7 +125,7 @@ def _coded_kernel(kinds: Tuple[str, ...], k_bucket: int):
         return ([(k.values, k.validity) for k in out_keys],
                 [(b.values, b.validity) for b in out_bufs], n)
 
-    return run
+    return coded_agg
 
 
 def _pow2_bucket(n: int) -> int:
@@ -139,13 +139,13 @@ def _probe_kernel(nkeys: int):
     merge stage, where keys already exist as columns)."""
 
     @jax.jit
-    def run(keys_flat, nrows):
+    def agg_key_probe(keys_flat, nrows):
         capacity = keys_flat[0][0].shape[0]
         keys = [ColVal(None, v, val) for v, val in keys_flat]
         live = jnp.arange(capacity, dtype=jnp.int32) < nrows
         return agg.key_range_probe(keys, live)
 
-    return run
+    return agg_key_probe
 
 
 class TpuHashAggregateExec(TpuExec):

@@ -100,11 +100,6 @@ class MetricTimer:
 class TpuExec:
     """Base physical operator."""
 
-    # per-plan: set by the planner from spark.rapids.tpu.profile.trace;
-    # when True each iteration step wraps in a jax.profiler
-    # TraceAnnotation (NVTX-range analog)
-    trace_ops = False
-
     # True when every batch this operator yields is freshly allocated
     # per pull and never retained by the operator (or anyone upstream) —
     # the safety precondition for a consumer stage to DONATE the batch's
@@ -141,10 +136,6 @@ class TpuExec:
         batch), not just generator construction — generators return
         instantly, the work happens in ``next()``."""
         from spark_rapids_tpu.utils import tracing
-        trace = None
-        if self.trace_ops:
-            from jax.profiler import TraceAnnotation
-            trace = TraceAnnotation
         it = self.do_execute()
         timer = self.metrics[OP_TIME]
         name = self.node_name()
@@ -154,18 +145,12 @@ class TpuExec:
                 # single branch per pull when tracing is off; spans
                 # nest through the child iterator pulls, so the
                 # rollup's exclusive time per operator matches the
-                # opTimeSelf discipline at span granularity.  The
-                # profile.trace jax annotation composes (nests inside
-                # the span) rather than being displaced by it.
-                if tracing._armed:
+                # opTimeSelf discipline at span granularity.  Under
+                # profile.trace the span is also the operator's
+                # annotation in the profiler's trace (NVTX-range
+                # analog), named by ``op``.
+                if tracing._active:
                     with tracing.span("operator.batch", op=name):
-                        if trace is not None:
-                            with trace(name):
-                                batch = next(it)
-                        else:
-                            batch = next(it)
-                elif trace is not None:
-                    with trace(name):
                         batch = next(it)
                 else:
                     batch = next(it)

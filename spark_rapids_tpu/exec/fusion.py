@@ -268,7 +268,7 @@ class FusedStageExec(TpuExec):
             # metric can legitimately exceed members-1 x inputBatches
             # on retried queries
             self.metrics[DISPATCHES_SAVED] += saved_per_batch
-            if tracing._armed:
+            if tracing._active:
                 with tracing.span("stage.fused", op=stage_op):
                     return self._compute_batch(batch, names)
             return self._compute_batch(batch, names)

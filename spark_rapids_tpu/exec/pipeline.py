@@ -68,13 +68,14 @@ def worker_attribution(owner_ident: int, stats=None):
     qcontext.adopt_thread(owner_ident)
     hostsync.host_sync_metrics.adopt(owner_ident)
     retry_metrics.adopt(owner_ident)
-    if stats is not None:
-        hostsync.watch_uploads(stats)
+    upload0 = hostsync.upload_metrics.thread_ns()
     try:
         yield
     finally:
         if stats is not None:
-            hostsync.unwatch_uploads()
+            # this thread's share of the one upload accounting
+            stats.upload_overlap_ns += \
+                hostsync.upload_metrics.thread_ns() - upload0
         retry_metrics.release()
         hostsync.host_sync_metrics.release()
         qcontext.release_thread()

@@ -26,6 +26,21 @@ from spark_rapids_tpu.ops import stringops as S
 from spark_rapids_tpu.ops.expressions import (
     Alias, BoundReference, Expression, Literal, UnresolvedColumn)
 from spark_rapids_tpu.plan.logical import FileRelation
+from spark_rapids_tpu.utils import tracing
+
+_END = object()
+
+
+def _decoded(it) -> Iterator:
+    """Pull a pyarrow iterator with each pull under a ``scan.decode``
+    span: the read and decode happen inside ``next``."""
+    it = iter(it)
+    while True:
+        with tracing.span("scan.decode"):
+            item = next(it, _END)
+        if item is _END:
+            return
+        yield item
 
 
 def _dataset(paths, file_format):
@@ -240,18 +255,23 @@ class TpuFileScanExec(TpuExec):
         dataset fragment reads and chunks independently (fragment reads
         keep hive partition columns), its constant meta columns ride
         every chunk."""
-        dataset = _dataset(self.paths, self.file_format)
-        for frag in dataset.get_fragments(filter=self.arrow_filter):
-            table = frag.to_table(schema=dataset.schema,
-                                  columns=self.columns,
-                                  filter=self.arrow_filter)
+        with tracing.span("scan.decode"):
+            dataset = _dataset(self.paths, self.file_format)
+            fragments = dataset.get_fragments(filter=self.arrow_filter)
+        for frag in _decoded(fragments):
+            with tracing.span("scan.decode"):
+                table = frag.to_table(schema=dataset.schema,
+                                      columns=self.columns,
+                                      filter=self.arrow_filter)
             for off in range(0, table.num_rows, self.batch_rows):
                 chunk = table.slice(off, self.batch_rows)
                 if not chunk.num_rows:
                     continue
                 self.metrics[NUM_INPUT_BATCHES] += 1
-                yield self._finish_batch(self._attach_meta(
-                    ColumnarBatch.from_arrow(chunk), frag.path))
+                with tracing.span("scan.convert"):
+                    batch = self._finish_batch(self._attach_meta(
+                        ColumnarBatch.from_arrow(chunk), frag.path))
+                yield batch
 
     def do_execute(self) -> Iterator[ColumnarBatch]:
         # "io.read" fires once per produced batch, so chaos tests can
@@ -260,7 +280,11 @@ class TpuFileScanExec(TpuExec):
         # Each pull runs under an "io.reader" watchdog section: a
         # stalled decode (slow object store, wedged reader pool
         # thread) overruns its deadline and the monitor converts the
-        # hang into a retryable TimeoutFault at the next checkpoint
+        # hang into a retryable TimeoutFault at the next checkpoint.
+        # Inside the section's span the pull splits where the work
+        # happens: ``scan.decode`` (pyarrow's read), ``scan.convert``
+        # (arrow -> host columns) and ``upload.h2d`` (Column._upload);
+        # ``io.reader``'s exclusive time is what is left
         from spark_rapids_tpu.robustness import watchdog
         from spark_rapids_tpu.robustness.inject import fire
         it = self._scan_batches()
@@ -284,28 +308,35 @@ class TpuFileScanExec(TpuExec):
             yield from self._simple_scan()
             return
         from spark_rapids_tpu.io.multifile import iter_file_tables
-        for table in iter_file_tables(
+        for table in _decoded(iter_file_tables(
                 self.paths, self.file_format, self.columns,
                 self.arrow_filter, self.reader_type, self.batch_rows,
-                self.num_threads, self.max_files_parallel):
+                self.num_threads, self.max_files_parallel)):
             self.metrics[NUM_INPUT_BATCHES] += 1
             for off in range(0, table.num_rows, self.batch_rows):
                 chunk = table.slice(off, self.batch_rows)
                 if chunk.num_rows:
-                    yield self._finish_batch(ColumnarBatch.from_arrow(chunk))
+                    with tracing.span("scan.convert"):
+                        batch = self._finish_batch(
+                            ColumnarBatch.from_arrow(chunk))
+                    yield batch
 
     def _simple_scan(self) -> Iterator[ColumnarBatch]:
         import pyarrow as pa
-        dataset = _dataset(self.paths, self.file_format)
         kwargs = {"columns": self.columns, "batch_size": self.batch_rows}
         if self.arrow_filter is not None:
             kwargs["filter"] = self.arrow_filter
-        for record_batch in dataset.to_batches(**kwargs):
+        with tracing.span("scan.decode"):
+            dataset = _dataset(self.paths, self.file_format)
+            record_batches = dataset.to_batches(**kwargs)
+        for record_batch in _decoded(record_batches):
             if record_batch.num_rows == 0:
                 continue
             self.metrics[NUM_INPUT_BATCHES] += 1
-            yield self._finish_batch(ColumnarBatch.from_arrow(
-                pa.Table.from_batches([record_batch])))
+            with tracing.span("scan.convert"):
+                batch = self._finish_batch(ColumnarBatch.from_arrow(
+                    pa.Table.from_batches([record_batch])))
+            yield batch
 
 
 def _bucket_pruned_paths(node: FileRelation) -> List[str]:

@@ -346,7 +346,7 @@ class SpillableHandle:
         if self.tier == DEVICE:
             return self._device
         from spark_rapids_tpu.utils import tracing
-        if tracing._armed:
+        if tracing._active:
             with tracing.span(f"spill.restore.{self.tier.lower()}"):
                 return self._materialize_cold()
         return self._materialize_cold()
@@ -539,7 +539,7 @@ class SpillableBatchCatalog:
             ctx = _qc.current()
             owner = ctx.owner_ident if ctx is not None else None
         from spark_rapids_tpu.utils import tracing
-        if tracing._armed:
+        if tracing._active:
             with tracing.span("spill.register"):
                 return self._register_impl(batch, priority, owner)
         return self._register_impl(batch, priority, owner)

@@ -27,6 +27,7 @@ from spark_rapids_tpu.columnar.column import Column
 from spark_rapids_tpu.columnar.dtypes import DataType
 from spark_rapids_tpu.ops.expressions import (
     ColVal, EmitContext, Expression, collect_param_slots)
+from spark_rapids_tpu.utils import tracing
 
 # A column crosses the jit boundary as (values, validity|None, offsets|None).
 FlatCol = Tuple
@@ -52,7 +53,11 @@ def effective_donate(donate: bool) -> bool:
 
 
 def batch_to_flat(batch: ColumnarBatch) -> List[FlatCol]:
-    return [(c.data, c.validity, c.offsets) for c in batch.columns.values()]
+    # where a scanned batch's host buffers become device arrays (first
+    # touch of Column.data): one ``upload.h2d`` span a batch
+    with tracing.span("upload.h2d"):
+        return [(c.data, c.validity, c.offsets)
+                for c in batch.columns.values()]
 
 
 def flat_to_colvals(flat: Sequence[FlatCol],

@@ -11,7 +11,8 @@ pairs, both power-of-two buckets, so the jit cache stays small.
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+import threading
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,37 @@ import jax.numpy as jnp
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column, bucket_capacity
 from spark_rapids_tpu.ops.expressions import ColVal
+
+
+class ConcatMetrics:
+    """What the append kernels move, known on the host without a sync:
+    every ``_append_fixed`` / ``_append_string`` call rewrites its whole
+    output (``bytes_written``: the output buffers' capacity x item
+    size, validity and offsets included) to place one input
+    (``bytes_appended``: the input buffers').  ``bytes_written /
+    bytes_appended`` is concat's wasted-work ratio.  Plain ints, bumped
+    with tracing on or off."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.appends = self.bytes_written = self.bytes_appended = 0
+
+    def note(self, outs, ins) -> None:
+        written = sum(a.nbytes for a in outs)
+        appended = sum(a.nbytes for a in ins)
+        with self._lock:
+            self.appends += 1
+            self.bytes_written += written
+            self.bytes_appended += appended
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"appends": self.appends,
+                    "bytes_written": self.bytes_written,
+                    "bytes_appended": self.bytes_appended}
+
+
+concat_metrics = ConcatMetrics()
 
 
 @jax.jit
@@ -55,6 +87,20 @@ def _append_string(out_chars, out_offs, out_valid, out_n,
     rwrite = (rpos >= out_n) & (rpos < out_n + in_n)
     valid = jnp.where(rwrite, in_valid[rsrc], out_valid)
     return chars, new_offs, valid
+
+
+def append_fixed(out_vals, out_valid, out_n, in_vals, in_valid, in_n):
+    concat_metrics.note((out_vals, out_valid), (in_vals, in_valid))
+    return _append_fixed(out_vals, out_valid, out_n, in_vals, in_valid,
+                         in_n)
+
+
+def append_string(out_chars, out_offs, out_valid, out_n,
+                  in_chars, in_offs, in_valid, in_n):
+    concat_metrics.note((out_chars, out_offs, out_valid),
+                        (in_chars, in_offs, in_valid))
+    return _append_string(out_chars, out_offs, out_valid, out_n,
+                          in_chars, in_offs, in_valid, in_n)
 
 
 def _ensure_validity(col: Column):
@@ -104,7 +150,7 @@ def concat_batches(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
             n = 0
             for b in batches:
                 c = b.column(name)
-                chars, offs, valid = _append_string(
+                chars, offs, valid = append_string(
                     chars, offs, valid, jnp.int32(n),
                     c.data, c.offsets, _ensure_validity(c),
                     jnp.int32(c.nrows))
@@ -118,7 +164,7 @@ def concat_batches(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
             n = 0
             for b in batches:
                 c = b.column(name)
-                vals, valid = _append_fixed(
+                vals, valid = append_fixed(
                     vals, valid, jnp.int32(n), c.data, _ensure_validity(c),
                     jnp.int32(c.nrows))
                 n += c.nrows
@@ -151,7 +197,7 @@ def _concat_batches_lazy(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
         n_dev = None
         for b, c in zip(batches, counts):
             col = b.column(name)
-            vals, valid = _append_fixed(
+            vals, valid = append_fixed(
                 vals, valid, jnp.int32(0) if n_dev is None else n_dev,
                 col.data, _ensure_validity(col), c)
             n_dev = c if n_dev is None else n_dev + c

@@ -45,15 +45,19 @@ ops/compiler.py).
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
+import re
 import threading
 import zlib
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
 
 import jax
+
+from spark_rapids_tpu.utils import tracing
 
 # LRU-bounded: cached entries close over their originating plan instance
 # (and thus its child tree), so an unbounded map would pin every distinct
@@ -317,19 +321,21 @@ class _Entry:
         # losing a rare racing increment beats serializing every
         # dispatch in the process on one mutex
         self.dispatches += 1
-        if self._cold:
-            # the entry's first dispatch pays the Python trace + XLA
-            # compile (or the AOT deserialize): span it and feed the
-            # site's compile_ms observation.  Later shape-bucket
-            # recompiles (rare) ride untraced — warm dispatches stay a
-            # single branch.  The flag flips even when tracing is off
-            # so arming mid-process never mis-labels a warm site.
-            from spark_rapids_tpu.utils import tracing
-            self._cold = False
-            if tracing._armed:
-                with tracing.span("jit.trace", site=self.sig,
-                                  observe="compile_ms"):
-                    return self._dispatch(args)
+        # the entry's first dispatch pays the Python trace + XLA
+        # compile (or the AOT deserialize): ``jit.trace`` spans it and
+        # feeds the site's compile_ms observation; every later one is
+        # a ``jit.dispatch`` (later shape-bucket recompiles, rare, ride
+        # inside it).  Both carry the site and the operator whose pull
+        # launched it, so the rollup maps program -> site -> operator.
+        # The flag flips even when tracing is off so arming
+        # mid-process never mis-labels a warm site; warm dispatches
+        # with tracing off stay a single branch.
+        cold, self._cold = self._cold, False
+        if tracing._active:
+            with tracing.span("jit.trace" if cold else "jit.dispatch",
+                              site=self.sig, op=tracing.current_op(),
+                              observe="compile_ms" if cold else None):
+                return self._dispatch(args)
         return self._dispatch(args)
 
     def _dispatch(self, args):
@@ -343,7 +349,6 @@ class _Entry:
         return fn(*args)
 
     def _bind(self, key, args, tier: PersistentJitCache) -> Callable:
-        from spark_rapids_tpu.utils import tracing
         store = False
         with self._lock:
             fn = self._bound.get(key)
@@ -351,7 +356,8 @@ class _Entry:
                 with tracing.span("jit.aotLoad", site=self.sig):
                     exported = tier.load(self.sig, key)
                 if exported is not None:
-                    fn = self._guarded(key, jax.jit(exported.call))
+                    fn = self._guarded(key, jax.jit(_named(
+                        exported.call, program_name(self.sig))))
                 else:
                     # miss: execution stays on the canonical jit
                     # (donation semantics preserved); the module is
@@ -409,6 +415,28 @@ class _Entry:
         return run
 
 
+def program_name(signature: Hashable) -> str:
+    """The name a signature's program carries in the profiler's trace
+    (``jit_<this>``): the signature's kind — its first string — and the
+    first 8 hex of the site id the spans rollup and the observation
+    store key on."""
+    parts = signature if isinstance(signature, tuple) else (signature,)
+    kind = next((p for p in parts if isinstance(p, str)), "program")
+    kind = re.sub(r"\W+", "_", kind).strip("_") or "program"
+    return f"{kind}_{tracing.site_id(signature)[:8]}"
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: jax.jit names the XLA module after the
+    function it is handed, and a bound method or a shard_map closure
+    cannot be renamed in place.  Runs at trace time only."""
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
 def cached_jit(signature: Hashable, make: Callable[[], Callable],
                **jit_kwargs: Any) -> Callable:
     """Return a jitted callable for ``signature``; build via ``make()``
@@ -436,7 +464,8 @@ def cached_jit(signature: Hashable, make: Callable[[], Callable],
                 _CACHE.move_to_end(signature)
                 _HITS += 1
                 return fn
-        built = _Entry(signature, jax.jit(make(), **jit_kwargs))
+        built = _Entry(signature, jax.jit(
+            _named(make(), program_name(signature)), **jit_kwargs))
         with _LOCK:
             global _EVICTED_DISPATCHES
             _MISSES += 1

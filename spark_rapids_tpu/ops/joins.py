@@ -243,7 +243,8 @@ def join_out_starts(probe_count, probe_n, outer: bool):
 @lru_cache(maxsize=None)
 def _gather_indices_kernel(out_cap: int):
     @jax.jit
-    def run(starts, ends, probe_count, probe_bstart, sorted_to_build, total):
+    def join_gather_rows(starts, ends, probe_count, probe_bstart,
+                         sorted_to_build, total):
         j = jnp.arange(out_cap, dtype=jnp.int64)
         p = jnp.searchsorted(ends, j, side="right").astype(jnp.int32)
         p = jnp.clip(p, 0, probe_count.shape[0] - 1)
@@ -254,7 +255,7 @@ def _gather_indices_kernel(out_cap: int):
                                         sorted_to_build.shape[0] - 1)]
         in_range = j < total
         return p, jnp.clip(brow, 0, None), matched & in_range, in_range
-    return run
+    return join_gather_rows
 
 
 def join_gather_indices(starts, ends, probe_count, probe_bstart,

@@ -889,7 +889,7 @@ class DistPlanner:
             frame = self._ckpt.restore(sid, self.mesh)
             if frame is not None:
                 return frame
-        if tracing._armed:
+        if tracing._active:
             # per-stage span keyed by the structural stage id: nested
             # stages subtract, so the rollup's per-site exclusive time
             # is each exchange stage's own cost — and the observation
@@ -1046,6 +1046,7 @@ class DistPlanner:
         order-preserving codes the rest of the engine expects."""
         from spark_rapids_tpu.io.readers import _dataset
         from spark_rapids_tpu.ops.dictionary import dict_encode_stable
+        from spark_rapids_tpu.utils import hostsync, tracing
         nshards = self.mesh.devices.size
         devices = self.mesh.devices.reshape(-1)
         axis = self.mesh.axis_names[0]
@@ -1113,8 +1114,10 @@ class DistPlanner:
                     mbuf[at:at + nb] = col.validity_numpy()
                     at += nb
                 dev = devices[s]
-                shard_bufs[2 * i].append(jax.device_put(vbuf, dev))
-                shard_bufs[2 * i + 1].append(jax.device_put(mbuf, dev))
+                with tracing.span("upload.h2d"):
+                    shard_bufs[2 * i].append(hostsync.upload(vbuf, dev))
+                    shard_bufs[2 * i + 1].append(
+                        hostsync.upload(mbuf, dev))
             del batches  # host copies of this shard are done
 
         sharding = NamedSharding(self.mesh, P(axis))
@@ -2048,13 +2051,16 @@ def try_distributed(session, plan: L.LogicalPlan, resume: bool = False):
     # FileRelation of a view is shared, and would otherwise carry
     # whatever the last single-process plan left on it
     from spark_rapids_tpu.plan.overrides import _pushdown_pass
-    _pushdown_pass(plan, session.cache_manager)
+    from spark_rapids_tpu.utils import tracing
+    with tracing.span("plan.physical"):
+        _pushdown_pass(plan, session.cache_manager)
     session.last_scan_stats = None  # per-query: no stale sharded stats
     session.last_fusion_stats = None  # per-query fusion attribution
     from spark_rapids_tpu.parallel import exchange_async as _xa
     _xa.set_current_window(planner._xwindow)
     try:
-        planner.run(plan, dry=True)  # support pre-flight: no data moves
+        with tracing.span("plan.physical"):
+            planner.run(plan, dry=True)  # pre-flight: no data moves
         # data-dependent limits (e.g. join fan-out vs output capacity)
         # can only surface while executing; they fall back too
         batch = planner.collect(planner.run(plan, dry=False))

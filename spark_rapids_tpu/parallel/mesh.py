@@ -255,7 +255,9 @@ def to_host(x) -> np.ndarray:
         mesh = getattr(getattr(x, "sharding", None), "mesh", None)
         if mesh is not None:
             try:
-                rep = jax.jit(lambda a: a,
+                def replicate_to_host(a):
+                    return a
+                rep = jax.jit(replicate_to_host,
                               out_shardings=replicated_spec(mesh))(x)
                 return np.asarray(rep)
             except Exception:

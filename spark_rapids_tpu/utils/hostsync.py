@@ -24,6 +24,7 @@ don't contaminate each other's attribution.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Sequence
 
 
@@ -115,29 +116,55 @@ def count_sync(n: int = 1) -> None:
 
 
 # ------------------------------------------------------ upload accounting --
-# Thread-local sink for host->device upload timing: the pipeline worker
-# (exec/pipeline.py) registers its PipelineStats here, and the columnar
-# materialization sites (columnar/column.py ``jnp.asarray``) report in.
-# Measures host-side dispatch+staging time (device transfer itself is
-# async) — the work the sequential loop would serialize against
-# consumption.
-_upload_sink = threading.local()
+class UploadMetrics:
+    """Host->device uploads, counted where a host buffer becomes a
+    device array (:func:`upload`: columnar/column.py ``Column._upload``
+    and the sharded scan's per-shard placement):
+    ``bytes`` and ``buffers`` moved, and ``ns`` of host-side
+    dispatch+staging time (the transfer itself is async).  Plain ints,
+    bumped with tracing on or off — what an operator would scrape.
+    ``thread_ns`` is the calling thread's own share of ``ns``: the
+    pipeline worker's delta of it is ``uploadOverlapMs``, the upload
+    work the sequential loop would have serialized against
+    consumption."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self.bytes = self.buffers = self.ns = 0
+
+    def note(self, nbytes: int, ns: int) -> None:
+        with self._lock:
+            self.bytes += nbytes
+            self.buffers += 1
+            self.ns += ns
+        self._local.ns = getattr(self._local, "ns", 0) + ns
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"bytes": self.bytes, "buffers": self.buffers,
+                    "ns": self.ns}
+
+    def thread_ns(self) -> int:
+        return getattr(self._local, "ns", 0)
 
 
-def watch_uploads(stats) -> None:
-    """Route this thread's upload timings into ``stats``
-    (any object with an ``upload_overlap_ns`` attribute)."""
-    _upload_sink.sink = stats
+upload_metrics = UploadMetrics()
 
 
-def unwatch_uploads() -> None:
-    _upload_sink.sink = None
-
-
-def note_upload(ns: int) -> None:
-    sink = getattr(_upload_sink, "sink", None)
-    if sink is not None:
-        sink.upload_overlap_ns += ns
+def upload(np_buf, device=None):
+    """Host buffer -> device array, counted (``upload_metrics``): the
+    default device through ``jnp.asarray``, a named one (a mesh shard's)
+    through ``jax.device_put``.  The ``upload.h2d`` span is the
+    caller's, one a batch (``ops/compiler.batch_to_flat``, the sharded
+    scan's placement): one a buffer made a profiled q6 query 8% slower
+    on the chip (PERF.md, PR 28)."""
+    import jax
+    t0 = time.perf_counter_ns()
+    out = jax.numpy.asarray(np_buf) if device is None \
+        else jax.device_put(np_buf, device)
+    upload_metrics.note(np_buf.nbytes, time.perf_counter_ns() - t0)
+    return out
 
 
 def _globalize(buffers):

@@ -17,9 +17,18 @@ on any backend):
   duration minus its direct children's durations, so rollups never
   double count (the ``opTimeSelf`` discipline, at span granularity).
 * Tracing is DEFAULT-OFF and, when off, every span site costs a single
-  branch (``span`` returns a shared no-op; hot loops read ``_armed``
+  branch (``span`` returns a shared no-op; hot loops read ``_active``
   directly and skip even the call).  Tracing changes no data path —
   chaos proves results bit-identical with it on.
+* **One timeline**: under ``spark.rapids.tpu.profile.trace`` every span
+  also enters a ``jax.profiler.TraceAnnotation`` for its lifetime —
+  named by its ``op`` where it has one (``operator.batch`` shows as
+  ``TpuFileScanExec``, its point in the event's ``point`` stat), by
+  its ``point`` otherwise (``io.reader``, ``hostsync.fetch``, ...) —
+  so the engine's spans sit on the
+  profiler's clock beside the device's programs.  This is the only
+  place the engine writes profiler annotations.  ``emit_span`` records
+  (already timed, async) stay host-only.
 * At QueryEnd ``finish_query`` drains the owner's closed records into
   (a) a Chrome-trace-event JSON file per query under
   ``spark.rapids.tpu.trace.dir`` (tools/traceview.py — load it in
@@ -50,7 +59,10 @@ from typing import Any, Dict, List, Optional, Tuple
 
 # --------------------------------------------------------------- state --
 
-_armed = False
+_armed = False     # spans are recorded (trace.enabled / trace.dir)
+_profile = False   # spans enter a profiler annotation (profile.trace)
+_active = False    # _armed or _profile: THE flag hot loops read
+_annotation = None  # jax.profiler.TraceAnnotation, bound when profiling
 _trace_dir: Optional[str] = None
 _max_events = 100_000
 _obs: Optional["ObservationStore"] = None
@@ -164,7 +176,7 @@ _NOOP = _NoopSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("point", "site", "op", "observe")
+    __slots__ = ("point", "site", "op", "observe", "_ann", "_open")
 
     def __init__(self, point: str, site, op, observe):
         self.point = point
@@ -173,12 +185,30 @@ class _SpanCtx:
         self.observe = observe
 
     def __enter__(self):
-        b = _buf()
-        b.stack.append([self.point, self.site, self.op,
-                        time.perf_counter_ns(), 0, _owner_ident()])
+        # the flags are read once per span: a (re)configure mid-span
+        # unwinds exactly what this enter did
+        self._ann = None
+        if _profile:
+            # named by the operator where there is one (its point rides
+            # along as the event's ``point`` stat), else by the point
+            self._ann = _annotation(self.op, point=self.point) \
+                if self.op else _annotation(self.point)
+            self._ann.__enter__()
+        self._open = _armed
+        if _armed:
+            _buf().stack.append([self.point, self.site, self.op,
+                                 time.perf_counter_ns(), 0,
+                                 _owner_ident()])
         return self
 
     def __exit__(self, *exc):
+        if self._open:
+            self._record()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+    def _record(self):
         b = _buf()
         end = time.perf_counter_ns()
         point, site, op, t0, child_ns, owner = b.stack.pop()
@@ -187,7 +217,7 @@ class _SpanCtx:
         if b.stack:
             b.stack[-1][4] += dur
         if not _armed:
-            return False  # disarmed mid-span: unwind, record nothing
+            return  # disarmed mid-span: unwind, record nothing
         if len(b.items) < _max_events:
             b.items.append((point, site, op, t0, dur, excl, owner,
                             b.tid, False))
@@ -196,19 +226,32 @@ class _SpanCtx:
         if self.observe is not None and site is not None and \
                 _obs is not None:
             _obs.observe(site_id(site), **{self.observe: dur / 1e6})
-        return False
 
 
 def span(point: str, site=None, op=None, observe: Optional[str] = None):
-    """Trace the enclosed region.  One branch when tracing is off.
+    """Trace the enclosed region.  One branch when tracing (recording
+    and the profiler bridge both) is off.
 
     ``site``: structural site object (jit signature / stage id) —
     hashed into the rollup's per-site breakdown and, with ``observe``
     set to an observation-store field name (e.g. ``"compile_ms"``),
     the span's duration is recorded as that site observation."""
-    if not _armed:
+    if not _active:
         return _NOOP
     return _SpanCtx(point, site, op, observe)
+
+
+def current_op() -> Optional[str]:
+    """``op`` of the innermost open span on this thread that has one
+    (the enclosing ``operator.batch``), None outside any or when spans
+    are not recorded.  ``jit.dispatch`` stamps it so the rollup maps a
+    program's site to the operator that launched it."""
+    b = getattr(_tls, "buf", None)
+    if b is not None:
+        for frame in reversed(b.stack):
+            if frame[2]:
+                return frame[2]
+    return None
 
 
 def emit_span(point: str, t0_ns: int, dur_ns: int, site=None, op=None,
@@ -252,11 +295,18 @@ def observe_host(host: int, point: str, **fields) -> None:
 
 def configure(enabled: bool, trace_dir: Optional[str] = None,
               max_events: int = 100_000,
-              obs_dir: Optional[str] = None) -> None:
+              obs_dir: Optional[str] = None,
+              profile: bool = False) -> None:
     """(Re)arm the process-global runtime from a session's conf.
     ``enabled=False`` disarms (buffers drop their backlog so a
-    disarmed process holds no span memory)."""
-    global _armed, _trace_dir, _max_events, _obs
+    disarmed process holds no span memory).  ``profile``
+    (``spark.rapids.tpu.profile.trace``) bridges every span into the
+    profiler's trace, with or without recording."""
+    global _armed, _profile, _active, _annotation
+    global _trace_dir, _max_events, _obs
+    if profile and _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
     _trace_dir = trace_dir or None
     _max_events = max(int(max_events), 1)
     if enabled and obs_dir:
@@ -267,6 +317,8 @@ def configure(enabled: bool, trace_dir: Optional[str] = None,
         # silently keep writing beside a previous session's cache dir
         _obs = None
     _armed = bool(enabled)
+    _profile = bool(profile)
+    _active = _armed or _profile
     if not _armed:
         with _reg_lock:
             for b in _bufs:
@@ -367,6 +419,10 @@ def rollup(records: List[tuple], wall_ms: float,
             s = sites.setdefault(sid, {"count": 0, "ms": 0.0})
             s["count"] += 1
             s["ms"] += excl_ms
+            if r[R_OP]:
+                # site -> operator: a jit.dispatch span carries the
+                # operator that launched the site's program
+                s["op"] = r[R_OP]
     unattributed = max(wall_ms - total_excl, 0.0)
     out = {
         "wallMs": round(wall_ms, 3),
@@ -388,7 +444,7 @@ def rollup(records: List[tuple], wall_ms: float,
                 "exclusiveMs": round(v["exclusiveMs"], 3)}
             for k, v in sorted(operators.items())}
     if sites:
-        out["sites"] = {k: {"count": v["count"], "ms": round(v["ms"], 3)}
+        out["sites"] = {k: dict(v, ms=round(v["ms"], 3))
                         for k, v in sorted(sites.items())}
     return out
 
