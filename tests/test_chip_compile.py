@@ -113,6 +113,37 @@ def test_shuffle_pack_and_unpack_compile(one_chip):
         assert _device_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("kind", ["int64", "float64", "date", "string"])
+def test_concat_programs_compile_without_a_gather(one_chip, kind):
+    """q3's shape: six inputs of 2^19 rows into 2^21, every other input
+    with a validity.  The scratch is ``lax.empty`` (an ``AllocateBuffer``
+    the X64 rewriter has to split for 64-bit lanes), the blocks are
+    ``dynamic-update-slice`` ops, and no gather is left in what the
+    chip's compiler makes of it."""
+    from spark_rapids_tpu.ops import concat
+    k, in_cap, cap = 6, 1 << 19, 1 << 21
+    counts = _spec((k,), jnp.int32, one_chip)
+    valids = tuple(_spec((in_cap,), jnp.bool_, one_chip) if i % 2 else None
+                   for i in range(k))
+    if kind == "string":
+        chars = tuple(_spec((1 << 22,), jnp.uint8, one_chip)
+                      for _ in range(k))
+        offsets = tuple(_spec((in_cap + 1,), jnp.int32, one_chip)
+                        for _ in range(k))
+        compiled = jax.jit(concat._make_concat_string(cap, 1 << 24)).lower(
+            chars, offsets, valids, counts, counts).compile()
+    else:
+        storage = {"int64": jnp.int64, "float64": jnp.float64,
+                   "date": jnp.int32}[kind]
+        datas = tuple(_spec((in_cap,), storage, one_chip) for _ in range(k))
+        compiled = jax.jit(concat._make_concat_fixed(cap)).lower(
+            datas, valids, counts).compile()
+    text = compiled.as_text()
+    assert "dynamic-update-slice" in text
+    assert " gather(" not in text and " scatter(" not in text
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 def test_fused_q6_stage_compiles(one_chip, monkeypatch):
     """The flagship fused filter+project+reduce stage at 2^23 rows."""
     import __graft_entry__ as g

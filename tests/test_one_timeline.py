@@ -226,20 +226,35 @@ def test_upload_counter_counts_each_buffer_once(rng):
 
 def test_concat_counters_read_capacities():
     k, c = 4, 1024
-    batches = [ColumnarBatch.from_arrow(pa.table(
-        {"x": np.arange(c, dtype=np.int64) + i})) for i in range(k)]
+    tables = [{"x": np.arange(c, dtype=np.int64) + i,
+               "y": pa.array([None if j == i else j for j in range(c)],
+                             type=pa.int64())} for i in range(k)]
+    # y: an input with a null carries a validity; the last has none
+    tables[-1]["y"] = pa.array(range(c), type=pa.int64())
+    batches = [ColumnarBatch.from_arrow(pa.table(t)) for t in tables]
     assert all(b.capacity == c for b in batches)
+    assert [b.column("y").validity is None for b in batches] \
+        == [False] * (k - 1) + [True]
     before = concat.concat_metrics.snapshot()
     out = concat.concat_batches(batches)
     after = concat.concat_metrics.snapshot()
     K = out.capacity
     assert K == k * c
-    item = 8 + 1  # an int64 and its validity byte
-    assert after["appends"] - before["appends"] == k
-    assert after["bytes_written"] - before["bytes_written"] == k * K * item
+    # one program a column places each input's block once and finishes
+    # each output buffer once.  x has no validity anywhere: none is
+    # placed or made.  y's output needs one: every input places a
+    # validity block (the program's own all-True one for the last
+    # input, which appends none)
+    x_written = k * c * 8 + K * 8
+    y_written = k * c * (8 + 1) + K * (8 + 1)
+    assert after["appends"] - before["appends"] == 2 * k
+    assert after["bytes_written"] - before["bytes_written"] \
+        == x_written + y_written
     assert after["bytes_appended"] - before["bytes_appended"] \
-        == k * c * item
+        == k * c * 8 + k * c * 8 + (k - 1) * c
     assert out.column("x").to_numpy()[-1] == c - 1 + k - 1
+    assert out.column("x").validity is None
+    assert out.column("y").null_count() == k - 1
 
 
 def test_plan_physical_inside_the_envelope(lineitem, monkeypatch):
