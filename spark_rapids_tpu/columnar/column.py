@@ -114,6 +114,24 @@ class RowCount:
         return "RowCount(<device>)"
 
 
+def _decimal_unscaled(arr, validity: Optional[np.ndarray]) -> np.ndarray:
+    """The unscaled int64 of every value of an Arrow decimal array, in
+    bulk: a decimal128 is 16 little-endian bytes and a precision within
+    DECIMAL_64 leaves the high word as the low word's sign, so the values
+    are a strided view of the low words (NULL slots, whose bytes Arrow
+    leaves undefined, read 0)."""
+    import pyarrow as pa
+    if not len(arr):
+        return np.zeros(0, dtype=np.int64)
+    if not pa.types.is_decimal128(arr.type):
+        arr = arr.cast(pa.decimal128(arr.type.precision, arr.type.scale))
+    words = np.frombuffer(arr.buffers()[1], dtype=np.int64)
+    low = words[2 * arr.offset: 2 * (arr.offset + len(arr)): 2]
+    if validity is not None:
+        return np.where(validity, low, 0)
+    return low
+
+
 class Column:
     """One device column with logical length ``nrows`` and static capacity.
 
@@ -169,11 +187,11 @@ class Column:
         return self._row_count
 
     # -------------------------------------------------------- buffer access --
-    def _upload(self, np_buf):
+    def _upload(self, np_buf, validity: bool = False):
         """Host->device materialization (once per buffer), counted
         where it happens (utils/hostsync.upload)."""
         from spark_rapids_tpu.utils import hostsync
-        return hostsync.upload(np_buf)
+        return hostsync.upload(np_buf, validity=validity)
 
     @property
     def data(self):
@@ -187,7 +205,8 @@ class Column:
         if self._jax_validity is None:
             if self._np_validity is None:
                 return None
-            self._jax_validity = self._upload(self._np_validity)
+            self._jax_validity = self._upload(self._np_validity,
+                                              validity=True)
         return self._jax_validity
 
     @property
@@ -428,10 +447,9 @@ class Column:
         if arr.null_count:
             validity = ~np.asarray(arr.is_null())
         if dtype.is_decimal:
-            ints = [None if v is None else int(v.scaleb(dtype.scale))
-                    for v in arr.to_pylist()]
-            values = np.array([0 if v is None else v for v in ints],
-                              dtype=np.int64)
+            from spark_rapids_tpu.utils import tracing
+            with tracing.span("scan.convert.decimal"):
+                values = _decimal_unscaled(arr, validity)
         elif dtype.is_timestamp:
             ints = arr.cast(pa.timestamp("us")).cast(pa.int64())
             values = np.asarray(ints.fill_null(0))

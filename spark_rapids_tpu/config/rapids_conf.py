@@ -204,8 +204,9 @@ HAS_NANS = conf(
 DECIMAL_ENABLED = conf(
     "spark.rapids.sql.decimalType.enabled", True,
     "Enable decimal (DECIMAL_64) processing: device arithmetic with "
-    "Spark result-type rules and overflow->null, sum up to "
-    "decimal(8,s) children; wider results and avg fall back to CPU "
+    "Spark result-type rules and overflow->null, sum and avg over up to "
+    "decimal(8,s) children (avg is exact: decimal(p+4,s+4), HALF_UP); "
+    "wider sum buffers fall back to CPU "
     "(reference RapidsConf.scala:564).", _to_bool)
 
 OPTIMIZER_TRANSITION_COST = conf(

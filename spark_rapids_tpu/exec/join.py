@@ -11,6 +11,7 @@ anti (left anti), cross.
 
 from __future__ import annotations
 
+import threading
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -28,9 +29,48 @@ from spark_rapids_tpu.ops.expressions import ColVal, Expression
 from spark_rapids_tpu.utils import hostsync
 
 
+class JoinMetrics:
+    """Rows through ``TpuHashJoinExec``'s probe loop, known on the host
+    where the join already has them (a probe batch's row count, the
+    output total it sizes its chunks from; semi and anti joins, which
+    emit no pairs, count their probe rows only): ``probe_rows`` in,
+    ``output_rows`` out, summed over every join of every query.  Their
+    ratio is what a reordered or pre-filtered star join would save.
+    Plain ints, bumped with tracing on or off."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.probe_rows = self.output_rows = 0
+
+    def note(self, probe: int, output: int) -> None:
+        with self._lock:
+            self.probe_rows += probe
+            self.output_rows += output
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"probe_rows": self.probe_rows,
+                    "output_rows": self.output_rows}
+
+
+join_metrics = JoinMetrics()
+
+
+def _colvals(columns: Sequence[Column]) -> List[ColVal]:
+    return [ColVal(c.dtype, c.data, c.validity, c.offsets) for c in columns]
+
+
 def _to_colvals(batch: ColumnarBatch) -> List[ColVal]:
-    return [ColVal(c.dtype, c.data, c.validity, c.offsets)
-            for c in batch.columns.values()]
+    return _colvals(batch.columns.values())
+
+
+def _null_colval(dt, capacity: int) -> ColVal:
+    """An all-null column nothing reads: zeros, no chars."""
+    validity = jnp.zeros(capacity, dtype=jnp.bool_)
+    if dt.has_offsets:
+        return ColVal(dt, jnp.zeros(bucket_capacity(1), dtype=dt.storage),
+                      validity, jnp.zeros(capacity + 1, dtype=jnp.int32))
+    return ColVal(dt, jnp.zeros(capacity, dtype=dt.storage), validity)
 
 
 def _to_columns(cols: Sequence[ColVal], nrows: int) -> List[Column]:
@@ -65,13 +105,20 @@ class TpuHashJoinExec(TpuExec):
                  right_keys: Sequence[Expression], join_type: str,
                  left: TpuExec, right: TpuExec,
                  using: Optional[List[str]] = None,
-                 max_output_rows: int = 1 << 22):
+                 max_output_rows: int = 1 << 22,
+                 live_columns: Optional[set] = None):
         super().__init__(left, right)
         self.left_keys = list(left_keys)
         self.right_keys = list(right_keys)
         self.join_type = join_type
         self.using = using
         self.max_output_rows = max_output_rows
+        # the output columns something above reads (None: all of them;
+        # plan/overrides._pushdown_pass).  The others are the scans'
+        # null placeholders carried along for their ordinals: the pair
+        # emission gathers the live ones and makes the rest anew.  A
+        # USING join's key coalescing reads both sides: all live.
+        self.live_columns = None if using else live_columns
         self._register_metric(JOIN_TIME)
         self._lkey_fn = StageFn(self.left_keys,
                                 [dt for _, dt in left.schema])
@@ -195,6 +242,7 @@ class TpuHashJoinExec(TpuExec):
         for batch, m in with_retry(probe_exec.execute(), match_one):
             with self.timer(JOIN_TIME):
                 if self.join_type in ("semi", "anti"):
+                    join_metrics.note(batch.nrows, 0)
                     # output <= one probe batch: spill-retry suffices
                     yield from with_retry_no_split(
                         lambda: list(self._emit_semi_anti(batch, m)))
@@ -203,6 +251,7 @@ class TpuHashJoinExec(TpuExec):
                     lambda: J.join_out_starts(
                         m["probe_count"], jnp.int32(batch.nrows), outer))
                 total = int(total)
+                join_metrics.note(batch.nrows, total)
                 # chunks stream one at a time (peak HBM stays bounded by
                 # max_output_rows); each emit gets spill-retry only — its
                 # size is already the configured bound, not splittable
@@ -232,24 +281,38 @@ class TpuHashJoinExec(TpuExec):
             ends - offset if offset else ends,
             m["probe_count"], m["probe_bstart"], m["sorted_to_build"],
             jnp.int64(n_out), out_cap)
+        probe_schema, build_schema = (self.right.schema, self.left.schema) \
+            if self._swap else (self.left.schema, self.right.schema)
+        probe_live = _colvals(self._live(
+            list(probe_batch.columns.values()), probe_schema))
+        build_live = self._live(build_payload, build_schema)
         probe_cols = selection.gather(
-            _to_colvals(probe_batch), p, jnp.int32(n_out),
-            char_capacity=self._char_cap(probe_batch, p, n_out))
+            probe_live, p, jnp.int32(n_out),
+            char_capacity=self._char_cap_cols(probe_live, p, n_out))
         build_cols = J.gather_build_side(
-            build_payload, brow, matched, jnp.int32(n_out),
-            char_capacity=self._char_cap_cols(build_payload, brow, n_out))
-        return self._assemble(probe_cols, build_cols, n_out,
-                              probe_valid=None)
+            build_live, brow, matched, jnp.int32(n_out),
+            char_capacity=self._char_cap_cols(build_live, brow, n_out))
+        return self._assemble(
+            self._with_placeholders(probe_cols, probe_schema, out_cap),
+            self._with_placeholders(build_cols, build_schema, out_cap),
+            n_out, probe_valid=None)
 
-    @staticmethod
-    def _char_cap(batch: ColumnarBatch, indices, n_out) -> int:
-        """Static char capacity covering a row-duplicating string gather."""
-        needed = 0
-        for c in batch.columns.values():
-            if c.offsets is not None:
-                needed = max(needed, int(selection.gathered_char_count(
-                    c.offsets, indices, jnp.int32(n_out))))
-        return bucket_capacity(needed) if needed else 0
+    def _live(self, cols: Sequence, schema: Schema) -> List:
+        """The columns of ``cols`` (one a schema entry) read above."""
+        if self.live_columns is None:
+            return list(cols)
+        return [c for c, (name, _) in zip(cols, schema)
+                if name in self.live_columns]
+
+    def _with_placeholders(self, live: List[ColVal], schema: Schema,
+                           capacity: int) -> List[ColVal]:
+        """``live`` back at the schema's positions, an all-null column
+        (as ``TpuFileScanExec._finish_batch`` makes them) at the others."""
+        if self.live_columns is None:
+            return live
+        live = iter(live)
+        return [next(live) if name in self.live_columns
+                else _null_colval(dt, capacity) for name, dt in schema]
 
     @staticmethod
     def _char_cap_cols(cols: Sequence[ColVal], indices, n_out) -> int:
@@ -285,17 +348,8 @@ class TpuHashJoinExec(TpuExec):
         if n == 0:
             return
         # left side all-null
-        lschema = self.left.schema
-        null_left = []
         cap = cols[0].values.shape[0] if cols else bucket_capacity(n)
-        for _, dt in lschema:
-            if dt.is_string:
-                c = Column.from_strings([None] * n, capacity=cap)
-                null_left.append(ColVal(dt, c.data, c.validity, c.offsets))
-            else:
-                null_left.append(ColVal(
-                    dt, jnp.zeros(cap, dtype=dt.storage),
-                    jnp.zeros(cap, dtype=jnp.bool_)))
+        null_left = [_null_colval(dt, cap) for _, dt in self.left.schema]
         yield self._assemble(null_left, cols, n, probe_valid=False)
 
     def _assemble(self, probe_cols: List[ColVal], build_cols: List[ColVal],

@@ -206,6 +206,16 @@ def _sum_result_type(t: DataType) -> DataType:
     return dts.INT64
 
 
+def _decimal_sum_gate(name: str, t: DataType) -> Optional[str]:
+    """Why ``name`` over ``t`` cannot keep its decimal sum on the device,
+    or None: the int64 accumulator could silently wrap past DECIMAL_64
+    (the reference's DECIMAL_64 sum gate)."""
+    if t.is_decimal and t.precision + 10 > 18:
+        return (f"{name} over {t} needs decimal({t.precision + 10},"
+                f"{t.scale}) > DECIMAL_64; falls back to CPU")
+    return None
+
+
 class Sum(AggregateFunction):
     name = "sum"
 
@@ -214,13 +224,7 @@ class Sum(AggregateFunction):
         return _sum_result_type(self.child.dtype)
 
     def supported_reason(self):
-        t = self.child.dtype
-        if t.is_decimal and t.precision + 10 > 18:
-            # the int64 accumulator could silently wrap past DECIMAL_64
-            # (the reference's DECIMAL_64 sum gate)
-            return (f"sum over {t} needs decimal({t.precision + 10},"
-                    f"{t.scale}) > DECIMAL_64; falls back to CPU")
-        return None
+        return _decimal_sum_gate(self.name, self.child.dtype)
 
     def buffers(self):
         return [BufferSpec("sum", self.result_dtype)]
@@ -298,11 +302,22 @@ class Max(AggregateFunction):
 
 
 class Average(AggregateFunction):
+    """avg: a sum and a count, both merge-by-sum.  Over a double or an
+    integer the sum is a double and the result ``sum / count``.  Over
+    ``decimal(p,s)`` the sum is the unscaled int64 of Spark's
+    ``decimal(p+10,s)`` sum buffer and the result is Spark's
+    ``decimal(p+4,s+4)``, rounded HALF_UP from the exact quotient in
+    integers (``decimal_average``): no float comes near it."""
+
     name = "avg"
 
     @property
+    def _decimal(self) -> bool:
+        return self.child is not None and self.child.dtype.is_decimal
+
+    @property
     def result_dtype(self):
-        if self.child is not None and self.child.dtype.is_decimal:
+        if self._decimal:
             # Spark avg(decimal(p,s)) = decimal(p+4, s+4) (capped)
             from spark_rapids_tpu.ops.decimal_ops import (
                 adjust_precision_scale)
@@ -311,17 +326,18 @@ class Average(AggregateFunction):
         return dts.FLOAT64
 
     def supported_reason(self):
-        if self.child is not None and self.child.dtype.is_decimal:
-            # the rounded unscaled division needs a 128-bit intermediate
-            return (f"avg over {self.child.dtype} not supported on "
-                    "device; falls back to CPU")
-        return None
+        # the sum buffer's gate is sum's own (p+10 within DECIMAL_64)
+        return _decimal_sum_gate(self.name, self.child.dtype) \
+            if self._decimal else None
 
     def buffers(self):
-        return [BufferSpec("sum", dts.FLOAT64), BufferSpec("sum", dts.INT64)]
+        total = _sum_result_type(self.child.dtype) if self._decimal \
+            else dts.FLOAT64
+        return [BufferSpec("sum", total), BufferSpec("sum", dts.INT64)]
 
     def update_inputs(self, c, capacity):
-        return [ColVal(dts.FLOAT64, c.values.astype(jnp.float64), c.validity),
+        total = self.buffers()[0].dtype
+        return [ColVal(total, c.values.astype(total.storage), c.validity),
                 ColVal(dts.INT64,
                        c.validity.astype(jnp.int64) if c.validity is not None
                        else jnp.ones(capacity, dtype=jnp.int64))]
@@ -330,7 +346,54 @@ class Average(AggregateFunction):
         s, n = bufs
         cnt = jnp.where(n.values == 0, 1, n.values)
         validity = combine_validity(s.validity, n.values > 0)
+        if self._decimal:
+            out = self.result_dtype
+            values, fits = decimal_average(
+                s.values, cnt, out.scale - self.child.dtype.scale,
+                out.precision)
+            return ColVal(out, values, combine_validity(validity, fits))
         return ColVal(dts.FLOAT64, s.values / cnt, validity)
+
+
+def _divmod_nonneg(n, d):
+    """``(n // d, n % d)`` of int64 ``0 <= n`` and ``1 <= d < 2^62`` by
+    shift and subtract, one bit a step of a 64-step loop: the chip has
+    no 64-bit divider, and its compiler spends 7 s on every ``//`` it
+    unrolls (22 s an average, a minute of a q7 merge program) against
+    under a second for this loop; the quotients are a handful of rows
+    a group, so the loop's run time is nothing."""
+    zero = jnp.zeros_like(n)
+
+    def step(_, carry):
+        n, q, r = carry
+        r = (r << 1) | jax.lax.shift_right_logical(n, jnp.int64(63))
+        ge = r >= d
+        return n << 1, (q << 1) | ge.astype(jnp.int64), \
+            jnp.where(ge, r - d, r)
+
+    _, q, r = jax.lax.fori_loop(0, 64, step, (n, zero, zero))
+    return q, r
+
+
+def decimal_average(total, count, digits: int, precision: int):
+    """(unscaled int64 of ``total / count`` with ``digits`` more decimal
+    places, rounded HALF_UP; whether it fits ``precision`` digits).
+
+    Exact in int64: with ``q, r = divmod(|total|, count)`` the result is
+    ``q * 10^digits + round_half_up(r * 10^digits / count)``, and
+    ``2 * r * 10^digits < 2 * count * 10^digits`` stays inside int64 for
+    any count below 4.6e14 at the usual four digits.  A ``q`` too large
+    for the result type (the sum buffer overflowed) is Spark's non-ANSI
+    overflow: NULL, and its product is not formed."""
+    mult = 10 ** digits
+    negative = total < 0
+    count = count.astype(jnp.int64)
+    q, r = _divmod_nonneg(jnp.abs(total), count)
+    frac, _ = _divmod_nonneg(2 * r * mult + count, 2 * count)
+    fits = q < 10 ** (precision - digits)
+    value = jnp.where(fits, q, 0) * mult + frac
+    fits = jnp.logical_and(fits, value < 10 ** precision)
+    return jnp.where(negative, -value, value), fits
 
 
 class _CentralMoment(AggregateFunction):

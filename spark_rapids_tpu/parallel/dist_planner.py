@@ -1117,7 +1117,7 @@ class DistPlanner:
                 with tracing.span("upload.h2d"):
                     shard_bufs[2 * i].append(hostsync.upload(vbuf, dev))
                     shard_bufs[2 * i + 1].append(
-                        hostsync.upload(mbuf, dev))
+                        hostsync.upload(mbuf, dev, validity=True))
             del batches  # host copies of this shard are done
 
         sharding = NamedSharding(self.mesh, P(axis))

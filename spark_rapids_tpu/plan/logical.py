@@ -221,6 +221,10 @@ class Join(LogicalPlan):
         self.condition = condition.bind(
             list(left.schema) + list(right.schema)) \
             if condition is not None else None
+        # output columns something above reads (None = all); the pushdown
+        # pass overwrites it for every query, as it does a FileRelation's
+        # required_columns
+        self.live_columns = None
 
     @property
     def left(self):

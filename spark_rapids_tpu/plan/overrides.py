@@ -736,7 +736,8 @@ def _conv_join(node: L.Join, children, conf):
     join = TpuHashJoinExec(node.left_keys, node.right_keys, join_type,
                            children[0], children[1], using=node.using,
                            max_output_rows=conf.get(
-                               rc.JOIN_OUTPUT_BATCH_ROWS))
+                               rc.JOIN_OUTPUT_BATCH_ROWS),
+                           live_columns=node.live_columns)
     if node.condition is not None:
         # residual condition evaluated over the joined output
         return TpuFilterExec(node.condition, join)
@@ -863,6 +864,9 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
     (the requirement passes through Filters and Joins by name), so
     BoundReference ordinals stay valid (the scan emits null placeholders
     for unread columns, which by construction nothing references).
+    A Join is told the same thing (``live_columns``): the columns of its
+    output that something above reads; it gathers those and re-emits the
+    rest as the placeholders they already are.
     Filters push down until a Project renames the namespace.
 
     Cached plan nodes are pushdown BARRIERS: a query-specific filter or
@@ -874,6 +878,16 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
     """
     barrier_entered: set = set()
     scanned: set = set()  # FileRelations this pass has already visited
+    joined: set = set()   # Joins this pass has already visited
+
+    def note_live(node, live):
+        """Overwritten on the pass's first visit (None = all), widened
+        on a second (a join that two parts of one query share)."""
+        if id(node) in joined:
+            prev = node.live_columns
+            live = None if live is None or prev is None else live | prev
+        joined.add(id(node))
+        node.live_columns = live
 
     def visit(node, required, filters):
         if cache_manager is not None and id(node) not in barrier_entered \
@@ -937,6 +951,7 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
             above = set(required)
             if node.condition is not None:
                 above.update(node.condition.references())
+            note_live(node, set(above))
             for child, keys in ((node.left, node.left_keys),
                                 (node.right, node.right_keys)):
                 need = {n for n, _ in child.schema if n in above}
@@ -944,6 +959,8 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
                     need.update(k.references())
                 visit(child, need, [])
             return
+        if isinstance(node, L.Join):
+            note_live(node, None)
         for c in node.children:
             visit(c, None, [])
 

@@ -137,7 +137,9 @@ class SubqueryRef:
 
 @dataclasses.dataclass
 class JoinClause:
-    how: str  # inner/left/right/full/semi/anti/cross
+    # inner/left/right/full/semi/anti/cross, or comma: ``FROM a, b``,
+    # an inner join whose condition is in the WHERE clause
+    how: str
     right: object  # TableRef | SubqueryRef
     on: Optional[object] = None
     using: Optional[List[str]] = None
@@ -422,6 +424,8 @@ class Parser:
 
     def join_clause(self) -> Optional[JoinClause]:
         how = None
+        if self.eat_op(","):
+            return JoinClause("comma", self.from_item())
         if self.eat_kw("join"):
             how = "inner"
         elif self.at_kw("inner", "left", "right", "full", "cross"):

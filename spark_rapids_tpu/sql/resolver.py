@@ -102,7 +102,7 @@ class Resolver:
         scope = Scope()
         # WHERE conjuncts over one source of an inner-join chain filter
         # that source BEFORE the joins (Spark's PushPredicateThroughJoin)
-        pushed = self._single_source_conjuncts(stmt)
+        pushed, spanning = self._conjuncts_by_source(stmt)
         pushed_ids = {id(c) for cs in pushed.values() for c in cs}
         if stmt.from_ is None:
             df = self.session.range(1)
@@ -110,6 +110,8 @@ class Resolver:
         else:
             df = self._from_item(stmt.from_, scope, pushed)
         for j in stmt.joins:
+            if j.how == "comma":
+                j = self._comma_join(j, scope, spanning, pushed_ids)
             df = self._join(df, j, scope, pushed)
         if stmt.where is not None:
             # top-level conjuncts that are IN (subquery) become
@@ -324,22 +326,25 @@ class Resolver:
         else:
             yield node
 
-    def _single_source_conjuncts(self, stmt: A.SelectStmt) -> Dict:
-        """alias -> the top-level WHERE conjuncts that reference columns
-        of that FROM/JOIN source only.  Only for a chain of inner joins
+    def _conjuncts_by_source(self, stmt: A.SelectStmt):
+        """(alias -> the top-level WHERE conjuncts that reference columns
+        of that FROM/JOIN source only, [(conjunct, its aliases)] for the
+        conjuncts over several sources).  Only for a chain of inner joins
         (a filter on one side commutes with an inner join; an outer
         join's null-extended side does not), and only where every
         column reference is attributable without building the sources:
         qualified by a known alias, or a bare name that exactly one
-        table has.  Everything else stays above the joins."""
+        table has.  Everything else stays above the joins.  The second
+        list is where ``FROM a, b WHERE a.k = b.k`` finds its join
+        conditions (``_comma_join``)."""
         if stmt.where is None or not stmt.joins or \
-                any(j.how != "inner" for j in stmt.joins):
-            return {}
+                any(j.how not in ("inner", "comma") for j in stmt.joins):
+            return {}, []
         items = [stmt.from_] + [j.right for j in stmt.joins]
         aliases = [getattr(i, "alias", None) or getattr(i, "name", None)
                    for i in items]
         if len(set(aliases)) != len(aliases):
-            return {}
+            return {}, []
         # a derived table's columns are unknown until it is built, so
         # with one in the FROM clause only qualified names attribute
         all_tables = all(isinstance(i, A.TableRef) for i in items)
@@ -374,13 +379,39 @@ class Resolver:
             return True
 
         pushed: Dict[str, list] = {}
+        spanning: List[tuple] = []
         for conj in self._split_conjuncts(stmt.where):
             # x IN (uncorrelated subquery): only x names a source
             probe = conj.child if isinstance(conj, A.InSubquery) else conj
             found: set = set()
-            if sources(probe, found) and len(found) == 1:
+            if not sources(probe, found):
+                continue
+            if len(found) == 1:
                 pushed.setdefault(found.pop(), []).append(conj)
-        return pushed
+            elif found and not isinstance(conj, A.InSubquery):
+                spanning.append((conj, frozenset(found)))
+        return pushed, spanning
+
+    @staticmethod
+    def _comma_join(j: A.JoinClause, scope: "Scope", spanning,
+                    used: set) -> A.JoinClause:
+        """``FROM ..., right``: an inner join on the WHERE conjuncts that
+        name ``right`` and otherwise only sources already joined (Spark
+        folds them into the join the same way), in the FROM clause's
+        order; a cross join where there is none.  The conjuncts taken
+        are added to ``used`` so that the WHERE filter skips them."""
+        alias = getattr(j.right, "alias", None) or \
+            getattr(j.right, "name", None)
+        joined = {a for a, _ in scope.sources}
+        on = None
+        for conj, found in spanning:
+            if id(conj) not in used and alias in found and \
+                    found - {alias} <= joined:
+                used.add(id(conj))
+                on = conj if on is None else A.BinOp("and", on, conj)
+        if on is None:
+            return A.JoinClause("cross", j.right)
+        return A.JoinClause("inner", j.right, on)
 
     def _in_subquery_join(self, df, node: A.InSubquery, scope: Scope):
         """x IN (SELECT k FROM ...) -> semi join; NOT IN -> null-aware
@@ -439,7 +470,7 @@ class Resolver:
         rcols = list(rmap)
         if j.how == "cross":
             scope.add(ralias, rcols)
-            out = left.join(right, on=None, how="cross")
+            out = left.crossJoin(right)
             return out if j.on is None else out.filter(
                 self._expr(j.on, scope))
         if j.using is not None:

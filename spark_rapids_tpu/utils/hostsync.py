@@ -120,8 +120,10 @@ class UploadMetrics:
     """Host->device uploads, counted where a host buffer becomes a
     device array (:func:`upload`: columnar/column.py ``Column._upload``
     and the sharded scan's per-shard placement):
-    ``bytes`` and ``buffers`` moved, and ``ns`` of host-side
-    dispatch+staging time (the transfer itself is async).  Plain ints,
+    ``bytes`` and ``buffers`` moved, ``validity_bytes`` the share of
+    ``bytes`` that is validity buffers (a column without NULLs uploads
+    none), and ``ns`` of host-side dispatch+staging time (the transfer
+    itself is async).  Plain ints,
     bumped with tracing on or off — what an operator would scrape.
     ``thread_ns`` is the calling thread's own share of ``ns``: the
     pipeline worker's delta of it is ``uploadOverlapMs``, the upload
@@ -131,19 +133,21 @@ class UploadMetrics:
     def __init__(self):
         self._lock = threading.Lock()
         self._local = threading.local()
-        self.bytes = self.buffers = self.ns = 0
+        self.bytes = self.buffers = self.ns = self.validity_bytes = 0
 
-    def note(self, nbytes: int, ns: int) -> None:
+    def note(self, nbytes: int, ns: int, validity: bool = False) -> None:
         with self._lock:
             self.bytes += nbytes
             self.buffers += 1
             self.ns += ns
+            if validity:
+                self.validity_bytes += nbytes
         self._local.ns = getattr(self._local, "ns", 0) + ns
 
     def snapshot(self) -> dict:
         with self._lock:
             return {"bytes": self.bytes, "buffers": self.buffers,
-                    "ns": self.ns}
+                    "ns": self.ns, "validity_bytes": self.validity_bytes}
 
     def thread_ns(self) -> int:
         return getattr(self._local, "ns", 0)
@@ -152,10 +156,11 @@ class UploadMetrics:
 upload_metrics = UploadMetrics()
 
 
-def upload(np_buf, device=None):
-    """Host buffer -> device array, counted (``upload_metrics``): the
-    default device through ``jnp.asarray``, a named one (a mesh shard's)
-    through ``jax.device_put``.  The ``upload.h2d`` span is the
+def upload(np_buf, device=None, validity: bool = False):
+    """Host buffer -> device array, counted (``upload_metrics``; a
+    validity buffer says so): the default device through
+    ``jnp.asarray``, a named one (a mesh shard's) through
+    ``jax.device_put``.  The ``upload.h2d`` span is the
     caller's, one a batch (``ops/compiler.batch_to_flat``, the sharded
     scan's placement): one a buffer made a profiled q6 query 8% slower
     on the chip (PERF.md, PR 28)."""
@@ -163,7 +168,8 @@ def upload(np_buf, device=None):
     t0 = time.perf_counter_ns()
     out = jax.numpy.asarray(np_buf) if device is None \
         else jax.device_put(np_buf, device)
-    upload_metrics.note(np_buf.nbytes, time.perf_counter_ns() - t0)
+    upload_metrics.note(np_buf.nbytes, time.perf_counter_ns() - t0,
+                        validity)
     return out
 
 

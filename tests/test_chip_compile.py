@@ -144,6 +144,37 @@ def test_concat_programs_compile_without_a_gather(one_chip, kind):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("capacity", [1 << 19, 1 << 20])
+def test_join_out_starts_compiles_without_a_wide_scan(one_chip, capacity):
+    """q7's and q3's probe capacities.  As one int64 ``cumsum`` this
+    program took the chip's compiler 70 to 100 s a capacity, and one
+    cold compile of q7 died in it (PERF.md, PR 30); as 32-bit rows of
+    1,024 it takes under 2 s.  No reduce-window is wider than a row."""
+    import re
+    from spark_rapids_tpu.ops import joins as J
+    lowered = J.join_out_starts.lower(
+        _spec((capacity,), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip), outer=False)
+    windows = [max(int(d) for d in m.split(","))
+               for m in re.findall(r"window_dimensions = array<i64: ([\d, ]+)>",
+                                   lowered.as_text())]
+    assert windows and max(windows) <= J._SCAN_BLOCK
+    assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
+def test_decimal_average_compiles_without_a_divide(one_chip):
+    """The finalize of ``avg(decimal)`` at q7's group capacity: the
+    64-bit quotients come from a 64-step loop, not from ``//`` (7 s of
+    compile each, six of them in q7's merge program)."""
+    from spark_rapids_tpu.ops.aggregates import decimal_average
+    lowered = jax.jit(lambda s, n: decimal_average(s, n, 4, 11)).lower(
+        _spec((8192,), jnp.int64, one_chip),
+        _spec((8192,), jnp.int64, one_chip))
+    text = lowered.as_text()
+    assert "stablehlo.divide" not in text and "stablehlo.while" in text
+    assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
 def test_fused_q6_stage_compiles(one_chip, monkeypatch):
     """The flagship fused filter+project+reduce stage at 2^23 rows."""
     import __graft_entry__ as g

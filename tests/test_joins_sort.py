@@ -231,3 +231,40 @@ def test_lexsort_i32_is_jnp_lexsort_with_an_int32_index(rng):
         np.testing.assert_array_equal(
             np.asarray(lexsort_i32(ks, dead=dead)),
             np.asarray(jnp.lexsort(ks + [dead.astype(jnp.int8)])))
+
+
+@pytest.mark.parametrize("capacity,top", [
+    (1000, 2**31 - 1),       # not a multiple of the scan's block: one scan
+    (1024, 7),               # one block
+    (3 * 1024, 2**31 - 1),   # rows of a block, sums far past 2^32
+    (1 << 16, 2**31 - 1),    # 64 rows
+    (1 << 21, 2**31 - 1),    # two levels of row totals
+    (1 << 20, 3),            # a million small counts: the usual join
+])
+@pytest.mark.parametrize("outer", [False, True])
+def test_join_out_starts_is_an_exact_int64_scan(capacity, top, outer):
+    """``join_out_starts`` scans its counts in 32-bit rows and counts
+    the wraps (an int64 scan is the chip's slowest compile): the ends
+    are numpy's int64 cumsum of the same counts to the last unit, also
+    where one count alone is 2^31 - 1 and the total passes 2^50."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import joins as J
+    rng = np.random.default_rng(capacity + top)
+    counts = rng.integers(0, top, size=capacity, endpoint=True) \
+        .astype(np.int32)
+    counts[rng.random(capacity) < 0.3] = 0
+    counts[capacity // 2] = top
+    probe_n = capacity - 17
+    count, starts, ends, total = J.join_out_starts(
+        jnp.asarray(counts), jnp.int32(probe_n), outer)
+    live = np.arange(capacity) < probe_n
+    want = counts.astype(np.int64)
+    if outer:
+        want = np.where(live & (want == 0), 1, want)
+    want = np.where(live, want, 0)
+    want_ends = np.cumsum(want)
+    assert ends.dtype == starts.dtype == total.dtype == jnp.int64
+    np.testing.assert_array_equal(np.asarray(count), want)
+    np.testing.assert_array_equal(np.asarray(ends), want_ends)
+    np.testing.assert_array_equal(np.asarray(starts), want_ends - want)
+    assert int(total) == int(want_ends[-1])

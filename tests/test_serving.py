@@ -400,6 +400,7 @@ def test_concurrent_scopes_do_not_clobber_each_other():
     must not un-wedge a concurrent client's injected hang)."""
     armed = {}
     entered = threading.Barrier(2)
+    both_armed = threading.Barrier(2)
     release = threading.Event()
 
     def tenant(i):
@@ -407,6 +408,10 @@ def test_concurrent_scopes_do_not_clobber_each_other():
             entered.wait()
             armed[i] = I.inject("memory.oom", count=5,
                                 all_threads=True)
+            # tenant 0 must not leave before tenant 1 has armed (on a
+            # loaded host it did, and the assert below read armed[1]
+            # before it was there)
+            both_armed.wait()
             if i == 0:
                 return  # exits first — removes only ITS rule
             release.wait(timeout=10)
