@@ -19,6 +19,7 @@ uint8 chars[char_capacity]) mirroring Arrow/cudf layout but padded.
 
 from __future__ import annotations
 
+import threading
 from typing import Optional, Sequence
 
 import numpy as np
@@ -112,6 +113,37 @@ class RowCount:
         if self._value is not None:
             return f"RowCount({self._value})"
         return "RowCount(<device>)"
+
+
+class StringLayoutMetrics:
+    """Rows of host string columns by how they were laid out:
+    ``string_rows_buffered`` from Arrow's own buffers
+    (``Column.from_arrow``), ``string_placeholder_rows`` the all-NULL
+    columns the file scan puts where it pruned one
+    (io/readers.py ``_finish_batch``), ``string_rows_listed`` through
+    ``Column.from_strings``' loop over Python objects, counted there
+    whoever calls it.  Plain ints, bumped with tracing on or off."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.string_rows_buffered = self.string_placeholder_rows = \
+            self.string_rows_listed = 0
+
+    def note(self, buffered: int = 0, placeholder: int = 0,
+             listed: int = 0) -> None:
+        with self._lock:
+            self.string_rows_buffered += buffered
+            self.string_placeholder_rows += placeholder
+            self.string_rows_listed += listed
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"string_rows_buffered": self.string_rows_buffered,
+                    "string_placeholder_rows": self.string_placeholder_rows,
+                    "string_rows_listed": self.string_rows_listed}
+
+
+string_metrics = StringLayoutMetrics()
 
 
 def _decimal_unscaled(arr, validity: Optional[np.ndarray]) -> np.ndarray:
@@ -349,21 +381,36 @@ class Column:
                 encoded.append(str(s).encode("utf-8"))
         offsets = np.zeros(nrows + 1, dtype=np.int32)
         np.cumsum([len(b) for b in encoded], out=offsets[1:] if nrows else None)
-        total = int(offsets[-1]) if nrows else 0
-        chars = np.frombuffer(b"".join(encoded), dtype=np.uint8) if total else \
-            np.zeros(0, dtype=np.uint8)
+        chars = np.frombuffer(b"".join(encoded), dtype=np.uint8)
+        string_metrics.note(listed=nrows)
+        return cls.from_string_buffers(offsets, chars, nrows, validity=valid,
+                                       capacity=capacity,
+                                       char_capacity=char_capacity)
+
+    @classmethod
+    def from_string_buffers(cls, offsets: np.ndarray, chars: np.ndarray,
+                            nrows: int,
+                            validity: Optional[np.ndarray] = None,
+                            capacity: Optional[int] = None,
+                            char_capacity: Optional[int] = None) -> "Column":
+        """The one place that lays a host string column out: ``offsets``
+        are int32[nrows + 1] starting at zero, ``chars`` the uint8 bytes
+        they index, ``validity`` bool[nrows] or None.  Offsets are padded
+        to ``capacity + 1`` with the tail repeating the total, chars with
+        zeros to ``char_capacity``; validity is kept (padded with False)
+        only where some row is NULL."""
+        total = int(offsets[nrows])
         cap = capacity or bucket_capacity(nrows)
         ccap = char_capacity or bucket_capacity(max(total, 1))
-        off_buf = np.zeros(cap + 1, dtype=np.int32)
+        off_buf = np.empty(cap + 1, dtype=np.int32)
         off_buf[: nrows + 1] = offsets
-        off_buf[nrows + 1:] = offsets[-1] if nrows else 0
+        off_buf[nrows + 1:] = total
         char_buf = np.zeros(ccap, dtype=np.uint8)
         char_buf[:total] = chars
         dev_validity = None
-        if not valid.all():
-            v = np.zeros(cap, dtype=np.bool_)
-            v[:nrows] = valid
-            dev_validity = v
+        if validity is not None and not validity.all():
+            dev_validity = np.zeros(cap, dtype=np.bool_)
+            dev_validity[:nrows] = validity
         return cls(dts.STRING, char_buf, nrows,
                    validity=dev_validity, offsets=off_buf)
 
@@ -439,7 +486,7 @@ class Column:
             arr = arr.dictionary_decode()
         dtype = dts.from_arrow_type(arr.type)
         if dtype.is_string:
-            return cls.from_strings(arr.to_pylist(), capacity=capacity)
+            return cls._from_arrow_strings(arr, capacity)
         if dtype.is_array:
             return cls.from_arrays(arr.to_pylist(), dtype.element,
                                    capacity=capacity)
@@ -464,6 +511,49 @@ class Column:
             values = np_arr.astype(dtype.storage, copy=False)
         return cls.from_numpy(values, dtype=dtype, validity=validity,
                               capacity=capacity)
+
+    @classmethod
+    def _from_arrow_strings(cls, arr, capacity: Optional[int]) -> "Column":
+        """A string / large_string array's own buffers as a host column:
+        what ``from_strings(arr.to_pylist())`` lays out, with no Python
+        object a row."""
+        import pyarrow as pa
+        nrows = len(arr)
+        string_metrics.note(buffered=nrows)
+        if not nrows:
+            return cls.from_string_buffers(
+                np.zeros(1, dtype=np.int32), np.zeros(0, dtype=np.uint8), 0,
+                capacity=capacity)
+        bitmap, offs_buf, data = arr.buffers()
+        wide = np.dtype(np.int64 if pa.types.is_large_string(arr.type)
+                        else np.int32)
+        offsets = np.frombuffer(offs_buf, dtype=wide, count=nrows + 1,
+                                offset=arr.offset * wide.itemsize)
+        first, last = int(offsets[0]), int(offsets[-1])
+        if last - first > np.iinfo(np.int32).max:
+            raise ValueError(
+                f"string column of {last - first} chars: a column's int32 "
+                f"offsets hold at most 2^31 - 1")
+        if first:
+            offsets = offsets - first
+        offsets = offsets.astype(np.int32, copy=False)
+        chars = np.frombuffer(data, dtype=np.uint8)[first:last] \
+            if last > first else np.zeros(0, dtype=np.uint8)
+        validity = None
+        if arr.null_count:
+            start = arr.offset % 8
+            bits = np.frombuffer(bitmap, dtype=np.uint8)[
+                arr.offset // 8: (arr.offset + nrows + 7) // 8]
+            validity = np.unpackbits(bits, bitorder="little")[
+                start: start + nrows].view(np.bool_)
+            # Arrow lets a NULL slot keep bytes; a column's NULL is empty
+            lens = np.diff(offsets)
+            if lens[~validity].any():
+                chars = chars[np.repeat(validity, lens)]
+                offsets = np.zeros(nrows + 1, dtype=np.int32)
+                np.cumsum(np.where(validity, lens, 0), out=offsets[1:])
+        return cls.from_string_buffers(offsets, chars, nrows,
+                                       validity=validity, capacity=capacity)
 
     # ------------------------------------------------------------- host export --
     def to_numpy(self) -> np.ndarray:

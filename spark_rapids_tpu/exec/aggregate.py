@@ -57,9 +57,12 @@ class _StringKeyEncoder:
         return Column.from_numpy(out, dtype=dts.INT32, capacity=col.capacity)
 
     def decode(self, col: Column) -> Column:
-        codes = col.to_numpy()
-        return Column.from_strings([self.values[c] for c in codes],
-                                   capacity=col.capacity)
+        # the distinct values once, the rows by Arrow's own take: a NULL
+        # key is a NULL of the dictionary
+        import pyarrow as pa
+        keys = pa.DictionaryArray.from_arrays(
+            col.to_numpy(), pa.array(self.values, type=pa.string()))
+        return Column.from_arrow(keys, capacity=col.capacity)
 
 
 from spark_rapids_tpu.ops.aggregates import merge_kind as _merge_kind  # noqa: E402

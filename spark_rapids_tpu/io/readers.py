@@ -17,6 +17,8 @@ from __future__ import annotations
 
 from typing import Iterator, List, Optional
 
+import numpy as np
+
 from spark_rapids_tpu.columnar import dtypes as dts
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.config import rapids_conf as rc
@@ -212,20 +214,25 @@ class TpuFileScanExec(TpuExec):
             return batch.select([n for n, _ in self._schema]) \
                 if batch.names != [n for n, _ in self._schema] else batch
         import jax.numpy as jnp
-        from spark_rapids_tpu.columnar.column import Column
+        from spark_rapids_tpu.columnar.column import Column, string_metrics
         cols = {}
         cap = batch.capacity
+        n = batch.nrows
+        no_offsets = np.zeros(n + 1, dtype=np.int32)
+        no_chars = np.zeros(0, dtype=np.uint8)
+        all_null = np.zeros(n, dtype=np.bool_)
         for name, dt in self._schema:
             if name in batch.columns:
                 cols[name] = batch.columns[name]
             elif dt.is_string:
-                c = Column.from_strings([None] * batch.nrows, capacity=cap)
-                cols[name] = c
+                cols[name] = Column.from_string_buffers(
+                    no_offsets, no_chars, n, validity=all_null, capacity=cap)
+                string_metrics.note(placeholder=n)
             else:
                 cols[name] = Column(
-                    dt, jnp.zeros(cap, dtype=dt.storage), batch.nrows,
+                    dt, jnp.zeros(cap, dtype=dt.storage), n,
                     validity=jnp.zeros(cap, dtype=jnp.bool_))
-        return ColumnarBatch(cols, batch.nrows)
+        return ColumnarBatch(cols, n)
 
     def _attach_meta(self, batch: ColumnarBatch, path: str
                      ) -> ColumnarBatch:

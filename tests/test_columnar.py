@@ -128,3 +128,104 @@ def test_conf_registry():
         conf.set("spark.rapids.sql.explain", "BOGUS").get(EXPLAIN)
     docs = RapidsConf.generate_docs()
     assert "spark.rapids.sql.batchSizeBytes" in docs
+
+
+def _assert_same_layout(got: Column, want: Column):
+    assert got.dtype == want.dtype and got.nrows == want.nrows
+    for read in ("host_offsets", "host_values", "host_validity"):
+        g, w = getattr(got, read)(), getattr(want, read)()
+        assert (g is None) == (w is None), read
+        if w is not None:
+            assert g.dtype == w.dtype and g.shape == w.shape, read
+            np.testing.assert_array_equal(g, w, err_msg=read)
+
+
+def _null_slot_with_bytes():
+    # Arrow does not promise that a NULL slot is empty: rows "ab", NULL
+    # (holding "cd"), "e", NULL (empty), "fgh"
+    return pa.Array.from_buffers(
+        pa.string(), 5,
+        [pa.py_buffer(np.packbits([1, 0, 1, 0, 1], bitorder="little")),
+         pa.py_buffer(np.array([0, 2, 4, 5, 5, 8], dtype=np.int32)),
+         pa.py_buffer(b"abcdefgh")], null_count=2)
+
+
+_ARROW_STRING_CASES = {
+    "ascii": lambda: pa.array(["hello", "tpu", "a", "columnar"]),
+    "utf8": lambda: pa.array(["wörld", "日本語", "a", "ß", "👍 ok"]),
+    "empty_strings": lambda: pa.array(["", "x", "", ""]),
+    "nulls": lambda: pa.array(["a", None, "", None, "bcd"] * 5),
+    "all_null": lambda: pa.array([None] * 9, type=pa.string()),
+    "zero_rows": lambda: pa.array([], type=pa.string()),
+    "slice": lambda: pa.array(
+        [None if i % 7 == 3 else "r%d" % i for i in range(100)]
+    ).slice(13, 61),
+    "chunked": lambda: pa.chunked_array(
+        [pa.array(["a", None, "bc"]), pa.array([], type=pa.string()),
+         pa.array(["déf", ""]).slice(1), pa.array(["ghi"] * 40).slice(3, 9)]),
+    "large_string": lambda: pa.array(["big", None, "offsets", ""],
+                                     type=pa.large_string()).slice(1),
+    "dictionary": lambda: pa.array(
+        ["N", "R", None, "A", "N", "N"]).dictionary_encode(),
+    "dictionary_null_value": lambda: pa.DictionaryArray.from_arrays(
+        np.array([2, 0, 1, 1, 0], dtype=np.int32),
+        pa.array(["N", None, "A"], type=pa.string())),
+    "capacity": lambda: pa.array(["p", "qr", None]),
+    "null_slot_with_bytes": _null_slot_with_bytes,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ARROW_STRING_CASES))
+def test_from_arrow_strings_match_from_strings(case):
+    arr = _ARROW_STRING_CASES[case]()
+    capacity = 4096 if case == "capacity" else None
+    got = Column.from_arrow(arr, capacity=capacity)
+    want = Column.from_strings(arr.to_pylist(), capacity=capacity)
+    _assert_same_layout(got, want)
+    assert got.to_pylist() == arr.to_pylist()
+    if case == "null_slot_with_bytes":
+        assert arr.to_pylist() == ["ab", None, "e", None, "fgh"]
+        assert got.host_values()[:6].tobytes() == b"abefgh"
+
+
+def test_from_arrow_strings_never_list_a_row(monkeypatch):
+    """2^20 rows of two string columns become host columns with no list
+    of Python objects: the loop such a list went through is patched to
+    raise (pyarrow's own ``to_pylist`` sits on an immutable type and
+    cannot be), and the counter says which way every row went."""
+    from spark_rapids_tpu.columnar.column import string_metrics
+    n = 1 << 20
+    flags = np.array(["A", "N", "R"])[np.arange(n) % 3]
+    table = pa.table({"flag": pa.array(flags),
+                      "note": pa.array(flags, mask=np.arange(n) % 5 == 0)})
+
+    def listed(*args, **kwargs):
+        raise AssertionError("a list of rows on the way to a host column")
+    monkeypatch.setattr(Column, "from_strings", listed)
+    before = string_metrics.snapshot()
+    batch = ColumnarBatch.from_arrow(table)
+    after = string_metrics.snapshot()
+    assert after["string_rows_buffered"] - \
+        before["string_rows_buffered"] == 2 * n
+    assert after["string_rows_listed"] == before["string_rows_listed"]
+    assert after["string_placeholder_rows"] == \
+        before["string_placeholder_rows"]
+    flag, note = batch.columns["flag"], batch.columns["note"]
+    assert flag.nrows == n and flag.capacity == n and not flag.has_nulls
+    np.testing.assert_array_equal(
+        flag.host_offsets(), np.arange(n + 1, dtype=np.int32))
+    assert flag.host_values()[:6].tobytes() == b"ANRANR"
+    assert note.null_count() == -(-n // 5)
+    assert int(note.host_offsets()[-1]) == n - note.null_count()
+
+
+def test_from_arrow_large_string_past_int32_is_refused():
+    # offsets that span 2^31 chars over a data buffer that only claims to
+    # be that long: the column is refused before a char is read
+    offsets = np.array([0, 5, 1 << 31, (1 << 31) + 1], dtype=np.int64)
+    five = pa.py_buffer(b"hello")
+    data = pa.foreign_buffer(five.address, (1 << 31) + 1, base=five)
+    arr = pa.Array.from_buffers(
+        pa.large_string(), 3, [None, pa.py_buffer(offsets), data])
+    with pytest.raises(ValueError, match="int32"):
+        Column.from_arrow(arr)

@@ -121,3 +121,42 @@ def test_orc_roundtrip(session, tmp_path):
     back = session.read.orc(path).to_pandas().sort_values("a") \
         .reset_index(drop=True)
     pd.testing.assert_frame_equal(back, pdf, check_dtype=False)
+
+
+def test_scan_string_columns_and_placeholders_from_buffers(tmp_path):
+    """Read string columns come from Arrow's buffers and pruned ones are
+    the all-NULL column a list of None would give, with no row listed."""
+    from spark_rapids_tpu.columnar import dtypes as dts
+    from spark_rapids_tpu.columnar.column import Column, string_metrics
+    from spark_rapids_tpu.io.readers import TpuFileScanExec
+    from tests.test_columnar import _assert_same_layout
+    n = 3000
+    table = pa.table({
+        "k": pa.array(np.arange(n, dtype=np.int64)),
+        "flag": pa.array([None if i % 11 == 0 else "ANR"[i % 3]
+                          for i in range(n)]),
+        "comment": pa.array([f"cömment {i}" * (i % 4) for i in range(n)]),
+        "mode": pa.array(["AIR", "RAIL"] * (n // 2)),
+    })
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(table, path, row_group_size=1000)
+    schema = [("k", dts.INT64), ("flag", dts.STRING),
+              ("comment", dts.STRING), ("mode", dts.STRING)]
+    scan = TpuFileScanExec([path], "parquet", schema, batch_rows=1024,
+                           columns=["k", "flag", "comment"])
+    before = string_metrics.snapshot()
+    batches = list(scan.execute())
+    moved = {k: v - before[k] for k, v in string_metrics.snapshot().items()}
+    assert moved == {"string_rows_buffered": 2 * n,
+                     "string_placeholder_rows": n, "string_rows_listed": 0}
+    assert sum(b.nrows for b in batches) == n and len(batches) > 1
+    rows = {name: [] for name, _ in schema}
+    for b in batches:
+        assert b.names == [name for name, _ in schema]
+        want = Column.from_strings([None] * b.nrows, capacity=b.capacity)
+        _assert_same_layout(b.columns["mode"], want)
+        for name in rows:
+            rows[name] += b.columns[name].to_pylist()
+    assert rows["mode"] == [None] * n
+    for name in ("k", "flag", "comment"):
+        assert rows[name] == table.column(name).to_pylist()
