@@ -110,16 +110,12 @@ for enabled in (True, False):
           f"trail: {[r['action'] for r in s.recovery_log]})")
 PY
 
-echo "== fused-wire + hash-kernel spray (both knobs on; exchange/spill/oom faults; forced slot-table overflow) =="
-# two legs: (1) wire-fused distributed stages — the warm
-# speculative launch folds the wire packer into the compute
-# program (one launch per shard, pinned by fusedWireStages)
-# and exchange faults then land on the fused program; (2) the
-# hash group-by with tableSlots forced far below the live key
-# count, so every launch overflows and must fall back to the
-# exact sort kernel.  Gates: bit-exact answers everywhere, the
-# overflow-fallback counter actually fired, clean recovery
-# trails.
+echo "== fused-wire spray (fusion.wire on; exchange/spill/oom faults) =="
+# wire-fused distributed stages — the warm speculative launch
+# folds the wire packer into the compute program (one launch
+# per shard, pinned by fusedWireStages) and exchange faults
+# then land on the fused program.  Gates: bit-exact answers,
+# clean recovery trails.
 python - <<'PY'
 import numpy as np
 import pandas as pd
@@ -133,8 +129,7 @@ from spark_rapids_tpu.robustness import inject as I
 
 rng = np.random.default_rng(7)
 # sparse 2^40 keyspace: the coded dense-directory path refuses, so the
-# hash-kernel dispatch is actually exercised (dense small keys would
-# route to direct indexing and spray nothing new)
+# sort / segment kernel feeds the fused wire
 uni = np.unique(rng.integers(0, 1 << 40, 8000, dtype=np.int64))[:2000]
 pdf = pd.DataFrame({"k": uni[rng.integers(0, len(uni), 4000)],
                     "v": rng.integers(0, 1000, 4000).astype(np.float64)})
@@ -150,16 +145,13 @@ base = TpuSession({})
 want = plan(base).to_pandas().sort_values("k", ignore_index=True)
 base.stop()
 
-# leg 1: wire-fused distributed stages under exchange faults.  Warm
-# speculative launches fold the wire packer into the compute program
-# (fusedWireStages pins it); sprayed faults then land on the fused
-# exchange and every answer must still be bit-exact.
+# warm speculative launches fold the wire packer into the compute
+# program (fusedWireStages pins it); sprayed faults then land on the
+# fused exchange and every answer must still be bit-exact.
 fusion_metrics.reset()
 s = TpuSession({
-    "spark.rapids.tpu.pallas.hash.enabled": True,
-    "spark.rapids.tpu.pallas.hash.tableSlots": 65536,
     "spark.rapids.tpu.fusion.wire.enabled": True,
-    # looser than the checkpoint spray's 500ms: the sparse-key hash
+    # looser than the checkpoint spray's 500ms: the sparse-key sort
     # path is legitimately slower than the coded directory on CPU, and
     # a trip inside the demoted (last) rung has no rung left to catch it
     "spark.rapids.tpu.watchdog.defaultDeadlineMs": 2000,
@@ -183,36 +175,6 @@ with I.scoped_rules():
 pd.testing.assert_frame_equal(got, want)
 m = fusion_metrics.snapshot()
 print(f"fused-wire spray OK (fusedWireStages={m['fusedWireStages']}, "
-      f"trail: {[r['action'] for r in s.recovery_log]})")
-s.stop()
-
-# leg 2: hash-kernel group-by under spill/oom faults, with the slot
-# table forced to overflow (tableSlots=64 << 2000 live keys).  Every
-# launch must come back overflowed, fall back to the exact sort
-# kernel, and still answer with clean-run results — rows are never
-# dropped, the fallback counter proves the rung actually fired.
-fusion_metrics.reset()
-s = TpuSession({
-    "spark.rapids.tpu.pallas.hash.enabled": True,
-    "spark.rapids.tpu.pallas.hash.tableSlots": 64,
-    # no tight watchdog here: the overflow rung legitimately pays the
-    # hash launch AND the full sort fallback in one pipeline step
-    "spark.rapids.tpu.watchdog.defaultDeadlineMs": 5000,
-    "spark.rapids.sql.recovery.backoffMs": 5,
-})
-df = plan(s)
-with I.scoped_rules():
-    for point, kind in (("spill.corrupt.host", "corrupt"),
-                        ("memory.oom", "raise")):
-        I.inject(point, kind=kind, count=2, probability=0.5,
-                 seed=37, delay_s=0.2, all_threads=True)
-    got = df.to_pandas().sort_values("k", ignore_index=True)
-pd.testing.assert_frame_equal(got, want)
-m = fusion_metrics.snapshot()
-assert m["hashKernelLaunches"] >= 1, m
-assert m["hashOverflowFallbacks"] >= 1, m
-print(f"hash overflow spray OK (launches={m['hashKernelLaunches']} "
-      f"fallbacks={m['hashOverflowFallbacks']}, "
       f"trail: {[r['action'] for r in s.recovery_log]})")
 s.stop()
 PY

@@ -62,9 +62,6 @@ print(f"trace smoke OK (unattributed={sp['unattributedFrac']:.1%}, "
       f"{len(files)} file(s) valid)")
 PY
 
-echo "== cost-model zero-conf smoke (reduced TPC-H A/B: hand-tuned confs vs every tuned conf unset + costModel on — every answer matched, decisions ledgered; bench.py --zero-conf runs the full sweep) =="
-BENCH_ZERO_CONF_QUERIES="q1,q3,q6" python bench.py --zero-conf
-
 echo "== docgen drift check =="
 tmp=$(mktemp -d)
 python -m spark_rapids_tpu.tools.docgen "$tmp"

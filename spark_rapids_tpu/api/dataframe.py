@@ -871,7 +871,7 @@ class DataFrame:
             # plan has no batch knob, so re-offering it would re-run
             # the identical plan that just failed
             from spark_rapids_tpu.exec.fusion import (fusion_metrics,
-                                                      hash_wire_delta)
+                                                      wire_delta)
             from spark_rapids_tpu.ops.jit_cache import persistent_info
             from spark_rapids_tpu.parallel.dist_planner import (
                 try_distributed)
@@ -929,7 +929,7 @@ class DataFrame:
                                   or {})
                     fusion.update(_persistent_delta(pjit0,
                                                     persistent_info()))
-                    fusion.update(hash_wire_delta(fm0))
+                    fusion.update(wire_delta(fm0))
                     sh = self._sharing_info()
                     fleet = None
                     if gray is not None:
@@ -1079,7 +1079,7 @@ class DataFrame:
         events = getattr(self.session, "events", None)
         if events is None or not events.enabled:
             from spark_rapids_tpu.exec.fusion import (
-                collect_runtime_savings, fusion_metrics, hash_wire_delta)
+                collect_runtime_savings, fusion_metrics, wire_delta)
             from spark_rapids_tpu.ops.jit_cache import persistent_info
             self.session._current_qid = None
             p0 = persistent_info()
@@ -1098,7 +1098,7 @@ class DataFrame:
                 fusion = dict(getattr(ov, "last_fusion", None) or {})
                 fusion.update(collect_runtime_savings(exec_plan))
                 fusion.update(_persistent_delta(p0, persistent_info()))
-                fusion.update(hash_wire_delta(fm0))
+                fusion.update(wire_delta(fm0))
                 self.session.last_fusion_stats = fusion
                 # span drain runs with or without an event log: bench
                 # reads session.last_span_stats, and trace files must
@@ -1154,12 +1154,12 @@ class DataFrame:
             # from the planner, runtime dispatch savings from the
             # executed tree, persistent-tier deltas from the jit cache
             from spark_rapids_tpu.exec.fusion import (
-                collect_runtime_savings, hash_wire_delta)
+                collect_runtime_savings, wire_delta)
             ov = overrides or self.session.overrides
             fusion = dict(getattr(ov, "last_fusion", None) or {})
             fusion.update(collect_runtime_savings(exec_plan))
             fusion.update(_persistent_delta(pjit0, persistent_info()))
-            fusion.update(hash_wire_delta(fm0))
+            fusion.update(wire_delta(fm0))
             self.session.last_fusion_stats = fusion
             wall_ms = (_time.perf_counter() - t0) * 1e3
             spans = tracing.finish_query(self.session, qid, wall_ms,

@@ -117,8 +117,8 @@ class PreparedStatement:
         return tuple(vals)
 
     def run_batches(self, *args, **params):
-        """Bind and execute, returning raw columnar batches — the
-        no-conversion entry the QPS bench drives."""
+        """Bind and execute, returning raw columnar batches (no
+        conversion to pandas)."""
         values = self._resolve(args, params)
         with self._lock:
             self.info.bind(values)

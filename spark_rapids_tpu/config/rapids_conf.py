@@ -196,11 +196,6 @@ CONCURRENT_TPU_TASKS = conf(
     "(reference `concurrentGpuTasks` RapidsConf.scala:423).", _to_int,
     _positive)
 
-HAS_NANS = conf(
-    "spark.rapids.sql.hasNans", True,
-    "Assume floating point values may be NaN; some float aggregations "
-    "refuse to run when set (reference RapidsConf.scala:549).", _to_bool)
-
 DECIMAL_ENABLED = conf(
     "spark.rapids.sql.decimalType.enabled", True,
     "Enable decimal (DECIMAL_64) processing: device arithmetic with "
@@ -224,16 +219,6 @@ INCOMPAT_ENABLED = conf(
     "defaults ON because each incompat is individually documented and "
     "per-op keys (spark.rapids.sql.expression.<Name>) can disable any "
     "single one.", _to_bool)
-
-IMPROVED_FLOAT_OPS = conf(
-    "spark.rapids.sql.improvedFloatOps.enabled", False,
-    "Allow float ops whose results may differ from CPU beyond 1-ulp.",
-    _to_bool)
-
-UDF_COMPILER_ENABLED = conf(
-    "spark.rapids.sql.udfCompiler.enabled", False,
-    "Compile Python UDF bytecode into TPU expression trees "
-    "(reference udf-compiler, RapidsConf.scala:519).", _to_bool)
 
 REGEXP_ENABLED = conf(
     "spark.rapids.sql.regexp.enabled", True,
@@ -316,10 +301,6 @@ SPILL_DISK_WRITE_THREADS = conf(
     "(reference spill-thread sizing, RapidsConf.scala:393).",
     _to_int, _positive)
 
-SPILL_ENABLED = conf(
-    "spark.rapids.memory.tpu.spillEnabled", True,
-    "Enable HBM->host->disk spilling of spillable batches.", _to_bool)
-
 DEVICE_MEMORY_LIMIT = conf(
     "spark.rapids.memory.tpu.deviceLimitBytes", 0,
     "Device-pool budget in bytes for spillable batches; 0 = derive from HBM "
@@ -366,12 +347,6 @@ DISTRIBUTED_NUM_SHARDS = conf(
     "--xla_force_host_platform_device_count=N to be set BEFORE jax "
     "initializes; session construction raises otherwise.", _to_int,
     lambda v: None if v >= 0 else "must be >= 0")
-
-SHUFFLE_TRANSPORT_ENABLED = conf(
-    "spark.rapids.shuffle.transport.enabled", True,
-    "Use the ICI all-to-all collective exchange when executing on a device "
-    "mesh (the UCX-transport analog, reference RapidsConf.scala:986); "
-    "otherwise serialize through the host shuffle store.", _to_bool)
 
 SHUFFLE_PACKED_ENABLED = conf(
     "spark.rapids.tpu.shuffle.packed.enabled", True,
@@ -748,30 +723,6 @@ FUSION_ENABLED = conf(
     "those chains auto-fall-back to unfused execution. False restores "
     "one-dispatch-per-operator execution (the A/B baseline; results are "
     "bit-identical either way).", _to_bool)
-
-PALLAS_HASH_ENABLED = conf(
-    "spark.rapids.tpu.pallas.hash.enabled", False,
-    "Hash-table group-by and join phase-A (ops/pallas_kernels.py): a "
-    "single-pass open-addressing table over the 64-bit coded key "
-    "replaces the sort/segment-sum formulation where the dense coded "
-    "table cannot fit (high-cardinality keys) and on the single-key "
-    "inner/left/semi/anti join probe.  A pallas kernel owns the "
-    "VMEM-resident table on real TPUs; elsewhere a round-based XLA "
-    "formulation runs the same contract.  Probe-chain overflow raises a "
-    "flag and the launch DISCARDS the hash output and re-runs the "
-    "current sort path (rows are never dropped), recorded in the "
-    "fusion-metrics breadcrumb family.  False (default) is a full A/B: "
-    "results are bit-identical either way.", _to_bool)
-
-PALLAS_HASH_TABLE_SLOTS = conf(
-    "spark.rapids.tpu.pallas.hash.tableSlots", 1 << 16,
-    "Slot count of the hash group-by table (power of two).  Bounds "
-    "distinct groups per launch — more groups than slots (or a probe "
-    "chain past the 256-step bound) overflows to the sort path.  Also "
-    "the VMEM bound: the table is 3 i32 lanes, 12 bytes/slot, so 2^20 "
-    "slots (~12 MB) is the practical ceiling on-chip.", _to_int,
-    lambda v: None if v >= 64 and (v & (v - 1)) == 0
-    else "must be a power of two >= 64")
 
 FUSION_WIRE_ENABLED = conf(
     "spark.rapids.tpu.fusion.wire.enabled", False,
@@ -1431,13 +1382,6 @@ TEST_ALLOWED_NON_TPU = conf(
     "spark.rapids.sql.test.allowedNonTpu", "",
     "Comma-separated op names tolerated on CPU in strict test mode "
     "(reference `test.allowedNonGpu`).", str, internal=True)
-
-METRICS_LEVEL = conf(
-    "spark.rapids.sql.metrics.level", "MODERATE",
-    "Operator metric verbosity: ESSENTIAL, MODERATE, DEBUG "
-    "(reference GpuExec.scala MetricsLevel).", str,
-    lambda v: None if v in ("ESSENTIAL", "MODERATE", "DEBUG") else
-    "must be ESSENTIAL, MODERATE or DEBUG")
 
 
 # dynamic per-op enable keys (confKey wiring, GpuOverrides.scala:204-296):

@@ -584,71 +584,6 @@ class TpuHashAggregateExec(TpuExec):
         """Sync the probe scalars and size the key space."""
         return self._coded_pick_host(*self._sync_range(mins, maxs))
 
-    def _hash_pick_host(self, mins_h, maxs_h):
-        """Size the hashed key space from host-resident probe results:
-        the cap is only that the radix strides fit int64 (2^62), far
-        past the coded path's materialized-directory bound.  None when
-        a key column is non-radixable or the product overflows."""
-        mins_h = np.asarray(mins_h)
-        maxs_h = np.asarray(maxs_h)
-        pick = agg.hashed_slot_ranges(mins_h, maxs_h)
-        if pick is None:
-            return None
-        slots, _total = pick
-        return (jnp.asarray(np.minimum(mins_h, maxs_h)),
-                jnp.asarray(np.asarray(slots, dtype=np.int64)))
-
-    def _hashed_update(self, table_slots: int):
-        """Build the hashed stage-B body (cached_jit per table size):
-        the same fused expression re-evaluation as the coded body, but
-        the group directory is an open-addressing hash table over the
-        radix code — used when the key space exceeds the coded cap."""
-
-        def run(flat_cols, nrows, mask, mins, slot_ranges, params=()):
-            capacity = capacity_of(flat_cols)
-            inputs = flat_to_colvals(flat_cols, self._in_dtypes)
-            ctx = EmitContext(inputs, nrows, capacity,
-                              params=params_dict(self._slots, params))
-            if self.pre_filters:
-                ctx.extra_check_mask = mask
-            keys = [agg.widen_colval(e.emit(ctx), capacity)
-                    for e in self._kgroup]
-            buf_inputs = self._eval_update_inputs(ctx)
-            out_keys, out_bufs, n, ovf = agg.groupby_aggregate_hashed(
-                keys, buf_inputs, nrows, capacity, mins, slot_ranges,
-                table_slots, row_mask=mask)
-            return ([(k.values, k.validity) for k in out_keys],
-                    [(b.values, b.validity) for b in out_bufs], n, ovf)
-
-        return run
-
-    def _try_hashed(self, flat, nrows, mask, mins_h, maxs_h):
-        """Attempt the hash-table stage B.  Returns ``(key_out,
-        buf_out, n)`` or None — disabled, ineligible key space, or
-        table overflow; the caller then runs the exact sort kernel, so
-        rows are never dropped.  Overflow fallbacks leave a breadcrumb
-        for the "fusible chain ran unfused" health-check family."""
-        from spark_rapids_tpu.ops import pallas_kernels as pk
-        enabled, table_slots = pk.hash_dispatch_conf()
-        if not enabled:
-            return None
-        hp = self._hash_pick_host(mins_h, maxs_h)
-        if hp is None:
-            return None
-        from spark_rapids_tpu.exec.fusion import fusion_metrics
-        from spark_rapids_tpu.ops.jit_cache import cached_jit
-        mins_d, slots_d = hp
-        fn = cached_jit(
-            ("agg_hashed_update", table_slots) + self._base_sig,
-            lambda: self._hashed_update(table_slots))
-        key_out, buf_out, n, ovf = fn(flat, nrows, mask, mins_d,
-                                      slots_d, self._pargs())
-        fusion_metrics.bump("hashKernelLaunches")
-        if bool(hostsync.fetch(ovf)):
-            fusion_metrics.bump("hashOverflowFallbacks")
-            return None
-        return key_out, buf_out, n
-
     def _wrap_count(self, n) -> RowCount:
         """Device group count -> RowCount; eager mode forces (and
         counts) the sync immediately, preserving the sequential
@@ -685,21 +620,10 @@ class TpuHashAggregateExec(TpuExec):
             pick = self._coded_pick_host(mins_h, maxs_h)
         else:
             mask, mins, maxs = self._stage_a_fn(flat, nrows, self._pargs())
-            mins_h, maxs_h = self._sync_range(mins, maxs)
-            pick = self._coded_pick_host(mins_h, maxs_h)
+            pick = self._coded_pick(mins, maxs)
         if pick is None:
-            # key space past the coded directory: the hash table next,
-            # then (disabled/overflow) the fully fused sort kernel
-            got = self._try_hashed(flat, nrows, mask, mins_h, maxs_h)
-            if got is not None:
-                key_out, buf_out, n = got
-                n_rc = self._wrap_count(n)
-                outs = [ColVal(dt, v, val) for dt, (v, val) in
-                        zip(dtypes, list(key_out) + list(buf_out))]
-                out_cap = key_out[0][0].shape[0] if key_out else \
-                    buf_out[0][0].shape[0]
-                cols = colvals_to_columns(outs, n_rc, out_cap)
-                return ColumnarBatch(dict(zip(names, cols)), n_rc)
+            # key space past the coded directory: the fully fused sort
+            # kernel
             key_flat, buf_flat, n = self._update_fn(flat, nrows,
                                                     self._pargs())
             n_rc = self._wrap_count(n)
@@ -904,54 +828,6 @@ class TpuHashAggregateExec(TpuExec):
 
         return run
 
-    def _merge_hashed(self, table_slots: int, finalize: bool):
-        """Build the hash-table merge kernel body for cached_jit."""
-        dtypes = [dt for _, dt in self._partial_schema]
-        nkeys = len(self.group_exprs)
-
-        def run(flat_cols, mins, slot_ranges, nrows):
-            capacity = capacity_of(flat_cols)
-            cols = flat_to_colvals(flat_cols, dtypes)
-            keys, bufs = cols[:nkeys], cols[nkeys:]
-            merge_inputs = [(k, c)
-                            for k, c in zip(self._merge_kinds, bufs)]
-            out_keys, out_bufs, n, ovf = agg.groupby_aggregate_hashed(
-                keys, merge_inputs, nrows, capacity, mins, slot_ranges,
-                table_slots)
-            if finalize:
-                results = [f.finalize(out_bufs[sl])
-                           for f, sl in zip(self.funcs, self._buf_slices)]
-            else:
-                results = out_bufs
-            return ([(k.values, k.validity, k.offsets) for k in out_keys],
-                    [(r.values, r.validity, r.offsets) for r in results],
-                    n, ovf)
-
-        return run
-
-    def _merge_try_hashed(self, flat, mins_h, maxs_h, nrows, finalize):
-        """Hash-table merge attempt; None means fall through to the
-        sort merge (disabled, ineligible, or table overflow)."""
-        from spark_rapids_tpu.ops import pallas_kernels as pk
-        enabled, table_slots = pk.hash_dispatch_conf()
-        if not enabled:
-            return None
-        hp = self._hash_pick_host(mins_h, maxs_h)
-        if hp is None:
-            return None
-        from spark_rapids_tpu.exec.fusion import fusion_metrics
-        from spark_rapids_tpu.ops.jit_cache import cached_jit
-        mins_d, slots_d = hp
-        fn = cached_jit(
-            ("agg_merge_hashed", finalize, table_slots) + self._base_sig,
-            lambda: self._merge_hashed(table_slots, finalize))
-        key_flat, buf_flat, n, ovf = fn(flat, mins_d, slots_d, nrows)
-        fusion_metrics.bump("hashKernelLaunches")
-        if bool(hostsync.fetch(ovf)):
-            fusion_metrics.bump("hashOverflowFallbacks")
-            return None
-        return key_flat, buf_flat, n
-
     def _merge_exec(self, merged_in: ColumnarBatch, finalize: bool):
         """Merge-stage dispatch mirroring the update stage: probe the
         partials' key ranges, run the coded kernel when the space fits.
@@ -977,8 +853,7 @@ class TpuHashAggregateExec(TpuExec):
         if self._coded_eligible:
             key_flat = [(v, val) for v, val, _ in flat[:nkeys]]
             mins, maxs = _probe_kernel(nkeys)(key_flat, nrows)
-            mins_h, maxs_h = self._sync_range(mins, maxs)
-            pick = self._coded_pick_host(mins_h, maxs_h)
+            pick = self._coded_pick(mins, maxs)
             if pick is not None:
                 from spark_rapids_tpu.ops.jit_cache import cached_jit
                 kb, mins_d, slots_d = pick
@@ -986,10 +861,6 @@ class TpuHashAggregateExec(TpuExec):
                     ("agg_merge_coded", finalize, kb) + self._base_sig,
                     lambda: self._merge_coded(kb, finalize))
                 return fn(flat, mins_d, slots_d, nrows)
-            got = self._merge_try_hashed(flat, mins_h, maxs_h, nrows,
-                                         finalize)
-            if got is not None:
-                return got
         fn = self._merge_fn if finalize else self._merge_partial_fn
         return fn(flat, nrows)
 

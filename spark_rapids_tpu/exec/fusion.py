@@ -44,16 +44,10 @@ DISPATCHES_SAVED = "dispatchesSaved"
 
 
 class FusionMetrics:
-    """Process-wide fusion counters (the checkpoint_metrics discipline),
-    surfaced by bench.py alongside the jit-cache counters."""
+    """Process-wide fusion counters (the checkpoint_metrics discipline)."""
 
     FIELDS = ("fusedStages", "fusedOperators", "fusibleChains",
               "fallbacks",
-              # Pallas hash-kernel dispatch breadcrumbs: launches that
-              # went through the hash table, and launches that came back
-              # with the overflow flag set and re-ran the sort kernel
-              # (rows are never dropped — the fallback is the exact path).
-              "hashKernelLaunches", "hashOverflowFallbacks",
               # Wire-fused distributed stages: stages that emitted the
               # packed wire payload inside the compute program, and warm
               # stages that COULD have fused but ran the two-dispatch
@@ -80,16 +74,15 @@ class FusionMetrics:
 
 fusion_metrics = FusionMetrics()
 
-# Hash-kernel / wire-fusion counters folded into each QueryEnd fusion
-# dict as per-query deltas of the process-wide counters above.  Only
-# non-zero deltas are merged: a query with no hash-kernel or wire-fusion
-# activity emits a fusion dict bit-identical to HEAD's.
-QUERY_DELTA_FIELDS = ("hashKernelLaunches", "hashOverflowFallbacks",
-                      "fusedWireStages", "wireUnfusedLaunches")
+# Wire-fusion counters folded into each QueryEnd fusion dict as
+# per-query deltas of the process-wide counters above.  Only non-zero
+# deltas are merged: a query with no wire-fusion activity emits a
+# fusion dict without them.
+QUERY_DELTA_FIELDS = ("fusedWireStages", "wireUnfusedLaunches")
 
 
-def hash_wire_delta(before: Dict[str, int]) -> Dict[str, int]:
-    """Non-zero per-query deltas of the hash/wire fusion counters since
+def wire_delta(before: Dict[str, int]) -> Dict[str, int]:
+    """Non-zero per-query deltas of the wire-fusion counters since
     ``before`` (a ``fusion_metrics.snapshot()`` taken at query start)."""
     now = fusion_metrics.snapshot()
     return {k: now.get(k, 0) - before.get(k, 0)
@@ -170,7 +163,7 @@ def collect_runtime_savings(exec_root: TpuExec) -> Dict[str, int]:
                     n.fused_ops * n.metrics[NUM_INPUT_BATCHES].value
             if getattr(n, "_encoded_exec", False):
                 # encoded execution: the stage ran on dictionary codes
-                # (bench encoded_stage_count / QueryEnd fusion dict)
+                # (QueryEnd fusion dict)
                 out["encodedStages"] += 1
         for c in n.children:
             rec(c)

@@ -26,7 +26,6 @@ from spark_rapids_tpu.ops import selection
 from spark_rapids_tpu.ops.compiler import StageFn
 from spark_rapids_tpu.ops.concat import concat_batches
 from spark_rapids_tpu.ops.expressions import ColVal, Expression
-from spark_rapids_tpu.utils import hostsync
 
 
 class JoinMetrics:
@@ -201,38 +200,13 @@ class TpuHashJoinExec(TpuExec):
         # probe side — safe for every join type (build-matched flags
         # accumulate across splits the same way they do across batches,
         # and logical_or is idempotent under re-attempts)
-        from spark_rapids_tpu.ops import pallas_kernels as pk
-        hash_on, _hash_slots = pk.hash_dispatch_conf()
-
-        def match_hash(probe_keys, probe_nrows):
-            """Hash phase-A attempt: None means run the sort merge
-            (disabled, ineligible, or table overflow — outputs of an
-            overflowed table are garbage and are discarded whole)."""
-            if not (hash_on and
-                    J.hash_join_eligible(build_keys, probe_keys)):
-                return None
-            from spark_rapids_tpu.exec.fusion import fusion_metrics
-            b_cap = build_keys[0].values.shape[0]
-            m = J.hash_join_match(build_keys, probe_keys,
-                                  jnp.int32(build.nrows),
-                                  jnp.int32(probe_nrows),
-                                  J.hash_join_table_slots(b_cap))
-            fusion_metrics.bump("hashKernelLaunches")
-            if bool(hostsync.fetch(m["overflow"])):
-                fusion_metrics.bump("hashOverflowFallbacks")
-                return None
-            m.pop("overflow")
-            return m
-
         def match_one(batch):
             nonlocal b_matched_acc
             with self.timer(JOIN_TIME):
                 probe_keys = self._encoded_keys(batch, probe_fn)
-                m = match_hash(probe_keys, batch.nrows)
-                if m is None:
-                    m = J.join_match(build_keys, probe_keys,
-                                     jnp.int32(build.nrows),
-                                     jnp.int32(batch.nrows))
+                m = J.join_match(build_keys, probe_keys,
+                                 jnp.int32(build.nrows),
+                                 jnp.int32(batch.nrows))
                 if self.join_type == "full":
                     bm = m["build_matched"]
                     b_matched_acc = bm if b_matched_acc is None else \

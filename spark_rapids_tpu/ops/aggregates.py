@@ -61,8 +61,8 @@ def _sortable_keys(keys: Sequence[ColVal], valid_rows, capacity: int,
             # canonicalize raw values under null BEFORE building the
             # order keys: otherwise null rows scatter by their garbage
             # payload, splitting the null group whenever a
-            # lower-significance key varies (the coded/hashed group-by
-            # paths treat all nulls as one digit, and SQL groups nulls
+            # lower-significance key varies (the coded group-by path
+            # treats all nulls as one digit, and SQL groups nulls
             # together)
             v = jnp.where(c.validity, v, jnp.zeros_like(v))
         lex.extend(_order_keys(v, desc))
@@ -949,140 +949,6 @@ def groupby_aggregate_coded_auto(keys: Sequence[ColVal],
         keys, buffer_inputs, nrows, capacity, mins, safe_ranges,
         k_bucket, row_mask=row_mask)
     return out_keys, out_bufs, num_groups, fits, mins, maxs
-
-
-MAX_HASHED_KEYSPACE = 1 << 62
-
-
-def hashed_slot_ranges(mins: np.ndarray, maxs: np.ndarray):
-    """Host-side analog of :func:`coded_slot_ranges` for the HASH path:
-    no dense-table cap — the radix code only needs to stay injective in
-    int64, so the bound is the key-space product staying under 2**62
-    (strides never overflow).  None when even that fails (the sort path
-    remains the backstop)."""
-    slots = []
-    total = 1
-    for mn, mx in zip(mins.tolist(), maxs.tolist()):
-        rn = max(0, int(mx) - int(mn) + 1)
-        slots.append(rn + 1)
-        total *= rn + 1
-        if total > MAX_HASHED_KEYSPACE:
-            return None
-    return slots, total
-
-
-def groupby_aggregate_hashed(keys: Sequence[ColVal],
-                             buffer_inputs: Sequence[Tuple[str, ColVal]],
-                             nrows, capacity: int, mins, slot_ranges,
-                             table_slots: int, row_mask=None,
-                             interpret=None):
-    """Single-pass hash group-by: the same injective radix code as the
-    coded path (digit 0 = null, so nulls-first ordering falls out of the
-    arithmetic) but addressed through a ``table_slots``-entry
-    open-addressing table instead of a dense code-space table — the key
-    space may be astronomically larger than the live group count.
-
-    Returns ``(out_keys, out_bufs, num_groups, overflow)``.  When
-    ``overflow`` is True (probe-chain blowout or more groups than the
-    table holds) the outputs are garbage to DISCARD — the caller re-runs
-    the sort/segment-sum path; rows are never dropped.  When False the
-    outputs are bit-identical to the coded/sort paths: occupied slots
-    compact in stored-code-ascending order, and group membership,
-    per-group reductions, and first/last representatives (original row
-    index) do not depend on table layout."""
-    from spark_rapids_tpu.ops import pallas_kernels as pk
-    nkeys = len(keys)
-    keys = [widen_colval(c, capacity) for c in keys]
-    live = _row_mask(nrows, capacity, row_mask)
-
-    code = jnp.zeros(capacity, dtype=jnp.int64)
-    stride = jnp.int64(1)
-    strides_rev = []
-    for i in reversed(range(nkeys)):
-        c = keys[i]
-        v = c.values
-        if v.dtype == jnp.bool_:
-            v = v.astype(jnp.int32)
-        v = v.astype(jnp.int64)
-        rn = slot_ranges[i] - 1
-        d = jnp.clip(v - mins[i], 0, jnp.maximum(rn - 1, 0)) + 1
-        if c.validity is not None:
-            d = jnp.where(c.validity, d, 0)
-        code = code + d * stride
-        strides_rev.append(stride)
-        stride = stride * slot_ranges[i]
-    strides = strides_rev[::-1]
-
-    lo = code.astype(jnp.int32)          # low 32 bits (truncating cast)
-    hi = (code >> 32).astype(jnp.int32)
-    if interpret is None:
-        slot, tlo, thi, occupied, overflow = pk.hash_table_insert(
-            lo, hi, live, table_slots)
-    else:
-        slot, tlo, thi, occupied, overflow = pk.hash_insert(
-            lo, hi, live, table_slots, interpret=interpret)
-    T = table_slots
-    ns = T + 1
-    slot = slot.astype(jnp.int32)
-    slot_code = (thi.astype(jnp.int64) << 32) \
-        | (tlo.astype(jnp.int64) & jnp.int64(0xFFFFFFFF))
-
-    # compaction ordered by STORED CODE ascending — exactly the coded
-    # path's slot-index order (slot == code there), so the output is
-    # independent of table layout (pallas vs XLA insert)
-    sortkey = jnp.where(occupied, slot_code,
-                        jnp.iinfo(jnp.int64).max)
-    order = jnp.argsort(sortkey)
-    rank = jnp.zeros(T, dtype=jnp.int32).at[order].set(
-        jnp.arange(T, dtype=jnp.int32))
-    num_groups = occupied.sum().astype(jnp.int32)
-    out_cap = max(T, 1024)
-    out_idx = jnp.where(occupied, rank, out_cap)
-
-    out_keys: List[ColVal] = []
-    for i, c in enumerate(keys):
-        digit = (slot_code // jnp.maximum(strides[i], 1)) % \
-            jnp.maximum(slot_ranges[i], 1)
-        vals = mins[i] + digit - 1
-        if c.validity is not None:
-            vd = jnp.zeros(out_cap, dtype=jnp.bool_)
-            vd = vd.at[out_idx].set(digit > 0, mode="drop")
-        else:
-            vd = None
-        out_dt = c.values.dtype
-        if out_dt == jnp.bool_:
-            vals = vals.astype(jnp.int64) != 0
-        dst = jnp.zeros(out_cap, dtype=out_dt)
-        dst = dst.at[out_idx].set(vals.astype(out_dt), mode="drop")
-        out_keys.append(ColVal(c.dtype, dst, vd))
-
-    slot_counts_all = jnp.bincount(slot, length=ns)
-    counts_cache = {}
-
-    def counts_of(validity, bcode):
-        if validity is None:
-            return slot_counts_all[:T]
-        key = id(validity)
-        got = counts_cache.get(key)
-        if got is None:
-            got = jnp.bincount(bcode, length=ns)[:T]
-            counts_cache[key] = got
-        return got
-
-    def compact(c, vals, counts):
-        vals, counts = vals[:T], counts[:T]
-        dv = jnp.zeros(out_cap, dtype=vals.dtype)
-        dv = dv.at[out_idx].set(vals, mode="drop")
-        dvalid = jnp.zeros(out_cap, dtype=jnp.bool_)
-        dvalid = dvalid.at[out_idx].set(counts > 0, mode="drop")
-        return ColVal(c.dtype, dv, dvalid)
-
-    out_bufs: List[ColVal] = []
-    for kind, c in buffer_inputs:
-        vals, counts = _segment_reduce_coded(kind, c, slot, ns,
-                                             counts_of)
-        out_bufs.append(compact(c, vals, counts))
-    return out_keys, out_bufs, num_groups, overflow
 
 
 def reduce_aggregate(buffer_inputs: Sequence[Tuple[str, ColVal]],

@@ -20,7 +20,7 @@ Full outer adds one extra batch of never-matched build rows.
 
 from __future__ import annotations
 
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -127,101 +127,6 @@ def join_match(build_keys: Sequence[ColVal], probe_keys: Sequence[ColVal],
         "probe_bstart": probe_bstart,
         "sorted_to_build": sorted_to_build,
         "build_matched": build_matched,
-    }
-
-
-from functools import partial
-
-
-def hash_join_eligible(build_keys: Sequence[ColVal],
-                       probe_keys: Sequence[ColVal],
-                       max_table_slots: int = 1 << 20) -> bool:
-    """Trace-time gate for the hash phase-A: single key column (the
-    normalized key IS the 64-bit table code — multi-key needs a range
-    probe the sort path doesn't) and a build side small enough that a
-    half-load table fits the VMEM bound."""
-    if len(build_keys) != 1 or len(probe_keys) != 1:
-        return False
-    b_cap = build_keys[0].values.shape[0]
-    return hash_join_table_slots(b_cap) <= max_table_slots
-
-
-def hash_join_table_slots(b_cap: int) -> int:
-    """Power-of-two table sized for load factor <= 0.5 over the build
-    capacity (distinct build keys <= b_cap, so insertion never runs out
-    of slots; only pathological probe chains can still overflow)."""
-    t = 64
-    while t < 2 * max(b_cap, 1):
-        t *= 2
-    return t
-
-
-@partial(jax.jit, static_argnames=("num_slots", "interpret"))
-def hash_join_match(build_keys: Sequence[ColVal],
-                    probe_keys: Sequence[ColVal],
-                    build_n, probe_n, num_slots: int,
-                    interpret: bool | None = None):
-    """Hash phase-A: same contract as :func:`join_match` plus an
-    ``overflow`` flag — when True the outputs are garbage to DISCARD and
-    the caller re-runs the sort-merge phase (rows are never dropped).
-
-    Bit-compatibility with the sort path: the table groups build rows by
-    exact normalized key; ``sorted_to_build`` lists each slot's build
-    rows in ORIGINAL index order (stable sort by slot), which is exactly
-    the within-run order the stable lexsort produces — so phase B
-    materializes byte-identical output, whichever phase A ran."""
-    from spark_rapids_tpu.ops import pallas_kernels as pk
-    bk, pk_col = build_keys[0], probe_keys[0]
-    b_cap = bk.values.shape[0]
-    p_cap = pk_col.values.shape[0]
-    T = num_slots
-
-    live_b = jnp.arange(b_cap, dtype=jnp.int32) < build_n
-    if bk.validity is not None:
-        live_b = live_b & bk.validity
-    code_b = _norm_key(bk.values).astype(jnp.int64)
-    blo = code_b.astype(jnp.int32)
-    bhi = (code_b >> 32).astype(jnp.int32)
-    if interpret is None:
-        slot_b, tlo, thi, occ, overflow = pk.hash_table_insert(
-            blo, bhi, live_b, T)
-    else:
-        slot_b, tlo, thi, occ, overflow = pk.hash_insert(
-            blo, bhi, live_b, T, interpret=interpret)
-    slot_b = slot_b.astype(jnp.int32)  # T for dead/overflowed rows
-
-    # build rows grouped by slot, ORIGINAL order within a slot (stable)
-    sorted_to_build = selection.lexsort_i32([slot_b])
-    counts = jnp.bincount(slot_b, length=T + 1)[:T].astype(jnp.int32)
-    starts = (jnp.cumsum(counts) - counts).astype(jnp.int32)
-
-    live_p = jnp.arange(p_cap, dtype=jnp.int32) < probe_n
-    if pk_col.validity is not None:
-        live_p = live_p & pk_col.validity
-    code_p = _norm_key(pk_col.values).astype(jnp.int64)
-    if interpret is None:
-        pslot = pk.hash_table_probe(
-            code_p.astype(jnp.int32), (code_p >> 32).astype(jnp.int32),
-            live_p, tlo, thi, occ)
-    else:
-        pslot = pk.hash_probe(
-            code_p.astype(jnp.int32), (code_p >> 32).astype(jnp.int32),
-            live_p, tlo, thi, occ, interpret=interpret)
-    hit = pslot < T
-    safe = jnp.clip(pslot, 0, T - 1)
-    probe_count = jnp.where(hit, counts[safe], 0).astype(jnp.int32)
-    probe_bstart = jnp.where(hit, starts[safe], 0).astype(jnp.int32)
-
-    matched_slot = jnp.zeros(T + 1, dtype=jnp.bool_)
-    matched_slot = matched_slot.at[pslot].set(True)  # T = trash
-    build_matched = live_b & (slot_b < T) & \
-        matched_slot[jnp.clip(slot_b, 0, T - 1)]
-    return {
-        "probe_count": probe_count,
-        "probe_bstart": probe_bstart,
-        "sorted_to_build": sorted_to_build,
-        "build_matched": build_matched,
-        "overflow": overflow,
     }
 
 

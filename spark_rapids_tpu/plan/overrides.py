@@ -968,7 +968,7 @@ def _pushdown_pass(plan: L.LogicalPlan, cache_manager=None) -> None:
 
 
 # process-wide planning-pass counter: every TpuOverrides.apply ticks it.
-# The template bench pins this at zero across prepared repeats — "skips
+# tests/test_templates.py pins this at zero across prepared repeats — "skips
 # planning entirely" is a measured claim, not a code-path assumption.
 _planning_passes = 0
 

@@ -65,7 +65,7 @@ register_point("checkpoint.restore")
 
 class CheckpointMetrics:
     """Process-wide checkpoint counters, surfaced by tools/profiling
-    and bench.py alongside the recovery/watchdog counters."""
+    alongside the recovery/watchdog counters."""
 
     FIELDS = ("writes", "bytesWritten", "resumes", "stagesSkipped",
               "evictions", "invalid")

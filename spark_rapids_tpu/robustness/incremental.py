@@ -152,11 +152,11 @@ class tick_execution_scope:
 
 
 class IncrementalMetrics(CheckpointMetrics):
-    """Process-wide continuous-ingest counters (bench.py --ingest-ticks
-    and the profiling tool read these alongside the checkpoint/recovery
-    counters).  Same lock/bump/snapshot discipline as the checkpoint
-    counters, wider field set; ``stateBytes`` is a gauge (last
-    committed epoch's size), everything else is a counter."""
+    """Process-wide continuous-ingest counters (the profiling tool reads
+    these alongside the checkpoint/recovery counters).  Same
+    lock/bump/snapshot discipline as the checkpoint counters, wider
+    field set; ``stateBytes`` is a gauge (last committed epoch's size),
+    everything else is a counter."""
 
     FIELDS = ("ticks", "incrementalTicks", "fullRecomputes", "commits",
               "rollbacks", "writes", "bytesWritten", "resumes",

@@ -27,8 +27,8 @@ query — both as :class:`~..robustness.faults.AdmissionFault`, which
 the recovery ladder classifies FATAL-for-this-query and hands back.
 
 Every grant/rejection emits an ``Admission`` / ``AdmissionReject``
-event, and cumulative counters (``snapshot()``) feed bench.py's
-``--concurrency`` mode and the profiling concurrency report.
+event, and cumulative counters (``snapshot()``) feed the profiling
+concurrency report.
 """
 
 from __future__ import annotations
@@ -71,7 +71,7 @@ class AdmissionController:
         self._queue: deque = deque()   # waiting tickets, FIFO
         self._active: Dict[int, AdmissionTicket] = {}  # seq -> ticket
         self.admitted_bytes = 0
-        # cumulative observability (bench --concurrency / profiling)
+        # cumulative observability (tools/profiling)
         self.total_admitted = 0
         self.total_rejected = 0
         self.total_wait_ns = 0

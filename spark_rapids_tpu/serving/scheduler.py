@@ -91,7 +91,7 @@ class FairInterleaver:
         self._order: List[InterleaveTicket] = []
         self._cur = 0
         self._turn_t0 = time.monotonic()  # when the turn last moved
-        # cumulative observability (bench --concurrency / profiling)
+        # cumulative observability (tools/profiling)
         self.total_registered = 0
         self.total_slices = 0
         self.total_wait_ns = 0

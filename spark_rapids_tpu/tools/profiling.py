@@ -271,8 +271,7 @@ def sharing_stats(apps: List[AppInfo]) -> Dict[str, float]:
     (serving/reuse.py + serving/scheduler.py): result-cache
     hits/misses/stores/invalidations, shared stage-store
     writes/splices, and the fair interleaver's wait/timeslice
-    accounting.  ``result_cache_hits`` and ``stage_splices`` are the
-    headline numbers the bench --concurrency overlap mode reports."""
+    accounting."""
     hits = misses = stores = invalid = evicts = 0
     t_hits = t_misses = t_stores = 0
     writes = splices = 0
@@ -503,9 +502,7 @@ def site_history(obs_dir: str, top: int = 20) -> str:
 
 
 def nearest_rank(sorted_vals: List[float], p: float) -> float:
-    """Nearest-rank percentile over an ascending list — shared by the
-    concurrency report and ``bench.py --concurrency`` so the two can
-    never silently diverge."""
+    """Nearest-rank percentile over an ascending list."""
     if not sorted_vals:
         return 0.0
     return sorted_vals[min(int(p * len(sorted_vals)),
@@ -810,15 +807,6 @@ def health_check(apps: List[AppInfo]) -> List[str]:
                     "spark.rapids.tpu.fusion.wire.enabled would fold "
                     "the wire packer into the compute program, one "
                     "launch per shard")
-            if fu and fu.get("hashOverflowFallbacks", 0):
-                problems.append(
-                    f"{a.session_id} query {q.query_id}: "
-                    f"{fu['hashOverflowFallbacks']} hash-kernel "
-                    "launch(es) overflowed the slot table and re-ran "
-                    "the sort kernel — results stay exact, but the "
-                    "hash dispatch was wasted work; raise "
-                    "spark.rapids.tpu.pallas.hash.tableSlots above "
-                    "2x the live key cardinality")
             pl = q.planner
             if pl and pl.get("mispredicts", 0):
                 # the SAME factor finish_query counted with — a tuned

@@ -2,10 +2,9 @@
 
 A cold process compiles every XLA program it runs, and on a TPU the
 64-bit sorts behind group-by / join / order-by take a minute or two
-each.  The entry scripts (``chip_smoke.py``, ``bench.py``,
-``__graft_entry__.py``) call :func:`enable_compile_cache` before their
-first jit so a second process on the same machine finds the compiled
-programs again.  The engine's own jit tier (``ops/jit_cache.py``) stores
+each.  The entry scripts (``chip_smoke.py``, ``__graft_entry__.py``)
+call :func:`enable_compile_cache` before their first jit so a second
+process on the same machine finds the compiled programs again.  The engine's own jit tier (``ops/jit_cache.py``) stores
 StableHLO and saves tracing, not XLA compilation.
 """
 

@@ -1,13 +1,12 @@
 """Host-sync accounting: every device->host scalar/buffer fetch counts.
 
 A device->host synchronization stalls the dispatch queue: the host
-blocks until the device has drained everything ahead of the read (the
-r05 bench attributes the group-by path's 10x gap to per-batch ``int(n)``
-syncs), so the engine treats syncs as a budgeted
-resource: every site that materializes device data on the host goes
-through :func:`fetch` / :func:`count_sync`, and the counters surface in
-QueryEnd events (``pipeline.hostSyncCount``), ``bench.py`` JSON and
-``tests/test_pipeline.py``'s regression assertions.
+blocks until the device has drained everything ahead of the read, so
+the engine treats syncs as a budgeted resource: every site that
+materializes device data on the host goes through :func:`fetch` /
+:func:`count_sync`, and the counters surface in QueryEnd events
+(``pipeline.hostSyncCount``), ``benchmark/run.py``'s ``exec.host_syncs``
+and ``tests/test_pipeline.py``'s regression assertions.
 
 The discipline for when a sync is allowed lives in
 ``docs/performance.md`` ("when is ``int(x)`` on a device value

@@ -8,8 +8,12 @@ topology is described inside the module-scoped fixture only (never at
 import): one process at a time may load the TPU library, and under
 pytest-xdist every worker imports this file.
 
-No 64-bit sort program is compiled here: each takes the chip's compiler
-one to two minutes.
+No 64-bit sort program is compiled at a real capacity here: each takes
+the chip's compiler one to two minutes.  The join, filter-stage and
+group-by programs that lead the ledger's ``device_ops`` are compiled at
+``SMALL`` rows, a few seconds each: what the compiler refuses (an op the
+X64 rewriter cannot lower, a layout Mosaic will not take) does not
+depend on the row count.
 """
 
 import os
@@ -28,6 +32,7 @@ from spark_rapids_tpu.parallel import shuffle
 from spark_rapids_tpu.parallel.mesh import shard_map
 
 ROWS = 1 << 22
+SMALL = 1 << 12
 HBM_BYTES = 16 * 10**9  # one v5e chip
 
 
@@ -160,6 +165,78 @@ def test_join_out_starts_compiles_without_a_wide_scan(one_chip, capacity):
                                    lowered.as_text())]
     assert windows and max(windows) <= J._SCAN_BLOCK
     assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
+@pytest.mark.parametrize("storage,nullable",
+                         [(jnp.int64, False), (jnp.int32, True)],
+                         ids=["int64", "nullable_int32"])
+def test_join_match_compiles(one_chip, storage, nullable):
+    """``jit_join_match`` on q3's key (one int64) and on q7's (one
+    nullable int32): the join's only match phase."""
+    from spark_rapids_tpu.ops import joins as J
+
+    def key():
+        return [ColVal(None, _spec((SMALL,), storage, one_chip),
+                       _spec((SMALL,), jnp.bool_, one_chip)
+                       if nullable else None)]
+
+    n = _spec((), jnp.int32, one_chip)
+    compiled = J.join_match.lower(key(), key(), n, n).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("with_string", [False, True],
+                         ids=["int64_float64_date", "with_string"])
+def test_filter_stage_with_compaction_compiles(one_chip, with_string):
+    """``jit_filter_stage_*``: q1's row (a nullable int64, double and
+    date, kept by a date predicate and compacted) and q3's, which also
+    carries a string column through its char buffer."""
+    from spark_rapids_tpu.ops import predicates as P
+    from spark_rapids_tpu.ops.compiler import FilterStageFn
+    from spark_rapids_tpu.ops.expressions import BoundReference, Literal
+    row = [dts.INT64, dts.FLOAT64, dts.DATE32]
+    flat = [(_spec((SMALL,), dt.storage, one_chip),
+             _spec((SMALL,), jnp.bool_, one_chip), None) for dt in row]
+    if with_string:
+        row.append(dts.STRING)
+        flat.append((_spec((16 * SMALL,), jnp.uint8, one_chip), None,
+                     _spec((SMALL + 1,), jnp.int32, one_chip)))
+    refs = [BoundReference(i, dt) for i, dt in enumerate(row)]
+    stage = FilterStageFn(
+        P.LessThanOrEqual(refs[2], Literal("1998-09-02", dts.DATE32)),
+        refs, row)
+    compiled = jax.jit(stage._run).lower(
+        flat, _spec((), jnp.int32, one_chip)).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _agg_buffers(one_chip):
+    # sum(double), avg(double) as its sum and count, count(*)
+    return [(_spec((SMALL,), dt, one_chip), None)
+            for dt in (jnp.float64, jnp.float64, jnp.int64, jnp.int64)]
+
+
+def test_coded_groupby_compiles(one_chip):
+    """``jit_coded_agg``, q1's group-by: two dictionary-coded keys
+    addressed directly, sum / avg / count buffers, no sort."""
+    from spark_rapids_tpu.exec.aggregate import _coded_kernel
+    keys = [(_spec((SMALL,), jnp.int64, one_chip), None)] * 2
+    ranges = _spec((2,), jnp.int64, one_chip)
+    compiled = _coded_kernel(("sum",) * 4, 64).lower(
+        keys, _agg_buffers(one_chip), ranges, ranges,
+        _spec((SMALL,), jnp.bool_, one_chip)).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_sort_segment_groupby_compiles(one_chip):
+    """The rung under the coded directory: one sparse int64 key sorted
+    (``lexsort_i32``) and its runs reduced by segment."""
+    from spark_rapids_tpu.exec.aggregate import _grouped_kernel
+    keys = [(_spec((SMALL,), jnp.int64, one_chip), None)]
+    compiled = _grouped_kernel(("sum",) * 4, 1).lower(
+        keys, _agg_buffers(one_chip),
+        _spec((), jnp.int32, one_chip)).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
 
 
 def test_decimal_average_compiles_without_a_divide(one_chip):

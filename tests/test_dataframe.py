@@ -109,6 +109,45 @@ def test_groupby_null_keys(session):
     assert s == [4, 5, 6]  # k=1 -> 4, k=2 -> 5, null -> 6
 
 
+def _sparse_int64_keys():
+    # 2,000 keys from a 2^40 keyspace: past the coded directory's cap
+    rng = np.random.default_rng(7)
+    uni = np.unique(rng.integers(0, 1 << 40, 8000, dtype=np.int64))[:2000]
+    return pd.DataFrame({
+        "k": uni[rng.integers(0, len(uni), 20000)],
+        "v": rng.integers(0, 1000, 20000).astype(np.float64)})
+
+
+def _nan_float_keys():
+    rng = np.random.default_rng(13)
+    k = rng.normal(size=4000)
+    k[::11] = np.nan
+    return pd.DataFrame({"k": k, "v": rng.normal(size=4000)})
+
+
+def _string_keys_500():
+    rng = np.random.default_rng(17)
+    words = np.array([f"k{i:05d}" for i in range(500)])
+    return pd.DataFrame({"k": words[rng.integers(0, 500, 6000)],
+                         "v": rng.normal(size=6000)})
+
+
+@pytest.mark.parametrize("make", [_sparse_int64_keys, _nan_float_keys,
+                                  _string_keys_500],
+                         ids=["sparse_int64", "nan_float64", "string_500"])
+def test_groupby_keys_without_a_coded_directory(session, make):
+    """Group keys the coded directory cannot hold (a sparse 40-bit
+    range, doubles with NaN, strings) go to the sort / segment kernel
+    and answer as pandas does."""
+    pdf = make()
+    out = (session.create_dataframe(pdf).group_by("k")
+           .agg(F.sum(F.col("v")).alias("sv"),
+                F.count(F.col("v")).alias("c")).to_pandas())
+    want = pdf.groupby("k", as_index=False, dropna=False).agg(
+        sv=("v", "sum"), c=("v", "count"))
+    assert_frames_equal(out, want, sort_by=["k"], approx=True)
+
+
 def test_distinct(session):
     df = session.create_dataframe({"a": [1, 2, 1, 3, 2], "b": [1, 1, 1, 2, 1]})
     out = df.distinct().to_pandas().sort_values(["a", "b"])

@@ -140,6 +140,25 @@ def test_join_duplicate_build_keys(session):
     _compare_join(got, left.merge(right, on="k"))  # 2*3 + 1 = 7 rows
 
 
+def test_join_sparse_40bit_keys(session):
+    """One int64 key from a 2^40 keyspace, half of the keys on the
+    build side, the joined rows summed by key."""
+    rng = np.random.default_rng(11)
+    uni = np.unique(rng.integers(0, 1 << 40, 4000,
+                                 dtype=np.int64))[:1000]
+    probe = pd.DataFrame({"k": uni[rng.integers(0, len(uni), 8000)],
+                          "v": rng.normal(size=8000)})
+    build = pd.DataFrame({"k": uni[::2],
+                          "w": rng.normal(size=len(uni[::2]))})
+    got = (session.create_dataframe(probe)
+           .join(session.create_dataframe(build), on="k")
+           .group_by("k").agg(F.sum(F.col("v")).alias("sv"),
+                              F.sum(F.col("w")).alias("sw")))
+    want = probe.merge(build, on="k").groupby("k", as_index=False).agg(
+        sv=("v", "sum"), sw=("w", "sum"))
+    _compare_join(got, want)
+
+
 def test_cross_join(session):
     left = pd.DataFrame({"a": [1, 2, 3]})
     right = pd.DataFrame({"b": ["x", "y"]})
