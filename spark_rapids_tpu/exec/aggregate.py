@@ -80,13 +80,13 @@ def _grouped_kernel(kinds: Tuple[str, ...], nkeys: int):
     """Group-by over pre-evaluated fixed-width (values, validity) columns."""
 
     @jax.jit
-    def grouped_agg(keys_flat, bufs_flat, nrows):
+    def grouped_agg(keys_flat, bufs_flat, nrows, mask=None):
         capacity = keys_flat[0][0].shape[0]
         keys = [ColVal(None, v, val) for v, val in keys_flat]
         buf_inputs = [(k, ColVal(None, v, val))
                       for k, (v, val) in zip(kinds, bufs_flat)]
         out_keys, out_bufs, n = agg.groupby_aggregate(
-            keys, buf_inputs, nrows, capacity)
+            keys, buf_inputs, nrows, capacity, row_mask=mask)
         return ([(k.values, k.validity) for k in out_keys],
                 [(b.values, b.validity) for b in out_bufs], n)
 
@@ -99,11 +99,12 @@ def _keyless_kernel(kinds: Tuple[str, ...]):
     staged path's keyless case, e.g. SELECT min(s))."""
 
     @jax.jit
-    def keyless_agg(bufs_flat, nrows):
+    def keyless_agg(bufs_flat, nrows, mask=None):
         capacity = bufs_flat[0][0].shape[0]
         buf_inputs = [(k, ColVal(None, v, val))
                       for k, (v, val) in zip(kinds, bufs_flat)]
-        outs = agg.reduce_aggregate(buf_inputs, nrows, capacity)
+        outs = agg.reduce_aggregate(buf_inputs, nrows, capacity,
+                                    row_mask=mask)
         return [(o.values, o.validity) for o in outs]
 
     return keyless_agg
@@ -139,13 +140,16 @@ def _pow2_bucket(n: int) -> int:
 @functools.lru_cache(maxsize=None)
 def _probe_kernel(nkeys: int):
     """Key-range probe over pre-evaluated key columns (string path and
-    merge stage, where keys already exist as columns)."""
+    merge stage, where keys already exist as columns).  ``mask`` is the
+    string path's folded predicate: only the rows it keeps size the key
+    space."""
 
     @jax.jit
-    def agg_key_probe(keys_flat, nrows):
+    def agg_key_probe(keys_flat, nrows, mask=None):
         capacity = keys_flat[0][0].shape[0]
         keys = [ColVal(None, v, val) for v, val in keys_flat]
-        live = jnp.arange(capacity, dtype=jnp.int32) < nrows
+        live = jnp.arange(capacity, dtype=jnp.int32) < nrows \
+            if mask is None else mask
         return agg.key_range_probe(keys, live)
 
     return agg_key_probe
@@ -165,7 +169,9 @@ class TpuHashAggregateExec(TpuExec):
                  max_dict_size: int = (1 << 31) - 1):
         """``pre_filter``: a fused upstream Filter condition (whole-stage
         fusion: predicate becomes a row mask inside the aggregation kernel —
-        no compaction pass at all).
+        no compaction pass at all).  Every path takes it: the two-stage
+        string path evaluates it in stage A and hands the mask to the
+        stage-B kernels.
 
         ``defer_syncs``: carry per-batch group counts as device-resident
         ``RowCount``s and dispatch the coded path speculatively
@@ -287,15 +293,6 @@ class TpuHashAggregateExec(TpuExec):
                 self._in_dtypes = [
                     dts.INT32 if j in self._enc_ords else dt
                     for j, dt in enumerate(self._in_dtypes)]
-        if self.pre_filters and self._needs_string_stage:
-            # planner invariant: a fused pre_filter never reaches the
-            # two-stage string path (which cannot apply it) — the
-            # planner either proves encoded eligibility or leaves the
-            # chain unfused
-            raise ValueError(
-                "fused pre_filter with string keys/buffers requires "
-                "encoded execution; plan the chain unfused instead")
-
         from spark_rapids_tpu.ops.jit_cache import cached_jit
         base_sig = (tuple(dt.name for dt in self._in_dtypes),
                     tuple(e.cache_key() for e in self._kgroup),
@@ -311,12 +308,14 @@ class TpuHashAggregateExec(TpuExec):
             agg.coded_key_eligible(key_dts) and \
             not any(s.dtype.has_offsets for s in self._buf_specs)
         if self._needs_string_stage:
-            # stage A evaluates keys + agg children; the group kernel runs in
-            # stage B after host dictionary encoding of string keys /
-            # string agg children
+            # stage A evaluates keys + agg children and, in the same
+            # program, the fused pre-filter conjuncts as a row mask; the
+            # group kernel runs in stage B after host dictionary
+            # encoding of string keys / string agg children
             pre_exprs = list(self.group_exprs) + \
                 [f.child for f in self.funcs if f.child is not None]
-            self._pre_fn = StageFn(pre_exprs, self._in_dtypes)
+            self._pre_fn = StageFn(pre_exprs, self._in_dtypes,
+                                   conjuncts=self.pre_filters)
         else:
             self._pre_fn = None
             update_sig = ("agg_update",) + base_sig + (
@@ -353,9 +352,11 @@ class TpuHashAggregateExec(TpuExec):
 
     def describe(self):
         enc = ", encoded" if self._encoded_exec else ""
+        pre = f", pre_filter={[str(c) for c in self.pre_filters]}" \
+            if self.pre_filters else ""
         return (f"TpuHashAggregateExec[keys="
                 f"{[e.name for e in self.group_exprs]}, aggs="
-                f"{[n for n, _ in self.agg_exprs]}{enc}]")
+                f"{[n for n, _ in self.agg_exprs]}{pre}{enc}]")
 
     @property
     def _needs_string_stage(self) -> bool:
@@ -374,8 +375,9 @@ class TpuHashAggregateExec(TpuExec):
         aliased), and no other kernel consumer — non-string keys, agg
         children, fused predicates (``consumers``) — reads those
         columns, so replacing them with stable dense codes changes no
-        evaluated value.  The SAME test gates the planner's fused-chain
-        fold and the exec's own rewrite: they must not diverge."""
+        evaluated value.  It gates the rewrite only: a chain under the
+        aggregate folds either way (the decoded two-stage path takes a
+        fused predicate as its row mask)."""
         ords: List[int] = []
         for e in group_exprs:
             if not e.dtype.is_string:
@@ -689,7 +691,11 @@ class TpuHashAggregateExec(TpuExec):
     def _partial_with_string_keys(self, batch, names, dtypes):
         from spark_rapids_tpu.ops.dictionary import ordered_dict_encode
         nkeys = len(self.group_exprs)
-        pre_cols = self._pre_fn(batch)
+        # mask: the rows the fused pre-filter keeps (None without one).
+        # The host encoders below go on seeing every row: a value that
+        # occurs only in dropped rows gets a code, and the kernels' row
+        # mask keeps it from making a group or winning a min/max
+        pre_cols, mask = self._pre_fn.masked(batch)
         key_cols, child_cols = pre_cols[:nkeys], pre_cols[nkeys:]
         enc_keys = [self._encoders[i].encode(c) if i in self._string_key_idx
                     else c for i, c in enumerate(key_cols)]
@@ -718,25 +724,27 @@ class TpuHashAggregateExec(TpuExec):
         if not enc_keys:
             # keyless (e.g. SELECT min(s)): one output row
             kernel = _keyless_kernel(self._update_kinds)
-            buf_flat = kernel(buf_flat_in, nrows)
+            buf_flat = kernel(buf_flat_in, nrows, mask)
             key_flat, n = [], 1
             out_cap = 1024
         else:
             pick = None
             if self._coded_eligible:
-                mins, maxs = _probe_kernel(nkeys)(key_flat_in, nrows)
+                mins, maxs = _probe_kernel(nkeys)(key_flat_in, nrows,
+                                                  mask)
                 pick = self._coded_pick(mins, maxs)
             if pick is not None:
                 k_bucket, mins_d, slots_d = pick
-                mask = jnp.arange(batch.capacity,
-                                  dtype=jnp.int32) < nrows
+                if mask is None:
+                    mask = jnp.arange(batch.capacity,
+                                      dtype=jnp.int32) < nrows
                 key_flat, buf_flat, n = _coded_kernel(
                     self._update_kinds, k_bucket)(
                     key_flat_in, buf_flat_in, mins_d, slots_d, mask)
             else:
                 kernel = _grouped_kernel(self._update_kinds, nkeys)
                 key_flat, buf_flat, n = kernel(key_flat_in, buf_flat_in,
-                                               nrows)
+                                               nrows, mask)
             # string buffers re-decode per batch below: a genuine host
             # decision point, so the count syncs (and is counted) here
             n = int(RowCount(device=n))

@@ -12,7 +12,10 @@ member's expressions in one trace — projections substitute through
 the trace, and the selection compacts ONCE at the stage boundary instead
 of once per filter.  Intermediates never leave registers/VMEM; each batch
 costs one jit dispatch per pipeline stage ("Data Path Fusion in GPU for
-Analytical Query Processing", PAPERS.md).
+Analytical Query Processing", PAPERS.md).  A Filter/Project chain under an
+Aggregate always folds into the aggregate unless it records ANSI checks
+(``TpuOverrides._try_fuse_aggregate``): the predicate is the group-by's
+row mask and nothing compacts.
 
 Composition is the logical-plan walk in ``plan/overrides.py``
 (``TpuOverrides._try_fuse_chain``) and

@@ -26,7 +26,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column
 from spark_rapids_tpu.columnar.dtypes import DataType
 from spark_rapids_tpu.ops.expressions import (
-    ColVal, EmitContext, Expression, collect_param_slots)
+    ColVal, EmitContext, Expression, collect_param_slots, fold_conjuncts)
 from spark_rapids_tpu.utils import tracing
 
 # A column crosses the jit boundary as (values, validity|None, offsets|None).
@@ -126,19 +126,29 @@ class StageFn:
 
     ``__call__(batch) -> list[Column]`` with the same nrows as the input.
     jax.jit's shape cache gives one XLA executable per capacity bucket.
+
+    ``conjuncts`` (bottom-first, the FilterStageFn discipline) fold into
+    a row mask that the same program returns beside the columns
+    (:meth:`masked`): a filter that its consumer applies as a mask
+    instead of a compaction — no column is gathered.
     """
 
     def __init__(self, exprs: Sequence[Expression],
                  input_dtypes: Sequence[DataType],
-                 donate: bool = False):
+                 donate: bool = False,
+                 conjuncts: Sequence[Expression] = ()):
         from spark_rapids_tpu.ops.jit_cache import cached_jit
         self.exprs = list(exprs)
+        self.conjuncts = list(conjuncts)
         self.input_dtypes = list(input_dtypes)
         self.donate = effective_donate(donate)
-        self._slots = collect_param_slots(self.exprs)
+        self._slots = collect_param_slots(self.exprs + self.conjuncts)
         self._sig = ("stage", tuple(e.cache_key() for e in self.exprs),
                      tuple(dt.name for dt in self.input_dtypes),
                      ("donate", self.donate))
+        if self.conjuncts:
+            self._sig += (("mask", tuple(c.cache_key()
+                                         for c in self.conjuncts)),)
         self._jitted = cached_jit(self._sig, lambda: self._run,
                                   **_donate_kwargs(self.donate))
 
@@ -147,24 +157,34 @@ class StageFn:
         inputs = flat_to_colvals(flat_cols, self.input_dtypes)
         ctx = EmitContext(inputs, nrows, capacity,
                           params=params_dict(self._slots, params))
+        # the expressions evaluate over every row; fold_conjuncts leaves
+        # the check mask at the survivor set
+        mask = fold_conjuncts(ctx, self.conjuncts) \
+            if self.conjuncts else None
         outs = [e.emit(ctx) for e in self.exprs]
         # messages are static per expression tree: record them at trace
         # time so a failure needs no re-execution
         _CHECK_MSGS[self._sig] = [m for m, _ in ctx.checks]
         return ([(o.values, o.validity, o.offsets) for o in outs],
-                tuple(flag for _, flag in ctx.checks))
+                tuple(flag for _, flag in ctx.checks), mask)
 
-    def __call__(self, batch: ColumnarBatch) -> List[Column]:
+    def masked(self, batch: ColumnarBatch):
+        """``(columns, mask)``: the mask is bool[capacity] of the rows
+        the conjuncts keep, None when the stage has no conjuncts."""
         flat = batch_to_flat(batch)
         # device_i32: a deferred upstream count flows straight into the
         # stage without a host sync
         nrows = batch.row_count.device_i32()
-        out_flat, check_flags = self._jitted(flat, nrows,
-                                             param_args(self._slots))
+        out_flat, check_flags, mask = self._jitted(
+            flat, nrows, param_args(self._slots))
         raise_failed_checks(_CHECK_MSGS.get(self._sig, []), check_flags)
         outs = [ColVal(e.dtype, v, validity, offsets)
                 for e, (v, validity, offsets) in zip(self.exprs, out_flat)]
-        return colvals_to_columns(outs, batch.row_count, batch.capacity)
+        return (colvals_to_columns(outs, batch.row_count, batch.capacity),
+                mask)
+
+    def __call__(self, batch: ColumnarBatch) -> List[Column]:
+        return self.masked(batch)[0]
 
 
 class FilterStageFn:
@@ -205,7 +225,6 @@ class FilterStageFn:
 
     def _run(self, flat_cols, nrows, params=()):
         from spark_rapids_tpu.ops import selection
-        from spark_rapids_tpu.ops.expressions import fold_conjuncts
         capacity = capacity_of(flat_cols)
         inputs = flat_to_colvals(flat_cols, self.input_dtypes)
         ctx = EmitContext(inputs, nrows, capacity,

@@ -582,39 +582,6 @@ def _encoding_exec_enabled(conf) -> bool:
                        False)
 
 
-def _agg_kernel_children(agg_out_exprs) -> List[Expression]:
-    """The aggregate-function children inside the output expressions —
-    the subtrees the aggregation KERNELS evaluate (everything else in
-    an output either matches a group key or reads the agg frame after
-    the kernels)."""
-    out: List[Expression] = []
-
-    def walk(e):
-        if isinstance(e, AggregateExpression):
-            if e.func.child is not None:
-                out.append(e.func.child)
-            return
-        for c in e.children:
-            walk(c)
-
-    for e in agg_out_exprs:
-        walk(e)
-    return out
-
-
-def _agg_fold_encodable(group, aggs, conds) -> bool:
-    """True when the fused aggregate fold may run ENCODED over string
-    group keys: no string-valued aggregate buffers (those force the
-    two-stage string path, which cannot carry a fused predicate) and
-    the keys pass the exec's own equality-faithfulness test."""
-    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
-    children = _agg_kernel_children(aggs)
-    if any(c.dtype.is_string for c in children):
-        return False
-    return TpuHashAggregateExec.encoded_key_ordinals(
-        group, children + list(conds)) is not None
-
-
 def _plan_aggregate(group_exprs, agg_out_exprs, child_exec,
                     pre_filter=None, merge_chunk_rows=1 << 22,
                     defer_syncs=True, encoded_exec=False,
@@ -1221,6 +1188,11 @@ class TpuOverrides:
         projections compose into key/agg expressions).  The reference gets
         partial fusion from cudf kernel launches per op; XLA gives us the
         fully fused stage if we hand it one computation.
+
+        The rule: a Filter/Project chain under an Aggregate always folds,
+        whatever the key and buffer types, unless it records ANSI checks
+        (the aggregation kernels have no check-flag channel; such a chain
+        runs as a FusedStageExec).
         """
         from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
         from spark_rapids_tpu.exec.fusion import fusion_metrics
@@ -1250,21 +1222,6 @@ class TpuOverrides:
             hops += 1
         if hops == 0:
             return None  # nothing upstream to fuse
-        enc_exec = _encoding_exec_enabled(self.conf)
-        if any(e.dtype.is_string for e in group):
-            # string keys fuse ONLY under encoded execution, and only
-            # when the exec's faithfulness test passes (bare refs, key
-            # columns consumed nowhere else, no string agg buffers) —
-            # otherwise the host dict-encode path runs unfused
-            if not (enc_exec and _agg_fold_encodable(group, aggs,
-                                                     conds)):
-                return None
-        elif conds and any(
-                c.dtype.is_string for c in _agg_kernel_children(aggs)):
-            # string-valued min/max buffers run the two-stage string
-            # path, which cannot carry a fused predicate: leave the
-            # chain unfused (the predicate compacts before the agg)
-            return None
         from spark_rapids_tpu.exec.fusion import has_check_exprs
         if has_check_exprs(group + aggs + conds):
             # the aggregation kernels have no ANSI check-flag channel:
@@ -1288,7 +1245,7 @@ class TpuOverrides:
             group, aggs, base, pre_filter=conds or None,
             merge_chunk_rows=self.conf.get(rc.AGG_MERGE_CHUNK_ROWS),
             defer_syncs=self.conf.get(rc.PIPELINE_DEFER_SYNCS),
-            encoded_exec=enc_exec,
+            encoded_exec=_encoding_exec_enabled(self.conf),
             max_dict_size=self.conf.get(rc.ENCODING_EXECUTION_MAX_DICT))
         # runtime dispatch-savings attribution (QueryEnd fusion dict):
         # each folded operator would have cost one dispatch per batch

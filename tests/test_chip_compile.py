@@ -228,6 +228,37 @@ def test_coded_groupby_compiles(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_string_groupby_stage_a_with_folded_filter_compiles(one_chip):
+    """``jit_stage_*`` of q1 since its filter folds into the group-by:
+    the two string keys and the aggregates' children evaluated, and the
+    date predicate returned as the row mask ``jit_coded_agg`` takes —
+    one program, no compaction."""
+    from spark_rapids_tpu.ops import arithmetic as A
+    from spark_rapids_tpu.ops import predicates as P
+    from spark_rapids_tpu.ops.compiler import StageFn
+    from spark_rapids_tpu.ops.expressions import BoundReference, Literal
+    row = [dts.STRING, dts.STRING, dts.FLOAT64, dts.FLOAT64, dts.DATE32]
+    flat = [(_spec((16 * SMALL,), jnp.uint8, one_chip), None,
+             _spec((SMALL + 1,), jnp.int32, one_chip))] * 2
+    flat += [(_spec((SMALL,), dt.storage, one_chip),
+              _spec((SMALL,), jnp.bool_, one_chip), None)
+             for dt in row[2:]]
+    flag, status, price, discount, shipdate = [
+        BoundReference(i, dt) for i, dt in enumerate(row)]
+    stage = StageFn(
+        [flag, status, price,
+         A.Multiply(price, A.Subtract(Literal(1.0, dts.FLOAT64),
+                                      discount))],
+        row, conjuncts=[P.LessThanOrEqual(
+            shipdate, Literal("1998-09-02", dts.DATE32))])
+    lowered = jax.jit(stage._run).lower(
+        flat, _spec((), jnp.int32, one_chip))
+    cols, _, mask = lowered.out_info
+    assert len(cols) == 4 and mask.shape == (SMALL,) \
+        and mask.dtype == jnp.bool_
+    assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
 def test_sort_segment_groupby_compiles(one_chip):
     """The rung under the coded directory: one sparse int64 key sorted
     (``lexsort_i32``) and its runs reduced by segment."""

@@ -88,11 +88,10 @@ def test_fused_tpch_bit_identical(data, q):
         return getattr(tpch, q)(tpch.load(s, data))
 
     s_on, s_off = _assert_fused_identical(build)
+    # q1 too: its filter folds into the string-keyed group-by as the
+    # stage-B kernels' row mask
     fu = s_on.overrides.last_fusion
-    if q != "q1":
-        # q1 groups on STRING keys (host dict-encode path) over a
-        # single-member chain: legitimately nothing to fuse
-        assert fu["fusedStages"] >= 1, fu
+    assert fu["fusedStages"] >= 1, fu
     assert s_off.overrides.last_fusion["fusedStages"] == 0
 
 
@@ -192,6 +191,142 @@ def test_agg_fold_ansi_checks_only_fire_for_survivors():
     for s in (s_on, s_off):
         with pytest.raises(ArithmeticError):
             build(s, 1000).to_pandas()
+
+
+# A Filter/Project chain under an Aggregate folds whatever the key and
+# buffer types (ISSUE 33): the two-stage string path takes the predicate
+# as the group-by's row mask.  Every case runs over five in-memory
+# batches of 64 rows (a parquet scan would apply a pushed filter on the
+# host, and the mask would have nothing to drop); ``x`` is 1..320 in row
+# order, so ``x`` ranges pick batches.
+def _fold_frame():
+    rng = np.random.default_rng(33)
+    n = 320
+    flag = rng.choice(np.array(["A", "N", "R", None], dtype=object), n)
+    status = rng.choice(np.array(["F", "O"], dtype=object), n)
+    x = np.arange(1, n + 1, dtype=np.int64)
+    # "ZZ" and "!only" occur in dropped rows alone (x <= 20): the former
+    # as a key, the latter as what min(s) would pick; "~last" likewise
+    # for max(s)
+    flag[:20] = "ZZ"
+    s = rng.choice(np.array(["kiwi", "apple", "mango", "fig", None],
+                            dtype=object), n)
+    s[:10] = "!only"
+    s[10:20] = "~last"
+    v = rng.normal(size=n).round(3)
+    w = np.where(np.arange(n) % 7 == 3, np.nan, rng.integers(0, 100, n))
+    return pd.DataFrame({"flag": flag, "status": status, "x": x, "s": s,
+                         "v": v, "w": w,
+                         "phone": [f"{10 + i % 4}-{i:04d}"
+                                   for i in range(n)]})
+
+
+def _fold_q1_shape(df):
+    return (df.filter(F.col("x") > 20).groupBy("flag", "status")
+            .agg(F.sum("v").alias("sv"), F.avg("v").alias("av"),
+                 F.count("*").alias("c")))
+
+
+def _fold_q1_oracle(p):
+    m = p[p.x > 20]
+    return (m.groupby(["flag", "status"], dropna=False)
+            .agg(sv=("v", "sum"), av=("v", "mean"), c=("v", "size"))
+            .reset_index())
+
+
+_FOLD_CASES = {
+    # q1's shape: two string keys, NULLs among them, partials of five
+    # batches merged
+    "q1_shape_null_keys": (_fold_q1_shape, _fold_q1_oracle),
+    # "ZZ" is encoded on the host (the encoder sees every row) and must
+    # make no group
+    "key_only_in_dropped_rows": (
+        lambda df: df.filter(F.col("x") > 20).groupBy("flag")
+        .agg(F.count("*").alias("c")),
+        lambda p: p[p.x > 20].groupby("flag", dropna=False)
+        .agg(c=("v", "size")).reset_index()),
+    # the second batch (x 65..128) loses every row
+    "batch_with_every_row_dropped": (
+        lambda df: df.filter((F.col("x") <= 64) | (F.col("x") > 128))
+        .groupBy("status").agg(F.sum("v").alias("sv"),
+                               F.count("*").alias("c")),
+        lambda p: p[(p.x <= 64) | (p.x > 128)].groupby("status")
+        .agg(sv=("v", "sum"), c=("v", "size")).reset_index()),
+    # w is NULL every seventh row: a NULL predicate keeps no row
+    "predicate_null_for_some_rows": (
+        lambda df: df.filter(F.col("w") < 50).groupBy("flag")
+        .agg(F.sum("v").alias("sv"), F.count("*").alias("c")),
+        lambda p: p[p.w < 50].groupby("flag", dropna=False)
+        .agg(sv=("v", "sum"), c=("v", "size")).reset_index()),
+    # TPC-H Q22's cntrycode: the key is computed, not a column
+    "computed_string_key": (
+        lambda df: df.filter(F.col("x") > 20)
+        .groupBy(F.substring(F.col("phone"), 1, 2).alias("cc"))
+        .agg(F.sum("v").alias("sv"), F.count("*").alias("c")),
+        lambda p: p[p.x > 20].assign(cc=p.phone.str[:2]).groupby("cc")
+        .agg(sv=("v", "sum"), c=("v", "size")).reset_index()),
+    # the dropped rows hold the would-be extrema "!only" and "~last"
+    "string_minmax_grouped": (
+        lambda df: df.filter(F.col("x") > 20).groupBy("status")
+        .agg(F.min("s").alias("lo"), F.max("s").alias("hi")),
+        lambda p: p[p.x > 20].groupby("status")
+        .agg(lo=("s", "min"), hi=("s", "max")).reset_index()),
+    "string_minmax_keyless": (
+        lambda df: df.filter(F.col("x") > 20)
+        .agg(F.min("s").alias("lo"), F.max("s").alias("hi")),
+        lambda p: pd.DataFrame({"lo": [p[p.x > 20].s.dropna().min()],
+                                "hi": [p[p.x > 20].s.dropna().max()]})),
+    # Filter <- Project <- Aggregate: the projection substitutes into
+    # the key and the aggregate's child, the predicate stays a mask
+    "project_between_filter_and_aggregate": (
+        lambda df: df.filter(F.col("x") > 20)
+        .select(F.col("flag").alias("f"),
+                (F.col("v") * 2.0).alias("v2"))
+        .groupBy("f").agg(F.sum("v2").alias("sv")),
+        lambda p: p[p.x > 20].assign(v2=p.v * 2.0)
+        .groupby("flag", dropna=False).agg(sv=("v2", "sum"))
+        .reset_index().rename(columns={"flag": "f"})),
+}
+
+
+@pytest.mark.parametrize("case", list(_FOLD_CASES))
+def test_agg_fold_string_keys_and_buffers(case):
+    from spark_rapids_tpu.api.dataframe import DataFrame
+    from spark_rapids_tpu.columnar.batch import ColumnarBatch
+    from spark_rapids_tpu.plan import logical as L
+    build, oracle = _FOLD_CASES[case]
+    pdf = _fold_frame()
+    batches = [ColumnarBatch.from_pandas(pdf.iloc[i:i + 64])
+               for i in range(0, len(pdf), 64)]
+
+    def norm(df):
+        df = df.copy()
+        for c in df.columns:
+            if df[c].dtype == object:
+                df[c] = df[c].where(df[c].notna(), "<null>")
+        return _norm(df)
+
+    res = {}
+    for fuse in (True, False):
+        s = TpuSession({"spark.rapids.tpu.fusion.enabled": fuse})
+        df = build(DataFrame(s, L.InMemoryRelation(batches,
+                                                   batches[0].schema)))
+        tree = s.plan(df.plan).tree_string()
+        res[fuse] = norm(df.to_pandas())
+        fu = s.last_fusion_stats
+        if fuse:
+            # the chain is gone from under the aggregate, which names it
+            assert "TpuFilterExec" not in tree, tree
+            assert "FusedStageExec" not in tree, tree
+            assert "pre_filter=" in tree, tree
+            assert fu["fusedStages"] >= 1, fu
+        else:
+            assert "TpuFilterExec" in tree, tree
+            assert fu["fusedStages"] == 0, fu
+    pd.testing.assert_frame_equal(res[True], res[False])
+    want = norm(oracle(pdf))
+    pd.testing.assert_frame_equal(res[True], want[list(res[True].columns)],
+                                  check_dtype=False)
 
 
 # ------------------------------------------------------------ plan shape --
