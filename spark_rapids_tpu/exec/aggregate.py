@@ -16,6 +16,7 @@ strings under XLA static shapes (SURVEY.md section 7 "hard parts").
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
@@ -25,7 +26,7 @@ import numpy as np
 from spark_rapids_tpu.columnar import dtypes as dts
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, empty_batch
 from spark_rapids_tpu.columnar.column import Column, RowCount
-from spark_rapids_tpu.utils import hostsync
+from spark_rapids_tpu.utils import hostsync, tracing
 from spark_rapids_tpu.exec.base import (
     AGG_TIME, CONCAT_TIME, NUM_INPUT_BATCHES, NUM_INPUT_ROWS, Schema, TpuExec)
 from spark_rapids_tpu.ops import aggregates as agg
@@ -37,6 +38,44 @@ from spark_rapids_tpu.ops.expressions import (
     Alias, BoundReference, ColVal, EmitContext, Expression,
     collect_param_slots)
 from spark_rapids_tpu.plan.logical import AggregateExpression
+
+
+class AggMetrics:
+    """Which rung of the group-by's ladder each batch and each merge of
+    ``TpuHashAggregateExec`` took, from what the operator already has on
+    the host where it picks the rung (no sync of its own): partial
+    batches on the coded directory (``coded_batches``, a speculative hit
+    or a sized one; ``coded_slots`` the directory slots they swept, so
+    slots over batches is the directory's size) or past it on the sort
+    kernel (``sort_batches``), speculations that missed
+    (``spec_misses``), partials handed to a merge (``merge_inputs``) and
+    the rung each keyed merge took (``merges_coded``, ``merges_sorted``).
+    A keyless reduction has no rung: it counts its merge inputs only.
+    Running sums over every group-by of every query; plain ints, bumped
+    with tracing on or off."""
+
+    KEYS = ("coded_batches", "sort_batches", "spec_misses", "coded_slots",
+            "merge_inputs", "merges_sorted", "merges_coded")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._n = dict.fromkeys(self.KEYS, 0)
+
+    def note(self, key: str, n: int = 1) -> None:
+        with self._lock:
+            self._n[key] += n
+
+    def coded(self, k_bucket: int) -> None:
+        with self._lock:
+            self._n["coded_batches"] += 1
+            self._n["coded_slots"] += k_bucket
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._n)
+
+
+agg_metrics = AggMetrics()
 
 
 class _StringKeyEncoder:
@@ -611,6 +650,7 @@ class TpuHashAggregateExec(TpuExec):
                 flat, nrows, self._pargs())
             fits_h, mins_h, maxs_h = hostsync.fetch(fits, mins, maxs)
             if bool(fits_h):
+                agg_metrics.coded(spec_k)
                 outs = [ColVal(dt, v, val) for dt, (v, val) in
                         zip(dtypes, list(key_out) + list(buf_out))]
                 out_cap = key_out[0][0].shape[0] if key_out else \
@@ -619,6 +659,7 @@ class TpuHashAggregateExec(TpuExec):
                 cols = colvals_to_columns(outs, n_rc, out_cap)
                 return ColumnarBatch(dict(zip(names, cols)), n_rc)
             self._spec_misses += 1
+            agg_metrics.note("spec_misses")
             pick = self._coded_pick_host(mins_h, maxs_h)
         else:
             mask, mins, maxs = self._stage_a_fn(flat, nrows, self._pargs())
@@ -626,6 +667,7 @@ class TpuHashAggregateExec(TpuExec):
         if pick is None:
             # key space past the coded directory: the fully fused sort
             # kernel
+            agg_metrics.note("sort_batches")
             key_flat, buf_flat, n = self._update_fn(flat, nrows,
                                                     self._pargs())
             n_rc = self._wrap_count(n)
@@ -635,6 +677,7 @@ class TpuHashAggregateExec(TpuExec):
             cols = colvals_to_columns(outs, n_rc, batch.capacity)
             return ColumnarBatch(dict(zip(names, cols)), n_rc)
         k_bucket, mins_d, slots_d = pick
+        agg_metrics.coded(k_bucket)
         fn = cached_jit(
             ("agg_coded_update", k_bucket) + self._base_sig,
             lambda: self._coded_update(k_bucket))
@@ -664,7 +707,7 @@ class TpuHashAggregateExec(TpuExec):
                     yield batch
 
         def compute(batch):
-            with self.timer(AGG_TIME):
+            with tracing.span("agg.partial"), self.timer(AGG_TIME):
                 if self._encoded_exec:
                     batch = self._encode_input_batch(batch)
                 if self._needs_string_stage:
@@ -672,6 +715,9 @@ class TpuHashAggregateExec(TpuExec):
                         batch, names, dtypes)
                 if self._coded_eligible:
                     return self._partial_coded(batch, names, dtypes)
+                if self.group_exprs:
+                    # a key no directory can address (a float)
+                    agg_metrics.note("sort_batches")
                 key_flat, buf_flat, n = self._update_fn(
                     batch_to_flat(batch), batch.row_count.device_i32(),
                     self._pargs())
@@ -735,6 +781,7 @@ class TpuHashAggregateExec(TpuExec):
                 pick = self._coded_pick(mins, maxs)
             if pick is not None:
                 k_bucket, mins_d, slots_d = pick
+                agg_metrics.coded(k_bucket)
                 if mask is None:
                     mask = jnp.arange(batch.capacity,
                                       dtype=jnp.int32) < nrows
@@ -742,6 +789,7 @@ class TpuHashAggregateExec(TpuExec):
                     self._update_kinds, k_bucket)(
                     key_flat_in, buf_flat_in, mins_d, slots_d, mask)
             else:
+                agg_metrics.note("sort_batches")
                 kernel = _grouped_kernel(self._update_kinds, nkeys)
                 key_flat, buf_flat, n = kernel(key_flat_in, buf_flat_in,
                                                nrows, mask)
@@ -865,10 +913,13 @@ class TpuHashAggregateExec(TpuExec):
             if pick is not None:
                 from spark_rapids_tpu.ops.jit_cache import cached_jit
                 kb, mins_d, slots_d = pick
+                agg_metrics.note("merges_coded")
                 fn = cached_jit(
                     ("agg_merge_coded", finalize, kb) + self._base_sig,
                     lambda: self._merge_coded(kb, finalize))
                 return fn(flat, mins_d, slots_d, nrows)
+        if nkeys:
+            agg_metrics.note("merges_sorted")
         fn = self._merge_fn if finalize else self._merge_partial_fn
         return fn(flat, nrows)
 
@@ -898,6 +949,7 @@ class TpuHashAggregateExec(TpuExec):
                 rows += h.nrows
                 if rows >= chunk and len(group) >= 2:
                     break
+            agg_metrics.note("merge_inputs", len(group))
             with self.timer(CONCAT_TIME):
                 merged_in = concat_batches([h.materialize()
                                             for h in group])
@@ -1058,14 +1110,22 @@ class TpuHashAggregateExec(TpuExec):
         # cache partials as spillable batches (the reference caches
         # SpillableColumnarBatch between update and merge, aggregate.scala)
         handles = [catalog.register(b) for b in self._partial_batches()]
+        if not handles and self.group_exprs:
+            return
+        with tracing.span("agg.merge"):
+            out = self._merge_partials(handles, catalog)
+        yield out
+
+    def _merge_partials(self, handles, catalog) -> ColumnarBatch:
+        """Tree-merge the partial handles down to one merge chunk, then
+        the final merge with finalization and the keys' decode."""
         nkeys = len(self.group_exprs)
         if not handles:
-            if nkeys:
-                return
             partials = [empty_batch(self._partial_schema)]
         else:
             handles = self._tree_merge(handles, catalog)
             partials = [h.materialize() for h in handles]
+            agg_metrics.note("merge_inputs", len(partials))
         with self.timer(CONCAT_TIME):
             merged_in = concat_batches(partials)
         for h in handles:
@@ -1105,4 +1165,4 @@ class TpuHashAggregateExec(TpuExec):
                 cols.append(colvals_to_columns([c], n, out_cap)[0])
         for i in self._string_key_idx:
             cols[i] = self._encoders[i].decode(cols[i])
-        yield ColumnarBatch(dict(zip(out_names, cols)), n)
+        return ColumnarBatch(dict(zip(out_names, cols)), n)

@@ -835,7 +835,9 @@ def groupby_aggregate_coded(keys: Sequence[ColVal],
         stacked = jnp.stack(cols, axis=1)
         summed = jax.ops.segment_sum(stacked, bcode, num_segments=ns)
         if fuse_counts:
-            slot_counts_all = summed[:, -1].astype(jnp.int64)
+            # read only as ``> 0``: left in the sums' own type (an
+            # emulated f64 -> int64 convert is a chain of passes)
+            slot_counts_all = summed[:, -1]
         for col_i, j in enumerate(idxs):
             batched_sums[j] = (summed[:, col_i], validity)
 
@@ -844,9 +846,11 @@ def groupby_aggregate_coded(keys: Sequence[ColVal],
         slot_counts_all = jnp.bincount(code, length=ns)
     counts_cache = {}
 
+    slot_counts = slot_counts_all[:k_bucket]
+
     def counts_of(validity, bcode):
         if validity is None:
-            return slot_counts_all[:k_bucket]
+            return slot_counts
         key = id(validity)
         got = counts_cache.get(key)
         if got is None:
@@ -854,7 +858,6 @@ def groupby_aggregate_coded(keys: Sequence[ColVal],
             counts_cache[key] = got
         return got
 
-    slot_counts = slot_counts_all[:k_bucket]
     occupied = slot_counts > 0
     num_groups = occupied.sum().astype(jnp.int32)
     pos = jnp.cumsum(occupied.astype(jnp.int32)) - 1
@@ -863,11 +866,20 @@ def groupby_aggregate_coded(keys: Sequence[ColVal],
     out_cap = max(k_bucket, 1024)
     out_idx = jnp.where(occupied, pos, out_cap)
 
-    slots = jnp.arange(k_bucket, dtype=jnp.int64)
+    # a slot's digits, in 32 bits (a slot index fits, as the row codes
+    # above do; the chip emulates 64-bit division in hundreds of passes
+    # over the directory).  The last key's stride is 1 and the first
+    # key's quotient is its digit already (slot < key space), so a
+    # single key divides nothing.
+    slots = jnp.arange(k_bucket, dtype=jnp.int32)
     out_keys: List[ColVal] = []
     for i, c in enumerate(keys):
-        digit = (slots // jnp.maximum(strides[i], 1)) % \
-            jnp.maximum(slot_ranges[i], 1)
+        digit = slots
+        if i < nkeys - 1:
+            digit = digit // jnp.maximum(strides[i].astype(jnp.int32), 1)
+        if i > 0:
+            digit = digit % jnp.maximum(
+                slot_ranges[i].astype(jnp.int32), 1)
         vals = mins[i] + digit - 1
         if c.validity is not None:
             vd = jnp.zeros(out_cap, dtype=jnp.bool_)
@@ -881,12 +893,18 @@ def groupby_aggregate_coded(keys: Sequence[ColVal],
         dst = dst.at[out_idx].set(vals.astype(out_dt), mode="drop")
         out_keys.append(ColVal(c.dtype, dst, vd))
 
+    # a buffer counted by the slots' own live rows is valid in every
+    # group: the dense prefix, no scatter (the chip sorts to scatter
+    # booleans)
+    all_groups = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
+
     def compact(c, vals, counts):
-        vals, counts = vals[:k_bucket], counts[:k_bucket]
         dv = jnp.zeros(out_cap, dtype=vals.dtype)
-        dv = dv.at[out_idx].set(vals, mode="drop")
+        dv = dv.at[out_idx].set(vals[:k_bucket], mode="drop")
+        if counts is slot_counts:
+            return ColVal(c.dtype, dv, all_groups)
         dvalid = jnp.zeros(out_cap, dtype=jnp.bool_)
-        dvalid = dvalid.at[out_idx].set(counts > 0, mode="drop")
+        dvalid = dvalid.at[out_idx].set(counts[:k_bucket] > 0, mode="drop")
         return ColVal(c.dtype, dv, dvalid)
 
     out_bufs: List[Optional[ColVal]] = [None] * len(buffer_inputs)
