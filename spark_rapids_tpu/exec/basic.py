@@ -8,6 +8,7 @@ forest is one XLA computation per capacity bucket.
 
 from __future__ import annotations
 
+import threading
 from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -20,6 +21,40 @@ from spark_rapids_tpu.exec.base import (
     NUM_INPUT_BATCHES, NUM_INPUT_ROWS, Schema, TpuExec)
 from spark_rapids_tpu.ops.compiler import FilterStageFn, StageFn
 from spark_rapids_tpu.ops.expressions import BoundReference, Expression
+
+
+class FilterMetrics:
+    """Batches through the filter stages (``TpuFilterExec`` and a fused
+    stage with predicates), from what the operator has on the host
+    anyway: ``batches`` and ``rows_out`` from the kept-row count it
+    syncs, ``rows_in`` and ``whole_batches`` (every row kept, so the
+    compaction moved nothing: ops/selection.py ``compact``) only where
+    the input's row count is already concrete, never by a sync of their
+    own.  Plain ints, bumped with tracing on or off."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.batches = self.whole_batches = 0
+        self.rows_in = self.rows_out = 0
+
+    def note(self, row_count, kept: int) -> None:
+        """``row_count``: the input batch's ``RowCount``."""
+        rows_in = int(row_count) if row_count.is_concrete else None
+        with self._lock:
+            self.batches += 1
+            self.rows_out += kept
+            if rows_in is not None:
+                self.rows_in += rows_in
+                self.whole_batches += kept == rows_in
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"batches": self.batches,
+                    "whole_batches": self.whole_batches,
+                    "rows_in": self.rows_in, "rows_out": self.rows_out}
+
+
+filter_metrics = FilterMetrics()
 
 
 class TpuCoalesceBatchesExec(TpuExec):
@@ -157,6 +192,7 @@ class TpuFilterExec(TpuExec):
 
         def compute(batch):
             cols, n = self._fn(batch)
+            filter_metrics.note(batch.row_count, n)
             return None if n == 0 else \
                 ColumnarBatch(dict(zip(names, cols)), n)
 

@@ -37,6 +37,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.exec.base import (NUM_INPUT_BATCHES, NUM_INPUT_ROWS,
                                         Schema, TpuExec)
+from spark_rapids_tpu.exec.basic import filter_metrics
 from spark_rapids_tpu.ops.compiler import FilterStageFn, StageFn
 from spark_rapids_tpu.ops.expressions import (BoundReference, Expression,
                                               substitute_bound)
@@ -230,6 +231,7 @@ class FusedStageExec(TpuExec):
             return ColumnarBatch(dict(zip(names, cols)),
                                  batch.row_count)
         cols, n = self._fn(batch)
+        filter_metrics.note(batch.row_count, n)
         return None if n == 0 else \
             ColumnarBatch(dict(zip(names, cols)), n)
 

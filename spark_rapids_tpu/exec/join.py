@@ -305,7 +305,7 @@ class TpuHashJoinExec(TpuExec):
             keep = jnp.logical_and(count > 0, in_range)
         else:
             keep = jnp.logical_and(count == 0, in_range)
-        cols, n = selection.compact(_to_colvals(batch), keep)
+        cols, n = selection.compact_by_gather(_to_colvals(batch), keep)
         n = int(n)
         if n == 0:
             return
@@ -317,7 +317,7 @@ class TpuHashJoinExec(TpuExec):
         in_range = jnp.arange(
             matched_acc.shape[0], dtype=jnp.int32) < build.nrows
         keep = jnp.logical_and(jnp.logical_not(matched_acc), in_range)
-        cols, n = selection.compact(build_payload, keep)
+        cols, n = selection.compact_by_gather(build_payload, keep)
         n = int(n)
         if n == 0:
             return

@@ -9,6 +9,15 @@ static shapes, so these kernels keep the input capacity and return a traced
 String gather is fully vectorized: new offsets by cumsum of gathered lengths,
 then a searchsorted over char positions maps every output byte to its source
 byte (O(C log N) for C chars — bandwidth-bound, which is what TPUs like).
+
+``compact`` gathers only where something has to move: a ``lax.cond`` on
+the mask hands the buffers out as they are when the kept rows are already
+the dense prefix (a filter over a scan that applied the same predicate on
+the host keeps every row; arbitrary-index gathers are what the chip does
+worst: 8 ns an element, 338 ms a 2^20-row batch of q3's lineitem, PR 35).
+The rule that goes with it: ``compact`` is only called under a trace,
+because a ``lax.cond`` run op by op compiles on every call.  A caller on
+the host runs ``compact_by_gather``, the same compaction with no branch.
 """
 
 from __future__ import annotations
@@ -112,19 +121,74 @@ def gathered_char_count(offsets, indices, out_count):
     return jnp.where(mask, lengths, 0).sum()
 
 
+def _gather_kept(cols: Sequence[ColVal], keep, new_nrows) -> List[ColVal]:
+    """The kept rows gathered to the front.  Linear cost: a prefix-sum
+    gives each kept row its target slot and one scatter builds the
+    permutation — no sort (cudf's apply_boolean_mask does a similar
+    stream compaction; an argsort here would be O(n log^2 n) on TPU's
+    bitonic sorter)."""
+    capacity = keep.shape[0]
+    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
+    tgt = jnp.where(keep, pos, capacity)  # dropped rows scatter out of range
+    perm = jnp.zeros(capacity, dtype=jnp.int32).at[tgt].set(
+        jnp.arange(capacity, dtype=jnp.int32), mode="drop")
+    return gather(cols, perm, new_nrows)
+
+
 def compact(cols: Sequence[ColVal], keep) -> Tuple[List[ColVal], jnp.ndarray]:
     """Move rows where ``keep`` is True to the front, preserving order.
 
     Returns (columns, new_nrows). ``keep`` must already exclude padding rows.
-    Linear cost: a prefix-sum gives each kept row its target slot and one
-    scatter builds the permutation — no sort (cudf's apply_boolean_mask does
-    a similar stream compaction; an argsort here would be O(n log^2 n) on
-    TPU's bitonic sorter).
+
+    Where the kept rows are already the dense prefix (every row kept, or
+    every dropped row behind the last kept one: a predicate the scan has
+    applied exactly on the host, a range over the sort key) nothing has
+    to move, and nothing does: a ``lax.cond`` on the mask returns the
+    buffers as they are, and only a mask that keeps scattered rows pays
+    the permutation and the gathers (:func:`_gather_kept`).  Rows below
+    ``new_nrows`` are bit for bit the same in both branches; the padding
+    above differs and nobody may read it.  Offset-bearing columns keep
+    :func:`gather`'s padding (offsets flat after the last kept row,
+    elements zero from their total on) with element-wise work only.
+
+    Call it under a trace only: a ``lax.cond`` run op by op is traced
+    and compiled anew on every call.  From the host, call
+    :func:`compact_by_gather`.
     """
     capacity = keep.shape[0]
-    pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
     new_nrows = keep.sum().astype(jnp.int32)
-    tgt = jnp.where(keep, pos, capacity)  # dropped rows scatter out of range
-    perm = jnp.zeros(capacity, dtype=jnp.int32).at[tgt].set(
-        jnp.arange(capacity, dtype=jnp.int32), mode="drop")
-    return gather(cols, perm, new_nrows), new_nrows
+    dense = jnp.all(
+        keep == (jnp.arange(capacity, dtype=jnp.int32) < new_nrows))
+
+    def in_place(cols):
+        outs = []
+        for c in cols:
+            if c.offsets is None:
+                outs.append(c)
+                continue
+            total = c.offsets[new_nrows]
+            pos = jnp.arange(c.values.shape[0], dtype=jnp.int32)
+            elements = jnp.where(pos < total, c.values,
+                                 jnp.zeros((), dtype=c.values.dtype))
+            outs.append(ColVal(c.dtype, elements, c.validity,
+                               jnp.minimum(c.offsets, total)))
+        return outs
+
+    def moved(cols):
+        return _gather_kept(cols, keep, new_nrows)
+
+    return jax.lax.cond(dense, in_place, moved, list(cols)), new_nrows
+
+
+def compact_by_gather(cols: Sequence[ColVal], keep
+                      ) -> Tuple[List[ColVal], jnp.ndarray]:
+    """:func:`compact` with no branch, for a caller on the host that
+    runs it op by op (the join's own compactions, exec/join.py).  Not a
+    program of its own, by measurement (PR 35, q18's semi join, 60 of
+    1.5M orders kept, nine columns at 2^20 rows): op by op each gather
+    is a program whose operand the chip's compiler stages in fast
+    memory, 9–10 ms a buffer; inside one program over the nine columns
+    the same gathers take 20–32 ms each (0.67 s a query against 0.30),
+    and a program a column compiles its own 23 s prefix-sum."""
+    new_nrows = keep.sum().astype(jnp.int32)
+    return _gather_kept(cols, keep, new_nrows), new_nrows

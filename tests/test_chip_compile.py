@@ -17,6 +17,7 @@ depend on the row count.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -190,7 +191,10 @@ def test_join_match_compiles(one_chip, storage, nullable):
 def test_filter_stage_with_compaction_compiles(one_chip, with_string):
     """``jit_filter_stage_*``: q1's row (a nullable int64, double and
     date, kept by a date predicate and compacted) and q3's, which also
-    carries a string column through its char buffer."""
+    carries a string column through its char buffer.  The compaction's
+    gathers stay inside a conditional (ops/selection.py ``compact``): a
+    compiler that flattened it into a select would run them on every
+    batch, the dense-prefix ones included."""
     from spark_rapids_tpu.ops import predicates as P
     from spark_rapids_tpu.ops.compiler import FilterStageFn
     from spark_rapids_tpu.ops.expressions import BoundReference, Literal
@@ -208,6 +212,7 @@ def test_filter_stage_with_compaction_compiles(one_chip, with_string):
     compiled = jax.jit(stage._run).lower(
         flat, _spec((), jnp.int32, one_chip)).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+    assert len(re.findall(r" conditional\(", compiled.as_text())) == 1
 
 
 def _agg_buffers(one_chip):

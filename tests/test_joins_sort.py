@@ -94,6 +94,34 @@ def test_semi_anti_join(session):
     _compare_join(anti, left[~in_right])
 
 
+@pytest.mark.parametrize("how", ["semi", "anti", "full"])
+def test_join_compaction_compiles_nothing_on_a_second_run(session, how):
+    """The join's own compactions (semi and anti survivors, a full
+    join's unmatched build rows) run op by op on the host, so they take
+    ``selection.compact_by_gather``: ``compact`` itself branches with a
+    ``lax.cond``, which outside a trace compiles on every call."""
+    import jax.monitoring
+    compiles = []
+
+    def listener(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    def run():
+        left, right, got = _join_frames(session, how, kmax=80)
+        want = left.merge(right, on="k", how="outer") if how == "full" \
+            else left[left.k.isin(right.k) == (how == "semi")]
+        assert len(got.to_pandas()) == len(want)
+
+    run()
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        run()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert compiles == []
+
+
 def test_join_with_nulls(session):
     left = pd.DataFrame({"k": [1, None, 2, 3], "lv": [10, 20, 30, 40]})
     right = pd.DataFrame({"k": [1, None, 3], "rv": [100, 200, 300]})

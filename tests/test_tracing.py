@@ -438,3 +438,30 @@ def test_qualification_surfaces_fusion_and_encoding_counters():
     rep = format_report([s])
     assert "fusedStages=2" in rep
     assert "encodedWireBytesSaved=4096" in rep
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_filter_metrics_count_whole_and_partly_kept_batches(rng, fused):
+    """``filter_metrics`` (the benchmark's ``filter.batches`` and
+    ``filter.whole_batches``): a batch whose every row passes counts as
+    whole, a batch that loses rows does not, through ``TpuFilterExec``
+    and through a fused Filter+Project stage alike."""
+    from spark_rapids_tpu.exec.basic import filter_metrics
+    session = TpuSession({"spark.rapids.tpu.fusion.enabled": fused})
+    df = _small_df(session, rng)
+
+    def run(cond):
+        q = df.filter(cond).select((F.col("v") * 2).alias("w"), "k")
+        tree = session.plan(q.plan).tree_string()
+        assert ("FusedStageExec" if fused else "TpuFilterExec") in tree
+        before = filter_metrics.snapshot()
+        rows = len(q.to_pandas())
+        after = filter_metrics.snapshot()
+        return rows, {k: after[k] - before[k] for k in after}
+
+    rows, whole = run(F.col("k") >= 0)
+    assert whole == {"batches": 1, "whole_batches": 1,
+                     "rows_in": 4000, "rows_out": 4000} and rows == 4000
+    rows, partly = run(F.col("k") < 25)
+    assert partly == {"batches": 1, "whole_batches": 0,
+                      "rows_in": 4000, "rows_out": rows} and 0 < rows < 4000
