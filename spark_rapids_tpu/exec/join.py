@@ -35,21 +35,33 @@ class JoinMetrics:
     emit no pairs, count their probe rows only): ``probe_rows`` in,
     ``output_rows`` out, summed over every join of every query.  Their
     ratio is what a reordered or pre-filtered star join would save.
-    Plain ints, bumped with tracing on or off."""
+    ``build_rows`` and ``build_capacity``: once a join, when its build
+    side has become one batch, that batch's rows and its capacity, which
+    ``join_match`` sorts again with every probe batch: what building on
+    the smaller side would save.  Plain ints, bumped with tracing on or
+    off."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.probe_rows = self.output_rows = 0
+        self.build_rows = self.build_capacity = 0
 
     def note(self, probe: int, output: int) -> None:
         with self._lock:
             self.probe_rows += probe
             self.output_rows += output
 
+    def note_build(self, rows: int, capacity: int) -> None:
+        with self._lock:
+            self.build_rows += rows
+            self.build_capacity += capacity
+
     def snapshot(self) -> dict:
         with self._lock:
             return {"probe_rows": self.probe_rows,
-                    "output_rows": self.output_rows}
+                    "output_rows": self.output_rows,
+                    "build_rows": self.build_rows,
+                    "build_capacity": self.build_capacity}
 
 
 join_metrics = JoinMetrics()
@@ -189,6 +201,7 @@ class TpuHashJoinExec(TpuExec):
         if build is None:
             from spark_rapids_tpu.columnar.batch import empty_batch
             build = empty_batch(build_exec.schema, capacity=1)
+        join_metrics.note_build(build.nrows, build.capacity)
         build_keys = with_retry_no_split(
             lambda: self._encoded_keys(build, build_fn))
         build_payload = _to_colvals(build)

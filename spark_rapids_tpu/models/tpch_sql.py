@@ -14,6 +14,14 @@ engines without correlated-subquery support (and mirroring how
 Uncorrelated scalar subqueries (q11, q15, q22) and IN-subqueries
 (q16, q18, q20, q22) use the SQL frontend's native support.
 
+Q9 also runs in the specification's own form, which these texts are
+not: ``FROM part, supplier, lineitem, partsupp, orders, nation`` with
+its conditions in WHERE (the resolver takes the join order from the
+join graph, not from the list) and ``extract(year from o_orderdate)``.
+That text is ``benchmark/queries/tpch_q9/q9.sql``, the cell
+``tpch_sf1.q9`` runs it on the chip at SF1, and ``tests/test_tpch_q9.py``
+here; q3 and q18 have theirs under ``benchmark/queries/`` too.
+
 ``register(session, tables)`` installs the temp views; ``QUERIES[name]``
 is the SQL text.
 """

@@ -130,25 +130,6 @@ def join_match(build_keys: Sequence[ColVal], probe_keys: Sequence[ColVal],
     }
 
 
-_SCAN_BLOCK = 1024
-
-
-def _cumsum_32(x):
-    """Inclusive prefix sums of a 32-bit vector in its own type
-    (wrapping), as rows of ``_SCAN_BLOCK``: each row scanned, then the
-    row totals, then one add.  The same numbers as ``jnp.cumsum``, whose
-    one reduce-window as wide as the vector the chip's compiler takes 20
-    to 100 s over at 2^19 and 2^20 rows, against 1 to 2 s for this."""
-    n = x.shape[0]
-    if n <= _SCAN_BLOCK or n % _SCAN_BLOCK:
-        return jnp.cumsum(x, dtype=x.dtype)
-    rows = jnp.cumsum(x.reshape(n // _SCAN_BLOCK, _SCAN_BLOCK), axis=1,
-                      dtype=x.dtype)
-    totals = rows[:, -1]
-    before = _cumsum_32(totals) - totals
-    return (rows + before[:, None]).reshape(n)
-
-
 def _cumsum_i64(count):
     """Inclusive prefix sums of non-negative int32 counts, exact in
     int64, from two 32-bit scans: the sums modulo 2^32, and how often
@@ -156,9 +137,9 @@ def _cumsum_i64(count):
     once, and it did iff the sum fell).  An int64 scan is emulated on
     the chip and compiles slowest of all; a cold compile died in it
     (PERF.md, PR 30)."""
-    lo = _cumsum_32(count.astype(jnp.uint32))
+    lo = selection.cumsum_32(count.astype(jnp.uint32))
     wrapped = lo < jnp.concatenate([jnp.zeros(1, jnp.uint32), lo[:-1]])
-    hi = _cumsum_32(wrapped.astype(jnp.int32))
+    hi = selection.cumsum_32(wrapped.astype(jnp.int32))
     return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
 
 

@@ -7,8 +7,9 @@ static shapes, so these kernels keep the input capacity and return a traced
 ``new_nrows`` — the caller re-buckets later if occupancy gets low.
 
 String gather is fully vectorized: new offsets by cumsum of gathered lengths,
-then a searchsorted over char positions maps every output byte to its source
-byte (O(C log N) for C chars — bandwidth-bound, which is what TPUs like).
+then every output byte finds its row (``rows_of_positions``: a histogram of
+the row ends and a prefix sum, O(C + N) for C chars in N rows) and from the
+row's shift its source byte: two gathers of C elements in all.
 
 ``compact`` gathers only where something has to move: a ``lax.cond`` on
 the mask hands the buffers out as they are when the kept rows are already
@@ -70,6 +71,38 @@ def lexsort_i32(keys: Sequence[jnp.ndarray], dead=None) -> jnp.ndarray:
         perm, unique_indices=True)
 
 
+SCAN_BLOCK = 1024
+
+
+def cumsum_32(x):
+    """Inclusive prefix sums of a 32-bit vector in its own type
+    (wrapping), as rows of ``SCAN_BLOCK``: each row scanned, then the
+    row totals, then one add.  The same numbers as ``jnp.cumsum``, whose
+    one reduce-window as wide as the vector the chip's compiler takes 20
+    to 100 s over at 2^19 and 2^20 rows, against 1 to 2 s for this."""
+    n = x.shape[0]
+    if n <= SCAN_BLOCK or n % SCAN_BLOCK:
+        return jnp.cumsum(x, dtype=x.dtype)
+    rows = jnp.cumsum(x.reshape(n // SCAN_BLOCK, SCAN_BLOCK), axis=1,
+                      dtype=x.dtype)
+    totals = rows[:, -1]
+    before = cumsum_32(totals) - totals
+    return (rows + before[:, None]).reshape(n)
+
+
+def rows_of_positions(offsets, n: int):
+    """For each position 0..n-1 of an element buffer, the row that holds
+    it: how many rows end at or before it, which for offsets that start
+    at 0 is ``searchsorted(offsets, pos, side="right") - 1`` to the last
+    row (callers clip to their capacity).  A histogram of the row ends
+    and a prefix sum: one scatter of the offsets and one scan of ``n``,
+    where the binary search is log2(rows) dependent gathers of ``n``
+    elements each, and an arbitrary gather is what the chip does worst:
+    for the 2^23 bytes of 2^18 part names 1,145 ms against 3 (PR 37)."""
+    ends = jnp.zeros(n, jnp.int32).at[offsets[1:]].add(1, mode="drop")
+    return cumsum_32(ends)
+
+
 def gather(cols: Sequence[ColVal], indices, out_count,
            char_capacity: int = 0) -> List[ColVal]:
     """Gather rows of every column at ``indices`` (int array, len=capacity).
@@ -89,19 +122,20 @@ def gather(cols: Sequence[ColVal], indices, out_count,
             outs.append(ColVal(c.dtype, c.values[indices], validity))
             continue
         # string column: rebuild offsets + chars
-        lengths = c.offsets[indices + 1] - c.offsets[indices]
-        lengths = jnp.where(out_mask, lengths, 0)
+        starts = c.offsets[indices]
+        lengths = jnp.where(out_mask, c.offsets[indices + 1] - starts, 0)
         new_offsets = jnp.concatenate(
             [jnp.zeros(1, dtype=jnp.int32), jnp.cumsum(lengths,
                                                        dtype=jnp.int32)])
         in_char_cap = c.values.shape[0]
         out_char_cap = char_capacity or in_char_cap
         pos = jnp.arange(out_char_cap, dtype=jnp.int32)
-        # row containing each output byte (last offset <= pos)
-        row = jnp.searchsorted(new_offsets, pos, side="right") - 1
-        row = jnp.clip(row, 0, capacity - 1)
-        src = c.offsets[indices[row]] + (pos - new_offsets[row])
-        src = jnp.clip(src, 0, in_char_cap - 1)
+        # row containing each output byte (last offset <= pos); a row's
+        # bytes all move by the same distance
+        row = jnp.clip(rows_of_positions(new_offsets, out_char_cap),
+                       0, capacity - 1)
+        shift = starts - new_offsets[:-1]
+        src = jnp.clip(pos + shift[row], 0, in_char_cap - 1)
         total = new_offsets[capacity]
         # keep the element buffer's own dtype: uint8 chars for strings,
         # the element storage dtype for arrays (a hardcoded uint8 cast

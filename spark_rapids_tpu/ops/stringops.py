@@ -6,10 +6,12 @@ bandwidth-friendly vector ops over the flat uint8 chars array plus per-row
 offsets:
 
 * per-row scalars (length, startswith, contains, ...) reduce over byte
-  ranges via a byte->row segment map (searchsorted over offsets);
+  ranges via a byte->row segment map (``byte_to_row``: a histogram of the
+  row ends and a prefix sum, ops/selection.py ``rows_of_positions``), or
+  read a prefix sum over the bytes at the row's two ends (``Contains``);
 * producers (substring, concat, trim, pad, upper/lower) compute output
   lengths first, then map every output byte back to its source byte — the
-  same two-searchsorted pattern the row gather uses;
+  same pattern the row gather uses;
 * character (not byte) positions honor UTF-8 via a prefix sum over
   non-continuation bytes.
 
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar import dtypes as dts
 from spark_rapids_tpu.columnar.dtypes import DataType
+from spark_rapids_tpu.ops import selection
 from spark_rapids_tpu.ops.expressions import (
     ColVal, EmitContext, Expression, UnaryExpression, combine_validity,
 )
@@ -49,8 +52,7 @@ def char_lengths(c: ColVal, ctx: EmitContext):
 
 def byte_to_row(c: ColVal, capacity: int):
     """row index of every byte position in the chars array."""
-    pos = jnp.arange(c.values.shape[0], dtype=jnp.int32)
-    row = jnp.searchsorted(c.offsets, pos, side="right") - 1
+    row = selection.rows_of_positions(c.offsets, c.values.shape[0])
     return jnp.clip(row, 0, capacity - 1)
 
 
@@ -63,8 +65,8 @@ def build_strings(lengths, src_byte_fn, src_chars, out_char_cap: int,
     offsets = jnp.concatenate([jnp.zeros(1, dtype=jnp.int32),
                                jnp.cumsum(lengths, dtype=jnp.int32)])
     pos = jnp.arange(out_char_cap, dtype=jnp.int32)
-    row = jnp.searchsorted(offsets, pos, side="right") - 1
-    row = jnp.clip(row, 0, capacity - 1)
+    row = jnp.clip(selection.rows_of_positions(offsets, out_char_cap),
+                   0, capacity - 1)
     k = pos - offsets[row]
     src = src_byte_fn(pos, row, k)
     total = offsets[capacity]
@@ -222,14 +224,24 @@ class EndsWith(_PatternPredicate):
         return ColVal(dts.BOOL, ok, c.validity)
 
 
+def _pattern_at(values, pat: np.ndarray):
+    """bool per byte position: the pattern's bytes stand from here on,
+    rows not regarded.  The buffer moved up a byte at a time (a slice,
+    zeros behind the end; a match over the end fits no row) and
+    compared: the same bytes as a gather at ``pos + i``, which costs the
+    chip 83 ms a 2^23-byte buffer whatever the indices are (PR 37)."""
+    m = jnp.ones(values.shape[0], dtype=jnp.bool_)
+    for i, b in enumerate(pat):
+        moved = values if i == 0 else jnp.concatenate(
+            [values[i:], jnp.zeros(i, dtype=values.dtype)])
+        m = jnp.logical_and(m, moved == b)
+    return m
+
+
 def _match_starts(c: ColVal, pat: np.ndarray, capacity: int):
     """bool per byte position: pattern matches starting here, within row."""
-    ccap = c.values.shape[0]
-    pos = jnp.arange(ccap, dtype=jnp.int32)
-    m = jnp.ones(ccap, dtype=jnp.bool_)
-    for i, b in enumerate(pat):
-        m = jnp.logical_and(
-            m, c.values[jnp.clip(pos + i, 0, ccap - 1)] == b)
+    pos = jnp.arange(c.values.shape[0], dtype=jnp.int32)
+    m = _pattern_at(c.values, pat)
     row = byte_to_row(c, capacity)
     # match must fit inside the row
     fits = pos + len(pat) <= c.offsets[row + 1]
@@ -244,11 +256,17 @@ class Contains(_PatternPredicate):
             shape = row_lengths(c).shape
             return ColVal(dts.BOOL, jnp.ones(shape, dtype=jnp.bool_),
                           c.validity)
-        m, row = _match_starts(c, pat, ctx.capacity)
-        hit = jax.ops.segment_max(m.astype(jnp.int32), row,
-                                  num_segments=ctx.capacity) > 0
-        # rows with no bytes at all never match non-empty patterns
-        return ColVal(dts.BOOL, hit, c.validity)
+        # a row holds the pattern iff a match starts between its first
+        # byte and the last one the pattern still fits behind: matches
+        # counted up to each byte, read at the row's two ends.  No byte
+        # asks for its row (rows with no bytes never match)
+        before = jnp.concatenate([
+            jnp.zeros(1, dtype=jnp.int32),
+            selection.cumsum_32(_pattern_at(c.values, pat)
+                                .astype(jnp.int32))])
+        lo = c.offsets[:-1]
+        hi = jnp.maximum(c.offsets[1:] - (len(pat) - 1), lo)
+        return ColVal(dts.BOOL, before[hi] > before[lo], c.validity)
 
 
 class Like(_PatternPredicate):

@@ -183,6 +183,15 @@ KEYWORDS = {
     "current", "row", "with",
 }
 
+# EXTRACT's fields -> the function of the same date part (the resolver's
+# table).  SECOND is left out: Spark's carries the fraction, second() is
+# whole seconds.
+EXTRACT_FIELDS = {
+    "year": "year", "quarter": "quarter", "month": "month", "day": "day",
+    "dayofweek": "dayofweek", "dow": "dayofweek", "doy": "dayofyear",
+    "hour": "hour", "minute": "minute",
+}
+
 
 class Token:
     __slots__ = ("kind", "value")
@@ -660,6 +669,8 @@ class Parser:
 
     def func_call(self, name: str) -> FuncCall:
         self.expect_op("(")
+        if name.lower() == "extract":
+            return self.extract_call()
         distinct = False
         args: List[object] = []
         if self.at_op("*"):
@@ -675,6 +686,20 @@ class Parser:
         if self.eat_kw("over"):
             window = self.window_def()
         return FuncCall(name.lower(), args, distinct, window)
+
+    def extract_call(self) -> FuncCall:
+        """``EXTRACT(<field> FROM <expr>)``, the opening parenthesis
+        taken: the date-part function of that name over ``<expr>``."""
+        field = self.cur.value.lower()
+        if field not in EXTRACT_FIELDS or \
+                self.toks[self.i + 1].value != "from":
+            raise ValueError(
+                f"EXTRACT takes (<field> FROM <expr>) with a field of "
+                f"{sorted(EXTRACT_FIELDS)}, got {self.cur}")
+        self.i += 2
+        arg = self.expr()
+        self.expect_op(")")
+        return FuncCall(EXTRACT_FIELDS[field], [arg], False, None)
 
     def window_def(self) -> WindowDef:
         self.expect_op("(")

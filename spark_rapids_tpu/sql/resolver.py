@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import datetime
+import threading
 from typing import Dict, List, Optional, Tuple
 
 from spark_rapids_tpu.sql import parser as A
@@ -24,6 +25,33 @@ AGG_FNS = {"sum", "count", "avg", "mean", "min", "max", "first", "last",
            "stddev_pop", "variance", "var_samp", "var_pop"}
 
 WINDOW_RANK_FNS = {"row_number", "rank", "dense_rank", "percent_rank"}
+
+
+class ResolverMetrics:
+    """What the resolver made of ``FROM a, b, ...`` lists, summed over
+    every statement resolved: ``comma_joins`` (relations joined by a
+    comma), ``reordered`` (those taken before a relation written ahead
+    of them, because the join graph reaches them first) and
+    ``cross_joins`` (those no conjunct connects).  Plain ints."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.comma_joins = self.reordered = self.cross_joins = 0
+
+    def note(self, reordered: bool, cross: bool) -> None:
+        with self._lock:
+            self.comma_joins += 1
+            self.reordered += reordered
+            self.cross_joins += cross
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"comma_joins": self.comma_joins,
+                    "reordered": self.reordered,
+                    "cross_joins": self.cross_joins}
+
+
+resolver_metrics = ResolverMetrics()
 
 
 class Scope:
@@ -109,9 +137,14 @@ class Resolver:
             scope.add(None, ["id"])
         else:
             df = self._from_item(stmt.from_, scope, pushed)
-        for j in stmt.joins:
+        pending = list(stmt.joins)
+        while pending:
+            k = self._next_join(pending, scope, spanning)
+            j = pending.pop(k)
             if j.how == "comma":
                 j = self._comma_join(j, scope, spanning, pushed_ids)
+                resolver_metrics.note(reordered=k > 0,
+                                      cross=j.how == "cross")
             df = self._join(df, j, scope, pushed)
         if stmt.where is not None:
             # top-level conjuncts that are IN (subquery) become
@@ -336,7 +369,8 @@ class Resolver:
         qualified by a known alias, or a bare name that exactly one
         table has.  Everything else stays above the joins.  The second
         list is where ``FROM a, b WHERE a.k = b.k`` finds its join
-        conditions (``_comma_join``)."""
+        conditions (``_comma_join``) and its join order
+        (``_next_join``)."""
         if stmt.where is None or not stmt.joins or \
                 any(j.how not in ("inner", "comma") for j in stmt.joins):
             return {}, []
@@ -393,15 +427,40 @@ class Resolver:
         return pushed, spanning
 
     @staticmethod
+    def _alias_of(j: A.JoinClause) -> Optional[str]:
+        return getattr(j.right, "alias", None) or \
+            getattr(j.right, "name", None)
+
+    @staticmethod
+    def _next_join(pending, scope: "Scope", spanning) -> int:
+        """Which of ``pending`` (the joins not yet made, as written) to
+        make next.  Of the relations a comma lists before the next
+        explicit JOIN, the first that an equality conjunct connects to
+        what is already joined (Spark's ReorderJoin: the written order,
+        but never a cross join while the join graph reaches another
+        relation); the first as written where there is none, or where
+        the next join is explicit."""
+        joined = {a for a, _ in scope.sources}
+        for k, j in enumerate(pending):
+            if j.how != "comma":
+                break
+            alias = Resolver._alias_of(j)
+            if any(isinstance(conj, A.BinOp) and conj.op == "=" and
+                   alias in found and found - {alias} <= joined
+                   for conj, found in spanning):
+                return k
+        return 0
+
+    @staticmethod
     def _comma_join(j: A.JoinClause, scope: "Scope", spanning,
                     used: set) -> A.JoinClause:
         """``FROM ..., right``: an inner join on the WHERE conjuncts that
         name ``right`` and otherwise only sources already joined (Spark
-        folds them into the join the same way), in the FROM clause's
-        order; a cross join where there is none.  The conjuncts taken
-        are added to ``used`` so that the WHERE filter skips them."""
-        alias = getattr(j.right, "alias", None) or \
-            getattr(j.right, "name", None)
+        folds them into the join the same way), taken in the order
+        ``_next_join`` gives; a cross join where there is none.  The
+        conjuncts taken are added to ``used`` so that the WHERE filter
+        skips them."""
+        alias = Resolver._alias_of(j)
         joined = {a for a, _ in scope.sources}
         on = None
         for conj, found in spanning:

@@ -158,13 +158,14 @@ def test_join_out_starts_compiles_without_a_wide_scan(one_chip, capacity):
     1,024 it takes under 2 s.  No reduce-window is wider than a row."""
     import re
     from spark_rapids_tpu.ops import joins as J
+    from spark_rapids_tpu.ops import selection
     lowered = J.join_out_starts.lower(
         _spec((capacity,), jnp.int32, one_chip),
         _spec((), jnp.int32, one_chip), outer=False)
     windows = [max(int(d) for d in m.split(","))
                for m in re.findall(r"window_dimensions = array<i64: ([\d, ]+)>",
                                    lowered.as_text())]
-    assert windows and max(windows) <= J._SCAN_BLOCK
+    assert windows and max(windows) <= selection.SCAN_BLOCK
     assert _device_bytes(lowered.compile()) < HBM_BYTES
 
 
@@ -333,3 +334,42 @@ def test_four_chip_exchange_compiles(topo, monkeypatch):
     assert "all-to-all" in text
     assert "tpu_custom_call" in text
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_two_key_join_match_compiles_at_q9s_capacities(one_chip):
+    """``jit_join_match`` on Q9's ``ps_suppkey = l_suppkey and
+    ps_partkey = l_partkey``: two int64 keys, ``partsupp`` at SF1 as the
+    build side (2^20 slots) and the lines that found a part and a
+    supplier as the probe (2^19): two single-key 64-bit sorts over
+    2^20 + 2^19 rows (ops/selection.py ``lexsort_i32``), at the cell's
+    own capacities."""
+    from spark_rapids_tpu.ops import joins as J
+
+    def keys(capacity):
+        return [ColVal(None, _spec((capacity,), jnp.int64, one_chip), None)
+                for _ in range(2)]
+
+    n = _spec((), jnp.int32, one_chip)
+    compiled = J.join_match.lower(keys(1 << 20), keys(1 << 19), n, n).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_like_filter_stage_compiles_at_q9s_capacities(one_chip):
+    """``jit_filter_stage_*`` of Q9's ``p_name like '%green%'``: ``part``
+    at SF1 is 200,000 rows (2^18 slots) of ``p_partkey`` and ``p_name``,
+    whose five colour words fill a 2^23-byte buffer; about one row in
+    nineteen is kept, scattered, so the compaction's gathers run."""
+    from spark_rapids_tpu.ops.compiler import FilterStageFn
+    from spark_rapids_tpu.ops.expressions import BoundReference
+    from spark_rapids_tpu.ops.stringops import Like
+    rows = 1 << 18
+    row = [dts.INT64, dts.STRING]
+    flat = [(_spec((rows,), jnp.int64, one_chip), None, None),
+            (_spec((1 << 23,), jnp.uint8, one_chip), None,
+             _spec((rows + 1,), jnp.int32, one_chip))]
+    refs = [BoundReference(i, dt) for i, dt in enumerate(row)]
+    stage = FilterStageFn(Like(refs[1], "%green%"), refs, row)
+    compiled = jax.jit(stage._run).lower(
+        flat, _spec((), jnp.int32, one_chip)).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert len(re.findall(r" conditional\(", compiled.as_text())) == 1
