@@ -38,13 +38,18 @@ class JoinMetrics:
     ``build_rows`` and ``build_capacity``: once a join, when its build
     side has become one batch, that batch's rows and its capacity, which
     ``join_match`` sorts again with every probe batch: what building on
-    the smaller side would save.  Plain ints, bumped with tracing on or
-    off."""
+    the smaller side would save.  ``expand_capacity`` and
+    ``expand_chunks``: the output slots the pair expansion maps (each
+    emitted chunk's bucketed capacity, which sizes
+    ``join_gather_indices``' work whatever the rows in it) and the chunks
+    emitted; semi and anti joins emit no pairs and bump neither.  Plain
+    ints, bumped with tracing on or off."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.probe_rows = self.output_rows = 0
         self.build_rows = self.build_capacity = 0
+        self.expand_capacity = self.expand_chunks = 0
 
     def note(self, probe: int, output: int) -> None:
         with self._lock:
@@ -56,12 +61,19 @@ class JoinMetrics:
             self.build_rows += rows
             self.build_capacity += capacity
 
+    def note_expand(self, capacity: int) -> None:
+        with self._lock:
+            self.expand_capacity += capacity
+            self.expand_chunks += 1
+
     def snapshot(self) -> dict:
         with self._lock:
             return {"probe_rows": self.probe_rows,
                     "output_rows": self.output_rows,
                     "build_rows": self.build_rows,
-                    "build_capacity": self.build_capacity}
+                    "build_capacity": self.build_capacity,
+                    "expand_capacity": self.expand_capacity,
+                    "expand_chunks": self.expand_chunks}
 
 
 join_metrics = JoinMetrics()
@@ -260,6 +272,7 @@ class TpuHashJoinExec(TpuExec):
     def _emit_chunk(self, probe_batch, build, build_payload, m, count,
                     starts, ends, offset, n_out) -> ColumnarBatch:
         out_cap = bucket_capacity(n_out)
+        join_metrics.note_expand(out_cap)
         # note: starts/ends use the outer-adjusted counts (row emission),
         # while `matched` must test the RAW match count so outer rows get
         # a null build side

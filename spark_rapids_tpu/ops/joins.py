@@ -11,8 +11,11 @@ through chunked gather maps (``GpuHashJoin.scala:96``, ``JoinGatherer.scala:
   semantics) but outer/anti rows survive via count adjustment.
 * phase B (``join_gather``): with the total match count known on the host,
   a bucketed output capacity is chosen and every output row is mapped back
-  to (probe row, k-th build match) with two searchsorted/gather passes —
-  the same static-shape expansion trick as the string gather.
+  to (probe row, k-th build match): the probe row by a histogram of the
+  probe rows' ends and a prefix sum, the build row by one 32-bit gather
+  of the probe row's offset into the sorted build side and one of the
+  build row there — the same static-shape expansion as the string gather
+  (``selection.rows_of_positions``), and as there no binary search.
 
 Semi/anti joins skip phase B entirely (a compaction of the probe side).
 Full outer adds one extra batch of never-matched build rows.
@@ -25,6 +28,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_tpu.ops.expressions import ColVal
 from spark_rapids_tpu.ops import selection
@@ -158,20 +162,44 @@ def join_out_starts(probe_count, probe_n, outer: bool):
     return count, starts, ends, ends[p_cap - 1]
 
 
+_SIGN = np.int32(-2 ** 31)        # ``x ^ _SIGN`` is x + 2^31 modulo 2^32
+
+
 @lru_cache(maxsize=None)
 def _gather_indices_kernel(out_cap: int):
     @jax.jit
     def join_gather_rows(starts, ends, probe_count, probe_bstart,
                          sorted_to_build, total):
-        j = jnp.arange(out_cap, dtype=jnp.int64)
-        p = jnp.searchsorted(ends, j, side="right").astype(jnp.int32)
-        p = jnp.clip(p, 0, probe_count.shape[0] - 1)
-        k = (j - starts[p]).astype(jnp.int32)
-        matched = k < probe_count[p]
-        bpos = probe_bstart[p] + k
-        brow = sorted_to_build[jnp.clip(bpos, 0,
-                                        sorted_to_build.shape[0] - 1)]
-        in_range = j < total
+        """Output row ``j`` of ``out_cap`` belongs to the probe row ``p``
+        with ``starts[p] <= j < ends[p]``: ``p`` is how many rows end at
+        or before ``j``, read off a histogram of the ends by a prefix
+        sum.  (A binary search of every ``j`` in the int64 ``ends`` is
+        log2(rows) dependent gathers of ``out_cap`` emulated 64-bit
+        elements, and an arbitrary gather is what the chip does worst:
+        320 to 394 ms a launch for q9's 2^19 rows against 9 to 12,
+        PERF.md, PR 38.)  ``ends`` may be the caller's ``ends - offset``:
+        an end at or before 0 is before every ``j`` and counts at 0, one
+        at or past ``out_cap`` is after every ``j`` and is dropped.
+        Rows that emit nothing share their end with the row before, so
+        the count passes over them.  Only the clip is 64-bit."""
+        p_cap = probe_count.shape[0]
+        cap = sorted_to_build.shape[0]
+        j = jnp.arange(out_cap, dtype=jnp.int32)
+        in_range = j < jnp.minimum(total, out_cap).astype(jnp.int32)
+        end_at = jnp.clip(ends, 0, out_cap).astype(jnp.int32)
+        p = selection.cumsum_32(
+            jnp.zeros(out_cap, jnp.int32).at[end_at].add(1, mode="drop"))
+        p = jnp.minimum(p, p_cap - 1)
+        # the k-th match of p sits at sorted position probe_bstart[p] + k
+        # with k = j - starts[p]: one int32 a probe row, exact modulo 2^32
+        # whatever the 64-bit starts of rows outside this chunk are.  A
+        # row kept by an outer join alone (adjusted count 1 over a raw
+        # count of 0) carries the sign bit: positions are under 2^31
+        delta = probe_bstart - starts.astype(jnp.int32)
+        delta = jnp.where(probe_count > 0, delta, delta ^ _SIGN)
+        bpos = j + delta[p]
+        matched = bpos >= 0
+        brow = sorted_to_build[jnp.minimum(bpos & ~_SIGN, cap - 1)]
         return p, jnp.clip(brow, 0, None), matched & in_range, in_range
     return join_gather_rows
 

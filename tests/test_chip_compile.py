@@ -169,6 +169,34 @@ def test_join_out_starts_compiles_without_a_wide_scan(one_chip, capacity):
     assert _device_bytes(lowered.compile()) < HBM_BYTES
 
 
+@pytest.mark.parametrize("p_cap,b_cap", [(1 << 19, 1 << 21),
+                                         (1 << 18, 1 << 23)])
+def test_join_gather_rows_compiles_without_a_search(one_chip, p_cap, b_cap):
+    """q9's pair expansions: 2^19 output rows from a probe batch of 2^19
+    (orders built at 2^21) and of 2^18 (the parts, lineitem built at
+    2^23).  The probe row of an output row was a binary search in the
+    int64 ends, a ``while`` of 19 dependent gathers and 321 to 453 ms a
+    launch on the chip (PERF.md, PR 37); it is a histogram and a prefix
+    sum in rows of 1,024: no loop, no reduce-window wider than a row."""
+    from spark_rapids_tpu.ops import joins as J
+    from spark_rapids_tpu.ops import selection
+    out_cap = 1 << 19
+    i64 = _spec((p_cap,), jnp.int64, one_chip)
+    i32 = _spec((p_cap,), jnp.int32, one_chip)
+    lowered = J._gather_indices_kernel(out_cap).lower(
+        i64, i64, i32, i32, _spec((b_cap + p_cap,), jnp.int32, one_chip),
+        _spec((), jnp.int64, one_chip))
+    text = lowered.as_text()
+    assert "while" not in text
+    windows = [max(int(d) for d in m.split(","))
+               for m in re.findall(r"window_dimensions = array<i64: ([\d, ]+)>",
+                                   text)]
+    assert windows and max(windows) <= selection.SCAN_BLOCK
+    compiled = lowered.compile()
+    assert "while" not in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 @pytest.mark.parametrize("storage,nullable",
                          [(jnp.int64, False), (jnp.int32, True)],
                          ids=["int64", "nullable_int32"])

@@ -315,3 +315,172 @@ def test_join_out_starts_is_an_exact_int64_scan(capacity, top, outer):
     np.testing.assert_array_equal(np.asarray(ends), want_ends)
     np.testing.assert_array_equal(np.asarray(starts), want_ends - want)
     assert int(total) == int(want_ends[-1])
+
+
+def _expansion_case(name):
+    """(raw counts, probe_n, outer, offset, n_out, out_cap) of one case
+    of the pair expansion; build matches are laid out by the test."""
+    rng = np.random.default_rng(38)
+    if name == "inner_zero_counts_in_the_middle_and_at_both_ends":
+        return [0, 0, 3, 0, 0, 1, 2, 0, 4, 0, 0, 0], 12, False, 0, 10, 16
+    if name == "left_outer_unmatched_rows_emit_one_null_row":
+        return [0, 2, 0, 0, 1, 3, 0], 7, True, 0, 10, 16
+    if name == "every_count_zero":
+        return [0] * 8, 8, False, 0, 0, 16
+    if name == "total_is_the_capacity":
+        return [4, 0, 4, 1, 0, 7], 6, False, 0, 16, 16
+    if name == "padding_probe_rows":
+        # rows past probe_n: 9 of 16, whatever their raw counts say
+        return [2, 0, 1, 3, 0, 1, 2, 5, 5, 0, 7, 1, 1, 0, 0, 9], \
+            7, False, 0, 9, 16
+    if name == "left_outer_padding_probe_rows":
+        return [0, 2, 0, 1, 0, 0, 0, 0], 5, True, 0, 6, 8
+    if name == "chunk_with_an_offset":
+        # rows 0..2 end before the chunk (ends - offset <= 0), row 3
+        # straddles its start, the last rows run past out_cap
+        return [5, 0, 6, 9, 0, 0, 3, 8, 0, 20, 4], 11, False, 14, 16, 16
+    if name == "left_outer_chunk_with_an_offset":
+        return [0, 5, 0, 0, 6, 0, 9, 0, 3, 0, 0, 8], 12, True, 9, 16, 16
+    if name == "one_row_wider_than_the_chunk":
+        return [3, 100, 2], 3, False, 20, 32, 32
+    if name == "last_chunk_shorter_than_its_capacity":
+        return [7, 0, 30, 1, 12], 5, False, 32, 18, 32
+    if name == "int64_ends_above_2_31_chunk_from_the_middle":
+        # 2^16 probe rows that each match 2^16 build rows: ends to 2^32
+        counts = np.full(1 << 16, 1 << 16)
+        counts[rng.random(1 << 16) < 0.2] = 0
+        return counts, 1 << 16, False, (1 << 31) + 12_345, 4096, 4096
+    if name == "total_past_the_capacity":
+        # the mesh's call: no offset, the whole total, the output cut
+        return [3, 0, 9, 2, 0, 30, 4, 1], 8, False, 0, 49, 16
+    if name == "blocked_scan_random_counts":
+        counts = rng.integers(0, 4, size=4096)
+        counts[rng.random(4096) < 0.5] = 0
+        return counts, 4000, True, 0, None, 8192
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "inner_zero_counts_in_the_middle_and_at_both_ends",
+    "left_outer_unmatched_rows_emit_one_null_row",
+    "every_count_zero",
+    "total_is_the_capacity",
+    "padding_probe_rows",
+    "left_outer_padding_probe_rows",
+    "chunk_with_an_offset",
+    "left_outer_chunk_with_an_offset",
+    "one_row_wider_than_the_chunk",
+    "last_chunk_shorter_than_its_capacity",
+    "int64_ends_above_2_31_chunk_from_the_middle",
+    "total_past_the_capacity",
+    "blocked_scan_random_counts",
+])
+def test_join_gather_indices_is_the_plain_expansion(name):
+    """``join_gather_indices`` finds an output row's probe row with a
+    histogram of the rows' ends and a prefix sum: the same pairs in the
+    same order as ``np.repeat`` of the probe rows by their adjusted
+    counts with the k-th build match beside each, row for row below
+    ``total``; padding rows only have to be in range."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import joins as J
+    raw, probe_n, outer, offset, n_out, out_cap = _expansion_case(name)
+    raw = np.asarray(raw, dtype=np.int32)
+    p_cap = raw.shape[0]
+    live = np.arange(p_cap) < probe_n
+    rng = np.random.default_rng(p_cap + out_cap)
+    # a sorted build side with room for every probe row's run; a probe
+    # row that matched nothing points one past it, as join_match leaves it
+    b_cap = int(raw.max()) + 5
+    cap = b_cap + p_cap
+    sorted_to_build = rng.integers(0, b_cap, size=cap).astype(np.int32)
+    probe_bstart = np.where(
+        raw > 0, rng.integers(0, 5, size=p_cap), cap).astype(np.int32)
+
+    count, starts, ends, total = J.join_out_starts(
+        jnp.asarray(raw), jnp.int32(probe_n), outer)
+    adj = np.where(live, np.where(outer & (raw == 0), 1, raw), 0) \
+        .astype(np.int64)
+    np.testing.assert_array_equal(np.asarray(count), adj)
+    if n_out is None:
+        n_out = int(total)
+    assert offset + n_out <= int(total)
+
+    p, brow, matched, in_range = J.join_gather_indices(
+        starts - offset if offset else starts,
+        ends - offset if offset else ends,
+        jnp.asarray(raw), jnp.asarray(probe_bstart),
+        jnp.asarray(sorted_to_build), jnp.int64(n_out), out_cap)
+    n_out = min(n_out, out_cap)
+    p, brow, matched, in_range = (np.asarray(a) for a in
+                                  (p, brow, matched, in_range))
+    assert p.dtype == brow.dtype == np.int32
+    assert p.shape == brow.shape == matched.shape == in_range.shape \
+        == (out_cap,)
+
+    # the plain expansion, cut to the chunk's window of output rows
+    np_ends = np.cumsum(adj)
+    np_starts = np_ends - adj
+    lo, hi = offset, offset + n_out
+    in_chunk = np.clip(np_ends, lo, hi) - np.clip(np_starts, lo, hi)
+    want_p = np.repeat(np.arange(p_cap), in_chunk)
+    k = lo + np.arange(n_out) - np_starts[want_p]
+    want_matched = raw[want_p] > 0
+    assert (k >= 0).all() and (k < adj[want_p]).all()
+    want_brow = sorted_to_build[
+        np.clip(probe_bstart[want_p] + k, 0, cap - 1)]
+
+    np.testing.assert_array_equal(in_range, np.arange(out_cap) < n_out)
+    np.testing.assert_array_equal(p[:n_out], want_p)
+    np.testing.assert_array_equal(matched[:n_out], want_matched)
+    np.testing.assert_array_equal(brow[:n_out], want_brow)
+    assert not matched[n_out:].any()
+    assert p.min(initial=0) >= 0 and p.max(initial=0) < p_cap
+    assert brow.min(initial=0) >= 0 and brow.max(initial=0) < b_cap
+    if outer:
+        assert (~want_matched).any()
+
+
+@pytest.mark.parametrize("how,chunks", [("inner", 1), ("left", 1),
+                                        ("semi", 0), ("anti", 0)])
+def test_join_metrics_count_the_expansions_slots(session, how, chunks):
+    """``expand_capacity`` is the sum of the emitted chunks' bucketed
+    capacities (what ``join_gather_indices`` maps, whatever the rows in
+    them) and ``expand_chunks`` their number; semi and anti joins emit
+    no pairs and bump neither."""
+    from spark_rapids_tpu.columnar.column import bucket_capacity
+    from spark_rapids_tpu.exec.join import join_metrics
+    left, right, got = _join_frames(session, how)
+    before = join_metrics.snapshot()
+    rows = len(got.to_pandas())
+    after = join_metrics.snapshot()
+    assert {"expand_capacity", "expand_chunks"} <= set(after)
+    moved = {k: after[k] - before[k] for k in after}
+    assert moved["expand_chunks"] == chunks
+    assert moved["expand_capacity"] == \
+        (bucket_capacity(rows) if chunks else 0)
+    assert moved["probe_rows"] > 0
+    assert moved["output_rows"] == (rows if chunks else 0)
+
+
+@pytest.mark.parametrize("how", ["inner", "left"])
+def test_chunked_join_output_is_the_whole_expansion(how):
+    """A probe batch whose pairs leave in chunks of ``outputBatchRows``:
+    every chunk after the first maps its rows from ``ends - offset``
+    (ends at or below zero before it, ends past its capacity after it),
+    and the chunks together are the join; ``expand_capacity`` is the sum
+    of the chunks' bucketed capacities."""
+    from spark_rapids_tpu.columnar.column import bucket_capacity
+    from spark_rapids_tpu.exec.join import join_metrics
+    chunk = 96                      # no power of two: capacity 128 a chunk
+    s = TpuSession({"spark.rapids.sql.join.outputBatchRows": str(chunk)})
+    left, right, got = _join_frames(s, how, kmax=80)
+    want = left.merge(right, on="k", how=how)
+    before = join_metrics.snapshot()
+    _compare_join(got, want)
+    after = join_metrics.snapshot()
+    sizes = [min(chunk, len(want) - off)
+             for off in range(0, len(want), chunk)]
+    assert len(sizes) > 5
+    assert after["expand_chunks"] - before["expand_chunks"] == len(sizes)
+    assert after["expand_capacity"] - before["expand_capacity"] == \
+        sum(bucket_capacity(n) for n in sizes)
