@@ -200,7 +200,7 @@ def main(argv=None):
         times = [d.seconds for d in done]
         values = {"query_s": window_s / len(done),
                   "query_p90_s": percentile(times, 90),
-                  "query_max_s": max(times),
+                  "query_p80_s": percentile(times, 80),
                   "setup_s": setup_s}
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in cell.end_to_end}
